@@ -1,6 +1,8 @@
-"""Pre-merge perf gate (`make bench-gate`): short bench runs at the
-committed configurations must not regress by more than the threshold
-(default 20%).
+"""CPU host bench: the pre-merge perf gate (`make bench-gate`).  Short
+bench runs at the committed configurations must not regress by more
+than the threshold (default 20%).  Every child is pinned with
+``JAX_PLATFORMS=cpu``; no number here is a device number (ROADMAP A1
+replaces this gate with per-cell bounds measured on the chip).
 
 Rows:
   e2e_commits_per_sec — a short `bench_e2e.py` run vs BENCH_E2E.json

@@ -4,19 +4,19 @@ drives 64K+ raft groups with every [G] protocol lane active (ISSUE 19).
 Three modes:
 
 ``--smoke``
-    CPU dryrun on 8 virtual host devices (XLA_FLAGS force_host_platform
-    _device_count): boots a mesh-mode engine at a small G and PROVES
-    each lane engaged — witness commit clamp (device commit pinned to
-    the best data-replica match on adversarial rows), stepdown/priority
-    tick delivery, device read-fence quorum tallies, election-due
-    scheduling.  Wired into ``make multichip-smoke`` / ``make check``.
+    CPU dryrun on 8 virtual host devices: boots a mesh-mode engine at a
+    small G and PROVES each lane engaged — witness commit clamp (device
+    commit pinned to the best data-replica match on adversarial rows),
+    stepdown/priority tick delivery, device read-fence quorum tallies,
+    election-due scheduling.  Wired into ``make multichip-smoke`` /
+    ``make check``.  The only mode that pins JAX to the CPU.
 
 ``--scale``
-    The acceptance rung: G=65536 groups sharded over 8 devices, same
-    lane assertions, sustained tick-rate + commit-rate measurement.
-    Writes MULTICHIP_r06.json and merges a ``sharded_engine`` row into
-    BENCH_SCALE.json (riding alongside the real-protocol ladder rows,
-    which prove the same lanes with full nodes at smaller G).
+    The acceptance rung: G=65536 groups sharded over the devices JAX
+    reports (``--devices`` to use fewer), same lane assertions,
+    sustained tick-rate + commit-rate measurement.  The RESULT line is
+    labelled from ``jax.devices()``; ``chip_smoke.py`` runs the same
+    driver on the chip.
 
 ``--engine-shape``
     Single-device calibration shape for bench_gate.py: G leader-heavy
@@ -36,20 +36,8 @@ the lanes at a G no single-process node population can reach.
 
 import argparse
 import json
-import os
 import sys
 import time
-
-REPO = os.path.dirname(os.path.abspath(__file__))
-
-
-def _force_host_devices(n: int) -> None:
-    """Must run before the first jax import anywhere in the process."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={n}".strip())
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 # ---------------------------------------------------------------------------
@@ -128,13 +116,17 @@ class _StubFence:
 
 
 # ---------------------------------------------------------------------------
-# mesh-mode driver (smoke + scale)
+# lane driver (smoke + scale + chip_smoke.py)
 # ---------------------------------------------------------------------------
 
-async def _drive_mesh(groups: int, devices: int, duration_s: float,
-                      seed: int) -> dict:
+async def drive_lanes(groups: int, devices: int, duration_s: float,
+                      seed: int, peers: int = 4) -> dict:
+    """Drive every [G] lane of one jax-backed engine and prove each
+    engaged.  ``devices`` > 1 shards the group axis over that many
+    devices (mesh mode); 1 runs the same driver on one device."""
     import resource
 
+    import jax
     import numpy as np
 
     from tpuraft.conf import Configuration
@@ -144,12 +136,15 @@ async def _drive_mesh(groups: int, devices: int, duration_s: float,
 
     rng = np.random.default_rng(seed)
     eng = MultiRaftEngine(TickOptions(
-        max_groups=groups, max_peers=4, mesh_devices=devices,
+        max_groups=groups, max_peers=peers, backend="jax",
+        mesh_devices=devices if devices > 1 else 0,
         tick_interval_ms=20, eager_commit=False,
         density_aware_timeouts=False))
     t_boot = time.monotonic()
     await eng.start()
-    assert eng._deadline_fold is not None, "mesh mode did not engage"
+    assert eng._tick_fn is not None, "jax tick did not engage"
+    assert (eng._deadline_fold is not None) == (devices > 1), \
+        "mesh mode did not follow the device count"
 
     G = eng.G
     factory = eng.ballot_box_factory()
@@ -254,13 +249,25 @@ async def _drive_mesh(groups: int, devices: int, duration_s: float,
     # plain witness groups commit normally through the clamp lane
     wit_lead = (kinds == 1) & leaders
     wit_commit_ok = bool((eng.commit_abs[wit_lead] >= rounds - 1).all())
+    # where the rows live: one direct call of the compiled tick on the
+    # same mirrors, outputs left on the device — every device must hold
+    # its G/devices rows, not everything on device 0
+    out = eng._tick_fn(eng._group_state(*eng._rel_views()),
+                       np.int32(eng.now_ms()), eng._params_dev)
+    shards = out.commit_rel.addressable_shards
+    rows_per_shard = [int(sh.data.shape[0]) for sh in shards]
+    shard_devices = sorted(sh.device.id for sh in shards)
+    n_dev = max(devices, 1)
     stats = eng.lane_stats()
     res = {
         "groups": G,
-        "peers": 4,
+        "peers": peers,
         "mesh_devices": devices,
-        "platform": "cpu-host-devices" if os.environ.get(
-            "JAX_PLATFORMS", "").startswith("cpu") else "accelerator",
+        "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": len(jax.devices()),
+        "rows_per_shard": rows_per_shard,
+        "shard_devices": shard_devices,
         "boot_s": round(boot_s, 1),
         "duration_s": round(elapsed, 2),
         "ticks": ticks,
@@ -292,55 +299,56 @@ async def _drive_mesh(groups: int, devices: int, duration_s: float,
         failures.append("witness clamp never engaged on probe rows")
     if not wit_commit_ok:
         failures.append("witness-conf groups failed to commit")
-    if res["stepdown_ticks"] <= 0 or res["stepdown_handler_calls"] <= 0:
-        failures.append("stepdown/priority lane never fired")
-    if res["fence_resolved"] <= 0:
-        failures.append("device fence lane never resolved a round")
+    if res["stepdown_ticks"] <= 0 \
+            or res["stepdown_handler_calls"] != res["stepdown_ticks"]:
+        failures.append("stepdown/priority lane: "
+                        f"{res['stepdown_handler_calls']} deliveries for "
+                        f"{res['stepdown_ticks']} fires")
+    if res["fence_resolved"] <= 0 \
+            or res["fence_resolved"] != res["fence_armed"]:
+        failures.append(f"device fence lane resolved "
+                        f"{res['fence_resolved']} of {res['fence_armed']} "
+                        f"armed rounds")
     if res["election_due_handled"] <= 0:
         failures.append("election lane never delivered")
     if commits[0] <= 0:
         failures.append("no commits advanced through the device tick")
+    if rows_per_shard != [G // n_dev] * n_dev \
+            or len(set(shard_devices)) != n_dev:
+        failures.append(f"group axis not spread: {rows_per_shard} rows "
+                        f"on devices {shard_devices}")
+    if stats["tick_failures"]:
+        failures.append(f"{stats['tick_failures']} ticks raised")
     res["ok"] = not failures
     res["failures"] = failures
     await eng.shutdown()
     return res
 
 
-def _merge_json(path: str, key: str, row: dict) -> None:
-    out = {}
-    if os.path.exists(path):
-        with open(path) as f:
-            out = json.load(f)
-    out[key] = row
-    with open(path, "w") as f:
-        json.dump(out, f, indent=1)
-
-
 def _run_mesh(args) -> int:
     import asyncio
 
-    _force_host_devices(args.devices)
+    import jax
+
+    from tpuraft.util.jax_cache import ensure_compile_cache
+
+    if args.smoke:
+        # the one mode pinned to the CPU: virtual host devices, set
+        # before the backend initialises
+        jax.config.update("jax_platforms", "cpu")
+        jax.config.update("jax_num_cpu_devices", args.devices or 8)
+    ensure_compile_cache()
+    devices = args.devices or len(jax.devices())
     groups = args.groups or (1024 if args.smoke else 65536)
     duration = args.duration or (1.5 if args.smoke else 6.0)
-    res = asyncio.run(_drive_mesh(groups, args.devices, duration,
-                                  args.seed))
+    res = asyncio.run(drive_lanes(groups, devices, duration, args.seed))
     print("RESULT " + json.dumps(res), flush=True)
-    if args.scale:
-        tail = (f"sharded_engine({res['groups']}g x "
-                f"{res['mesh_devices']}dev): {res['ticks_per_sec']} "
-                f"ticks/s, {res['commits_per_sec']} commits/s, lanes "
-                f"witness+stepdown+fence+election all engaged")
-        with open(os.path.join(REPO, "MULTICHIP_r06.json"), "w") as f:
-            json.dump({"n_devices": args.devices, "rc": 0 if res["ok"]
-                       else 1, "ok": res["ok"], "skipped": False,
-                       "tail": tail, "sharded_engine": res}, f, indent=1)
-        _merge_json(os.path.join(REPO, "BENCH_SCALE.json"),
-                    "sharded_engine", res)
     if not res["ok"]:
         print("FAIL: " + "; ".join(res["failures"]), file=sys.stderr)
         return 1
     print(f"multichip {'smoke' if args.smoke else 'scale'} OK: "
-          f"{res['groups']} groups / {res['mesh_devices']} devices, "
+          f"{res['groups']} groups / {res['mesh_devices']} "
+          f"{res['platform']} devices, "
           f"{res['ticks_per_sec']} ticks/s", flush=True)
     return 0
 
@@ -401,14 +409,17 @@ def main() -> None:
     mode.add_argument("--smoke", action="store_true",
                       help="fast CPU 8-device lane-parity dryrun")
     mode.add_argument("--scale", action="store_true",
-                      help="64K-group acceptance rung; writes "
-                           "MULTICHIP_r06.json + BENCH_SCALE.json row")
+                      help="64K-group acceptance rung on the devices "
+                           "JAX reports")
     mode.add_argument("--engine-shape", action="store_true",
                       help="single-device tick-rate calibration shape "
                            "(bench_gate.py row)")
     ap.add_argument("--groups", type=int, default=0,
                     help="override G (default: 1024 smoke / 65536 scale)")
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--devices", type=int, default=0,
+                    help="mesh size (default: 8 virtual CPU devices "
+                         "for --smoke, every device JAX reports for "
+                         "--scale)")
     ap.add_argument("--duration", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=7)
     args = ap.parse_args()

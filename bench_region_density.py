@@ -1,13 +1,16 @@
-"""RheaKV at region density (VERDICT r3 #5): >= 1K regions on a
-3-store cluster through the FULL KV stack — region engines + KV state
+"""CPU host bench: RheaKV at region density (VERDICT r3 #5).  >= 1K
+regions on a 3-store cluster through the FULL KV stack — region engines + KV state
 machines + native C++ data engine + multilog shared journal + engine
 protocol plane + the batching RheaKV client — under mixed load, with PD
 heartbeat volume counted.
 
 rhea:StoreEngine's whole point is thousands of regions per process
 (SURVEY.md §3.2); until r4 the densest recorded KV run was 64 regions
-(BENCH_E2E.json).  Writes BENCH_REGIONS.json; bench.py embeds it as
-extra.regions.
+(BENCH_E2E.json).  Writes BENCH_REGIONS.json.  The parent pins the
+child with ``JAX_PLATFORMS=cpu`` (backend "auto" is then the numpy
+twin) — a CPU host bench until ROADMAP A1/C8 replaces it; no number
+here is a device number.  ``chip_smoke.py`` runs this topology on the
+chip.
 
 Topology: ONE process hosts all three stores over in-proc RPC (the
 loopback-TCP e2e variant at its own G lives in bench_e2e.py), each
@@ -27,10 +30,6 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 
 
 async def run_config(args) -> dict:
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
     import random
     import resource
 

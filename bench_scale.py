@@ -1,6 +1,6 @@
-"""Scale ladder for the REAL protocol plane (VERDICT r2 #1): 1K -> 4K
--> 16K raft groups per process under sustained write load — engine
-device-plane ticks + multilog fsync + RPC + FSM apply, NO synthetic
+"""CPU host bench: scale ladder for the REAL protocol plane (VERDICT
+r2 #1).  1K -> 4K -> 16K raft groups per process under sustained write
+load — engine ticks + multilog fsync + RPC + FSM apply, NO synthetic
 acks — recording commits/s, ack p50/p99, RSS, and asyncio task count
 per G, plus the per-G overhead curve.
 
@@ -14,8 +14,9 @@ collapse behavior (the 3-process loopback-TCP variant lives in
 bench_e2e.py and is recorded separately at its own G).
 
 Each rung runs in a fresh subprocess (clean RSS accounting, no
-cross-rung warm state).  Writes BENCH_SCALE.json; bench.py embeds it
-as extra.scale so the driver's record carries the curve.
+cross-rung warm state), pinned by its parent with ``JAX_PLATFORMS=cpu``
+— a CPU host bench until ROADMAP A1/C8 replaces it; no number here is
+a device number.  Writes BENCH_SCALE.json.
 """
 
 import argparse
@@ -30,10 +31,6 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 
 
 async def run_rung(args) -> dict:
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
     import random
     import resource
 

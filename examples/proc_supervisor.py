@@ -206,10 +206,13 @@ def server_argv(endpoint: str, stores: list[str], regions: int, data: str,
                 transport: str = "tcp", store: str = "memory",
                 log_scheme: str = "file", pd: str = "",
                 eto_ms: int = 1000, apply_lane: bool = False,
-                engine: bool = False,
+                engine: str = "",
                 drain_timeout_s: float = 10.0, boot_delay_s: float = 0.0,
                 metrics_port: Optional[int] = 0) -> list[str]:
-    """Command line for one ``examples.rheakv_server`` child."""
+    """Command line for one ``examples.rheakv_server`` child.
+    ``engine`` is the child's tick backend, "numpy" or "jax" ("" =
+    per-node timers): a chip belongs to one process, so at most one
+    child of a fleet on a one-chip host may be given "jax"."""
     argv = [sys.executable, "-m", "examples.rheakv_server",
             "--serve", endpoint, "--stores", ",".join(stores),
             "--regions", str(regions), "--data", data,
@@ -222,7 +225,7 @@ def server_argv(endpoint: str, stores: list[str], regions: int, data: str,
     if apply_lane:
         argv += ["--apply-lane"]
     if engine:
-        argv += ["--engine"]
+        argv += ["--engine", engine]
     if boot_delay_s:
         argv += ["--boot-delay", str(boot_delay_s)]
     if metrics_port is not None:
@@ -394,17 +397,27 @@ class ProcSupervisor:
 
 async def _soak(seconds: float, stores_n: int, regions: int, data: str,
                 transport: str, apply_lane: bool,
-                engine: bool = False) -> int:
+                engine: bool = False, chip_store: int = -1) -> int:
     from examples.rheakv_server import client_for
     from tpuraft.util.linearizability import History, check_history
 
     endpoints = free_endpoints(stores_n)
+    # every engine child is TOLD its backend: the one named by
+    # --chip-store takes the chip, the rest run the numpy twin
+    backends = [("jax" if i == chip_store else "numpy") if engine else ""
+                for i in range(stores_n)]
+    if engine:
+        print("soak: engine backends " + ", ".join(
+            f"{ep}={b}" for ep, b in zip(endpoints, backends))
+            + ("; chip held by " + endpoints[chip_store]
+               if 0 <= chip_store < stores_n else "; no child holds a chip"),
+            flush=True)
     sup = ProcSupervisor([
         StoreProcess(ep, server_argv(
             ep, endpoints, regions, data, transport=transport,
-            eto_ms=500, apply_lane=apply_lane, engine=engine,
+            eto_ms=500, apply_lane=apply_lane, engine=backend,
             metrics_port=None))
-        for ep in endpoints])
+        for ep, backend in zip(endpoints, backends)])
     await sup.start()
     sup.supervise()
     if transport == "native":
@@ -496,7 +509,13 @@ def main() -> None:
     ap.add_argument("--engine", action="store_true",
                     help="children drive their region nodes from ONE "
                          "MultiRaftEngine each (fused [G] tick) instead "
-                         "of per-node timers")
+                         "of per-node timers, on the numpy twin unless "
+                         "--chip-store names them")
+    ap.add_argument("--chip-store", type=int, default=-1,
+                    help="with --engine: index of the ONE child started "
+                         "with the jax backend (it takes this host's "
+                         "chip; a chip belongs to one process). "
+                         "Default: none")
     args = ap.parse_args()
     if not args.soak:
         ap.error("nothing to do (pass --soak)")
@@ -504,7 +523,8 @@ def main() -> None:
     shutil.rmtree(args.data, ignore_errors=True)
     rc = asyncio.run(_soak(args.seconds, args.stores, args.regions,
                            args.data, args.transport, args.apply_lane,
-                           engine=args.engine))
+                           engine=args.engine,
+                           chip_store=args.chip_store))
     sys.exit(rc)
 
 

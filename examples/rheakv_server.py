@@ -9,6 +9,13 @@ topologies; here the topology is CLI flags shared by every member.
         --stores 127.0.0.1:9001,127.0.0.1:9002,127.0.0.1:9003 \\
         --regions 4 --data /tmp/rkv1 [--transport native] [--store native]
 
+``--engine numpy|jax`` drives every region node from ONE MultiRaftEngine
+and names its tick backend outright: a chip belongs to one process, so
+on a one-chip host at most one store of a fleet is started with
+``--engine jax`` (it takes the chip, and fails to start if it cannot)
+and the others with ``--engine numpy`` (the bit-exact host twin).  The
+server never asks for ``backend="auto"``.
+
 Every member derives the same region layout from (--stores, --regions),
 so a client needs only the store list (see `client_for`); region
 discovery and split survival ride the `kv_list_regions` refresh path.
@@ -44,7 +51,7 @@ async def serve(endpoint: str, stores: list[str], n_regions: int,
                 metrics_port: int | None = None,
                 eto_ms: int = 1000,
                 apply_lane: bool = False,
-                engine: bool = False,
+                engine: str = "",
                 drain_timeout_s: float = 10.0,
                 boot_delay_s: float = 0.0) -> None:
     if boot_delay_s:
@@ -86,13 +93,14 @@ async def serve(endpoint: str, stores: list[str], n_regions: int,
         # ONE MultiRaftEngine drives every region node of this store
         # with a fused [G] tick (StoreEngine starts/stops it); capacity
         # sized to the next power of two above the region count so
-        # splits can land without an immediate _grow
+        # splits can land without an immediate _grow.  The backend is
+        # the operator's word, never "auto": see the module docstring.
         from tpuraft.core.engine import MultiRaftEngine
         from tpuraft.options import TickOptions
         cap = 1 << max(4, (n_regions + 3).bit_length())
         raft_engine = MultiRaftEngine(TickOptions(
             max_groups=cap, max_peers=max(4, len(stores) + 1),
-            tick_interval_ms=20))
+            tick_interval_ms=20, backend=engine))
     engine = StoreEngine(opts, server, transport,
                          multi_raft_engine=raft_engine, pd_client=pd_client)
     await engine.start()
@@ -164,12 +172,14 @@ def main() -> None:
                     help="run FSM applies + fenced reads on a dedicated "
                          "worker lane thread (one hot store saturates "
                          ">1 core)")
-    ap.add_argument("--engine", action="store_true",
+    ap.add_argument("--engine", choices=["numpy", "jax"], default="",
                     help="drive all region nodes from ONE MultiRaftEngine "
-                         "(fused [G] device/numpy tick) instead of "
-                         "per-node timers; witness members, priority "
-                         "re-election and device read fences all ride "
-                         "the engine lanes")
+                         "(fused [G] tick) instead of per-node timers, "
+                         "on the named backend: jax takes this host's "
+                         "accelerator (one process per chip), numpy is "
+                         "the host twin; witness members, priority "
+                         "re-election and read fences all ride the "
+                         "engine lanes")
     ap.add_argument("--drain-timeout", type=float, default=10.0,
                     help="seconds to wait for in-flight work on SIGTERM")
     ap.add_argument("--boot-delay", type=float, default=0.0,

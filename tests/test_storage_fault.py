@@ -980,6 +980,38 @@ def test_disk_budget_enospc_latch_pins_full():
     assert b.counters()["disk_pressure_resumes"] == 1
 
 
+def test_disk_budget_statvfs_view_counts_only_reachable_blocks():
+    """Whole-filesystem mode (no explicit budget): a volume whose free
+    blocks are mostly out of this process's reach (252 GiB of blocks,
+    241 free, 19.4 available, 11 used) is 36% full as the store can use
+    it — not "92% full", which shed every write on such a host.  A disk
+    the process really has almost no room left on still reads FULL."""
+    from types import SimpleNamespace
+
+    from tpuraft.util.health import (
+        PRESSURE_FULL,
+        PRESSURE_OK,
+        DiskBudget,
+        DiskBudgetOptions,
+        statvfs_usage,
+    )
+
+    gib = (1 << 30) // 4096
+
+    def level(blocks, bfree, bavail):
+        sv = SimpleNamespace(f_frsize=4096, f_blocks=int(blocks * gib),
+                             f_bfree=int(bfree * gib),
+                             f_bavail=int(bavail * gib))
+        b = DiskBudget(DiskBudgetOptions(worsen_after=1))
+        b.reconcile(*statvfs_usage(sv))
+        return b.evaluate()
+
+    assert level(252, 241, 19.4) == PRESSURE_OK
+    assert level(252, 241, 0.5) == PRESSURE_FULL    # 11 used, 0.5 left
+    assert level(252, 2, 2) == PRESSURE_FULL        # plainly full
+    assert level(252, 200, 200) == PRESSURE_OK      # plainly empty
+
+
 async def test_log_manager_enospc_flush_rolls_back_frontier(tmp_path):
     """Regression for the non-contiguous-append wedge the disk-pressure
     soak found: a flush that dies ENOSPC must fail its waiters AND roll

@@ -30,7 +30,7 @@ Division of labor per event:
 The tick loop is ADAPTIVE: a dirty mark (new ack / vote / deadline
 change) fires a tick immediately — commit acks are not quantized to a
 fixed cadence — while consecutive ticks self-pace by the previous tick's
-cost (slow tunneled devices batch more per dispatch).  Idle engines
+cost (a slow device batches more per dispatch).  Idle engines
 sleep until the next election/heartbeat deadline, capped at
 ``tick_interval_ms``.
 
@@ -56,6 +56,7 @@ from tpuraft.entity import PeerId
 from tpuraft.options import TickOptions
 from tpuraft.util import clock as clockmod
 from tpuraft.util.trace import RECORDER as _RECORDER
+from tpuraft.ops.ballot import NEG_INF_I32 as _NEG_I32
 from tpuraft.ops.tick import (
     ROLE_CANDIDATE,
     ROLE_FOLLOWER,
@@ -67,7 +68,6 @@ LOG = logging.getLogger(__name__)
 
 _REBASE_LIMIT = 1 << 28
 _TIME_REBASE_MS = 1 << 30        # epoch-shift threshold (int32 headroom)
-_NEG_I32 = -(2 ** 30)            # matches tpuraft.ops.ballot.NEG_INF_I32
 # protocol-param defaults for slots no node has registered yet
 _DEF_ETO_MS, _DEF_HB_MS, _DEF_LEASE_MS = 1000, 100, 900
 
@@ -831,6 +831,7 @@ class MultiRaftEngine:
         self._deadline_fold = None  # mesh mode: sharded earliest-deadline min
         self._params_dev = None
         self.ticks = 0
+        self.tick_failures = 0   # ticks that raised inside _loop
         self.commit_advances = 0
         # event-driven commit advancement (TickOptions.eager_commit):
         # quorums closed on the ack path by eager_commit_slot, without
@@ -996,7 +997,7 @@ class MultiRaftEngine:
           core — solve for eto.
         - tick-cost: one heartbeat interval must dwarf a measured tick
           dispatch (x50), or the engine cannot keep every group's beat
-          schedule — a tunneled/slow device raises the floor on its own.
+          schedule — a slow device raises the floor on its own.
         """
         if not self.opts.density_aware_timeouts:
             return 0
@@ -1341,7 +1342,8 @@ class MultiRaftEngine:
                 f"ctrl={int(self.has_ctrl.sum())} "
                 f"backend={self.opts.backend} "
                 f"mesh={self.opts.mesh_devices or 1} "
-                f"ticks={self.ticks} commit_advances={self.commit_advances} "
+                f"ticks={self.ticks} tick_failures={self.tick_failures} "
+                f"commit_advances={self.commit_advances} "
                 f"eager_commits={self.eager_commits} "
                 f"leaders={int((self.role == ROLE_LEADER).sum())} "
                 f"quiescent={int(self.quiescent.sum())} "
@@ -1383,6 +1385,7 @@ class MultiRaftEngine:
             "tick_cost_ema_ms": round(self._tick_cost_ema_s * 1e3, 3),
             "witness_groups": self._n_witness_slots,
             "stepdown_ticks": self.stepdown_ticks,
+            "tick_failures": self.tick_failures,
             "fence_lane_armed": self.fence_lane_armed,
             "fence_lane_resolves": self.fence_lane_resolves,
             "fences_pending": sum(len(w) for w
@@ -1461,25 +1464,26 @@ class MultiRaftEngine:
         on a CPU-only host the vectorized numpy twin of the tick beats
         XLA-CPU dispatch overhead at any G that fits one box (profiled:
         per-tick jit call overhead dominated small-G CPU ticks).  A mesh
-        request always means jax."""
+        request always means jax.  Choosing numpy on a CPU-only host is a
+        choice; a backend that fails to initialise is an error and
+        raises from here (it must never read as "no accelerator")."""
         b = self.opts.backend
         if b != "auto":
             return b
         if self.opts.mesh_devices and self.opts.mesh_devices > 1:
             return "jax"
-        try:
-            import jax
+        import jax
 
-            return "jax" if jax.default_backend() != "cpu" else "numpy"
-        except Exception:  # noqa: BLE001 — no jax at all
-            return "numpy"
+        return "jax" if jax.default_backend() != "cpu" else "numpy"
 
     async def start(self) -> None:
         if self._resolve_backend() != "numpy":
             import jax
 
             from tpuraft.ops.tick import raft_tick_outputs_jit
+            from tpuraft.util.jax_cache import ensure_compile_cache
 
+            ensure_compile_cache()
             if self.opts.mesh_devices and self.opts.mesh_devices > 1:
                 # SPMD over the group axis: each chip advances its own
                 # group rows; upload scatters, download gathers (the
@@ -1516,6 +1520,11 @@ class MultiRaftEngine:
             # tick mid-protocol would block the event loop for the
             # compile and miss every group's heartbeat window at once
             self.tick_once()
+            # say where the tick runs: with JAX_PLATFORMS unset JAX
+            # itself settles for the CPU when it cannot open the chip
+            dev = jax.devices()[0]
+            LOG.info("engine tick on %s (%s) x%d", dev.platform,
+                     dev.device_kind, self.opts.mesh_devices or 1)
         if self.opts.profile_dir:
             if self._resolve_backend() == "numpy":
                 LOG.warning("profile_dir set but backend is numpy: the "
@@ -1589,7 +1598,7 @@ class MultiRaftEngine:
     async def _loop(self) -> None:
         """Adaptive cadence: dirty -> tick now (sub-ms commit ack at low
         load); consecutive ticks pace by the previous tick's cost (a
-        tunneled device batches more per dispatch); idle -> sleep to the
+        slow device batches more per dispatch); idle -> sleep to the
         next deadline, capped at tick_interval_ms."""
         max_idle_s = self.opts.tick_interval_ms / 1000.0
         min_pace_s = self.opts.min_tick_interval_ms / 1000.0
@@ -1604,6 +1613,11 @@ class MultiRaftEngine:
                 try:
                     advanced = self.tick_once()
                 except Exception:
+                    # the loop must outlive one bad tick, but a tick
+                    # that raises (lost device, OOM, a refused compile
+                    # after _grow) is counted where operators look —
+                    # it must not read as "slow elections"
+                    self.tick_failures += 1
                     LOG.exception("engine tick failed")
                     self._dirty = True  # re-process pending acks next tick
                 dur = time.perf_counter() - t0
@@ -1663,10 +1677,7 @@ class MultiRaftEngine:
                                & (self.self_col >= 0))[0]
         if lead_rows.size:
             self.last_ack[lead_rows, self.self_col[lead_rows]] = now
-        rel = np.clip(self.match_abs - self.base[:, None], 0, None
-                      ).astype(np.int32)
-        commit_rel_now = np.clip(self.commit_abs - self.base, 0, None
-                                 ).astype(np.int32)
+        rel, commit_rel_now = self._rel_views()
 
         t1 = time.perf_counter()
         if self._tick_fn is not None:
@@ -1691,19 +1702,24 @@ class MultiRaftEngine:
             self._profile_tick(t0, t1, t2, t3, advanced)
         return advanced
 
-    def _device_tick(self, rel, commit_rel_now, now):
-        import jax
+    def _rel_views(self) -> tuple[np.ndarray, np.ndarray]:
+        """(match_rel [G,P], commit_rel [G]): the int32 base-relative
+        views of the absolute-index mirrors, as the tick reduces them."""
+        rel = np.clip(self.match_abs - self.base[:, None], 0, None
+                      ).astype(np.int32)
+        commit_rel_now = np.clip(self.commit_abs - self.base, 0, None
+                                 ).astype(np.int32)
+        return rel, commit_rel_now
 
-        from tpuraft.ops.tick import GroupState, TickParams
+    def _group_state(self, rel, commit_rel_now):
+        """The tick's input built from the host mirrors.  numpy goes
+        STRAIGHT into the jitted call — jit commits it to the device
+        itself, and an explicit jnp.asarray per field doubles the
+        per-tick host overhead (profiled: the asarray+device_put pair
+        dominated small-G tick cost)."""
+        from tpuraft.ops.tick import GroupState
 
-        if self._params_dev is None:
-            self._params_dev = TickParams.make(self.eto_ms, self.hb_ms,
-                                               self.lease_ms, self.snap_ms)
-        # numpy mirrors go STRAIGHT into the jitted call — jit commits
-        # them to the device itself, and an explicit jnp.asarray per
-        # field doubles the per-tick host overhead (profiled: the
-        # asarray+device_put pair dominated small-G tick cost)
-        state = GroupState(
+        return GroupState(
             role=self.role,
             commit_rel=commit_rel_now,
             pending_rel=self.pending_rel,
@@ -1720,6 +1736,16 @@ class MultiRaftEngine:
             stepdown_deadline=self.stepdown_deadline.astype(np.int32),
             fence_start=self.fence_start.astype(np.int32),
         )
+
+    def _device_tick(self, rel, commit_rel_now, now):
+        import jax
+
+        from tpuraft.ops.tick import TickParams
+
+        if self._params_dev is None:
+            self._params_dev = TickParams.make(self.eto_ms, self.hb_ms,
+                                               self.lease_ms, self.snap_ms)
+        state = self._group_state(rel, commit_rel_now)
         with jax.profiler.TraceAnnotation("tpuraft.raft_tick"):
             out = self._tick_fn(state, np.int32(now), self._params_dev)
         return jax.tree_util.tree_map(np.asarray, out)

@@ -17,7 +17,9 @@ import jax.numpy as jnp
 
 # Sentinel for masked-out peer slots. Using iinfo.min would overflow under
 # arithmetic; half-range is safely below any valid relative index (>= -1).
-NEG_INF_I32 = jnp.int32(-(2**30))
+# A plain int: a jnp scalar here would initialise the JAX backend (and
+# take the chip) in every process that merely imports the package.
+NEG_INF_I32 = -(2**30)
 
 
 def _masked_desc_sort(values: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:

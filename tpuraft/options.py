@@ -119,11 +119,11 @@ class TickOptions:
     # immediately (sub-ms commit ack); the floor only bounds the
     # sustained tick rate so a busy engine batches instead of
     # monopolizing the event loop.  pace_factor x last tick's cost
-    # additionally self-paces slow (tunneled) devices.
+    # additionally self-paces slow devices.
     min_tick_interval_ms: float = 1.0
     # Sleep pace_factor x (last tick duration) between consecutive
     # dirty ticks: cheap ticks run nearly back-to-back (sub-ms ack),
-    # expensive ticks (tunneled device) batch more per dispatch.
+    # expensive ticks (a slow device) batch more per dispatch.
     pace_factor: float = 0.5
     # Engine-driven protocol control plane: nodes whose ballot box comes
     # from this engine get elections / leases / step-down / heartbeat

@@ -58,29 +58,13 @@ def replicated_tick(mesh: Mesh, n_replicas: int,
       commit:  int32 [G] — quorum commit point per group
       votes:   int32 [G] — vote counts per group
     """
-    # jax moved shard_map out of experimental and renamed check_rep ->
-    # check_vma after 0.4.x — as SEPARATE changes, so feature-detect the
-    # kwarg from the signature rather than keying it off where shard_map
-    # lives (a public jax.shard_map may still take check_rep)
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map  # jax <= 0.4.x
-    import inspect
-
-    try:
-        params = inspect.signature(shard_map).parameters
-    except (TypeError, ValueError):  # builtins/partials without signatures
-        params = {}
-    check_kw = {"check_rep": False} if "check_rep" in params \
-        else {"check_vma": False}
-
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(replica_axis, group_axis), P(replica_axis, group_axis)),
         out_specs=(P(None, group_axis), P(None, group_axis)),
         # outputs ARE replica-identical (post-psum/gather)
-        **check_kw,
+        check_vma=False,
     )
     def step(match_block, granted_block):
         # blocks: [R_local, G_local]; local rows fold first, then the

@@ -59,7 +59,7 @@ def _load() -> ctypes.CDLL:
     global _lib
     with _lib_lock:
         if _lib is None:
-            lib = ctypes.CDLL(lib_path())
+            lib = ctypes.CDLL(ensure_built())
             u8p = ctypes.POINTER(ctypes.c_uint8)
             lib.tkv_open.restype = ctypes.c_void_p
             lib.tkv_open.argtypes = [ctypes.c_char_p, ctypes.c_int,
@@ -445,6 +445,5 @@ def create_raw_kv_store(uri: str) -> RawKVStore:
     if uri == "memory://":
         return MemoryRawKVStore()
     if uri.startswith("native://"):
-        ensure_built()
         return NativeRawKVStore(uri[len("native://"):])
     raise ValueError(f"unknown raw kv store uri: {uri}")

@@ -988,7 +988,7 @@ class StoreEngine:
         reclaim while under pressure."""
         from tpuraft.util.health import (DEGRADED, HEALTHY, SICK,
                                          PRESSURE_FULL, PRESSURE_NEAR_FULL,
-                                         PRESSURE_OK)
+                                         PRESSURE_OK, statvfs_usage)
 
         b = self.disk_budget
         if round_no % max(1, self.opts.disk_reconcile_rounds) == 1:
@@ -1002,8 +1002,7 @@ class StoreEngine:
                 # no explicit budget: whole-filesystem statvfs view
                 try:
                     sv = await loop.run_in_executor(None, os.statvfs, base)
-                    b.reconcile((sv.f_blocks - sv.f_bavail) * sv.f_frsize,
-                                sv.f_blocks * sv.f_frsize)
+                    b.reconcile(*statvfs_usage(sv))
                 except OSError:
                     pass
         level = b.evaluate()

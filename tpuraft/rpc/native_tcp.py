@@ -59,7 +59,7 @@ def _load() -> ctypes.CDLL:
     global _lib
     with _lib_lock:
         if _lib is None:
-            lib = ctypes.CDLL(lib_path())
+            lib = ctypes.CDLL(ensure_built())
             u8p = ctypes.POINTER(ctypes.c_uint8)
             lib.tnt_create.restype = ctypes.c_void_p
             lib.tnt_create.argtypes = [ctypes.c_char_p, ctypes.c_int]
@@ -105,7 +105,6 @@ class _NativeCtx:
     def __init__(self,
                  on_frame: Callable[[int, str, int, int, bytes], None],
                  on_closed: Callable[[int, str], None]):
-        ensure_built()
         self._lib = _load()
         err = ctypes.create_string_buffer(256)
         self._h = self._lib.tnt_create(err, len(err))

@@ -49,7 +49,7 @@ def _load() -> ctypes.CDLL:
     global _lib
     with _lib_lock:
         if _lib is None:
-            lib = ctypes.CDLL(lib_path())
+            lib = ctypes.CDLL(ensure_built())
             u8p = ctypes.POINTER(ctypes.c_uint8)
             lib.tls_open.restype = ctypes.c_void_p
             lib.tls_open.argtypes = [ctypes.c_char_p, ctypes.c_int64,

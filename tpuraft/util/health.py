@@ -478,6 +478,19 @@ class DiskBudgetOptions:
     enospc_latch_rounds: int = 2
 
 
+def statvfs_usage(sv) -> tuple[int, int]:
+    """(used, capacity) bytes of a filesystem as THIS process can use it,
+    from an ``os.statvfs`` result — df's Use% arithmetic.  Used is what
+    is occupied (``f_blocks - f_bfree``); capacity is that plus what the
+    process may still write (``f_bavail``).  Blocks that are free but
+    out of the process's reach (root reserve, another tenant's share of
+    a thin-provisioned volume) belong to neither: counting them as used
+    reads an almost empty disk as almost full, and counting them as
+    capacity hides a disk the store can no longer write to."""
+    used = (sv.f_blocks - sv.f_bfree) * sv.f_frsize
+    return used, used + sv.f_bavail * sv.f_frsize
+
+
 # Fed from EXECUTOR threads (the LogManager flush loop accounts append
 # bytes off-loop; snapshot commits run in the executor) as well as the
 # store's event loop — cross-thread like DiskLatencyProbe, so it

@@ -1,16 +1,17 @@
 """Shared build-or-dlopen logic for the native C++ engines.
 
-Used by multilog / logstore / transport / kvstore loaders.  Three
-deployment shapes must all work:
+Used by multilog / logstore / transport / kvstore loaders.  Two
+deployment shapes work:
 
-  1. dev checkout (toolchain + writable dir): rebuild when sources are
-     newer than the .so, under a cross-process flock so concurrently
-     spawned stores never dlopen a half-written file;
+  1. checkout (toolchain + writable dir): build when the .so is missing
+     or older than its sources, under a cross-process flock so
+     concurrently spawned stores never dlopen a half-written file.  The
+     libraries are git-ignored, so a fresh checkout always builds, and a
+     build that fails RAISES — a stale library left over from other
+     sources is never loaded in its place;
   2. read-only install (no writable dir — the flock file itself cannot
      be created): nobody can be mid-build either, so dlopen the
-     existing .so directly;
-  3. toolchain-free host (make missing/failing): fall back to an
-     existing .so with a warning instead of refusing to open storage.
+     existing .so directly.
 """
 
 from __future__ import annotations
@@ -77,10 +78,8 @@ def ensure_built(native_dir: str, lib_path: str, target: str | None = None,
         try:
             subprocess.run(cmd, check=True, timeout=timeout,
                            capture_output=True)
-        except (OSError, subprocess.SubprocessError) as exc:
-            if os.path.exists(path):
-                LOG.warning("native build failed (%s); falling back to "
-                            "existing %s", exc, path)
-                return path
-            raise
+        except subprocess.CalledProcessError as exc:
+            raise RuntimeError(
+                f"native build failed ({' '.join(cmd)}): "
+                f"{exc.stderr.decode(errors='replace')[-2000:]}") from exc
     return path
