@@ -1,0 +1,74 @@
+"""The manifest's self-check, and that the benchmark grows by adding files."""
+
+import json
+import os
+
+import pytest
+from bench_helpers import REPO, TINY_CELL, extended_copy
+
+from benchmark import check_manifest
+from benchmark.check_manifest import ManifestError
+
+
+def test_the_committed_manifest_passes():
+    bm = check_manifest.check(REPO)
+    assert bm["command"][:3] == ["python3", "-m", "benchmark.run"]
+    for w in bm["workloads"]:
+        names = {m["name"] for m in check_manifest.metrics_of(
+            bm, w["name"], "end_to_end")}
+        assert {"setup_s", "ops_per_s"} <= names
+        assert check_manifest.metrics_of(bm, w["name"], "per_layer")
+
+
+def test_a_cell_config_mix_and_metric_are_added_as_files_only(tmp_path):
+    bm = check_manifest.check(extended_copy(str(tmp_path)))
+    cell, cfg, mix = check_manifest.cell(bm, TINY_CELL)
+    assert (cfg["regions"], mix["loop"]["clients"]) == (8, 16)
+    layer = {m["name"]: m for m in check_manifest.metrics_of(
+        bm, TINY_CELL, "per_layer")}
+    assert layer["srv_propose_ms"]["_reader"]["span"] == "srv_propose"
+    assert "raft_tick_roofline" in layer    # no workloads key: every cell
+
+
+def _edit(root, rel, fn):
+    path = os.path.join(root, rel)
+    with open(path) as f:
+        data = json.load(f)
+    fn(data)
+    with open(path, "w") as f:
+        json.dump(data, f)
+
+
+@pytest.mark.parametrize("rel, edit, says", [
+    # PR 22's refusal: a source that is not 1 to 200 printable characters
+    ("BENCHMARK.json",
+     lambda bm: bm["configs"][0].update(source="x" * 201), "source"),
+    ("BENCHMARK.json",
+     lambda bm: bm["configs"][0].update(source="3 × 1,024"), "ASCII"),
+    ("benchmark/configs/kv3x8.json",
+     lambda c: c.update(source="tab\there"), "source"),
+    ("BENCHMARK.json",
+     lambda bm: bm["workloads"][0].update(name="has space"), "not a name"),
+    ("BENCHMARK.json",
+     lambda bm: bm["end_to_end"][0].update(unit="ops per second"), "unit"),
+    ("BENCHMARK.json",
+     lambda bm: bm["per_layer"][0].update(moves="tick_host_ms"),
+     "no end-to-end metric"),
+    ("BENCHMARK.json",
+     lambda bm: bm["workloads"][0].update(traffic="ycsb_z"), "no such file"),
+    ("BENCHMARK.json",
+     lambda bm: bm["per_layer"][0].update(why="because"), "unknown keys"),
+    ("BENCHMARK.json",
+     lambda bm: bm["end_to_end"][0].update(bound=0.4), "bound"),
+    ("benchmark/traffic/ycsb_a16.json",
+     lambda t: t.update(think_time_ms=5), "unknown keys"),
+    ("benchmark/traffic/ycsb_a16.json",
+     lambda t: t.update(read_share=0.7), "sum to 1"),
+    ("benchmark/layer_metrics/srv_propose_ms.json",
+     lambda m: m["reader"].update(kind="regex"), "reader.kind"),
+])
+def test_the_check_refuses(tmp_path, rel, edit, says):
+    root = extended_copy(str(tmp_path))
+    _edit(root, rel, edit)
+    with pytest.raises(ManifestError, match=says):
+        check_manifest.check(root)
