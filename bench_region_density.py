@@ -263,12 +263,6 @@ async def run_config(args) -> dict:
         TRACER.configure(enabled=True, sample_rate=args.trace_sample,
                          seed=0)
 
-    if args.profile_ticks > 0:
-        # device-tick profiling window on the first store's engine:
-        # each of the next N ticks records build/device/apply phase
-        # spans, exported below as a perfetto tick timeline
-        engines[0].profile_ticks(args.profile_ticks)
-
     stop_at = time.monotonic() + args.duration
 
     async def worker(wid: int) -> None:
@@ -445,13 +439,6 @@ async def run_config(args) -> dict:
             # any window-sampled ops still in the ring)
             res["trace_file"] = args.trace
             res["trace_spans"] = TRACER.export_chrome(args.trace)
-    if args.profile_ticks > 0:
-        # tick timeline: the N-tick window as a perfetto-loadable
-        # export (root tick span + build/device/apply phase spans)
-        out = args.profile_ticks_out or os.path.join(
-            args.dir, "tick_timeline.json")
-        res["tick_timeline_file"] = out
-        res["tick_timeline_spans"] = engines[0].export_tick_timeline(out)
     print("RESULT " + json.dumps(res), flush=True)
     os._exit(0)  # 3R region engines: teardown is not the measurement
 
@@ -617,13 +604,6 @@ def main() -> None:
                     help="disable the write plane (store-wide append "
                          "rounds, eager commits, ack-at-commit) — the "
                          "unbatched A/B comparator")
-    ap.add_argument("--profile-ticks", type=int, default=0,
-                    help="arm an N-tick device profiling window on the "
-                         "first store's engine; exports a perfetto "
-                         "tick timeline (build/device/apply phases)")
-    ap.add_argument("--profile-ticks-out", default="",
-                    help="tick timeline output path (default: "
-                         "<workdir>/tick_timeline.json)")
     ap.add_argument("--json-out", default="BENCH_REGIONS.json")
     ap.add_argument("--config", action="store_true",
                     help="internal: run one config in this process")
@@ -669,11 +649,6 @@ def main() -> None:
         cmd.append("--no-write-batch")
     if args.lifecycle_pd:
         cmd.append("--lifecycle-pd")
-    if args.profile_ticks > 0:
-        cmd += ["--profile-ticks", str(args.profile_ticks)]
-        if args.profile_ticks_out:
-            cmd += ["--profile-ticks-out",
-                    os.path.abspath(args.profile_ticks_out)]
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     t0 = time.monotonic()
     p = subprocess.Popen(cmd, stdout=subprocess.PIPE, env=env)

@@ -532,16 +532,155 @@ def _numpy_engine(g: int = 8):
                                        backend="numpy"))
 
 
-def test_tick_phase_histograms_count_ticks():
-    e = _numpy_engine()
+PER_TICK_HISTS = {"tick_total_ms", "tick_build_ms", "tick_device_ms",
+                  "tick_apply_ms", "tick_state_ms", "tick_call_ms",
+                  "tick_fetch_ms", "tick_heartbeat_ms"}
+
+
+async def _engine(backend: str, g: int = 8):
+    """numpy: the twin, no loop needed.  jax: the jitted tick on CPU
+    JAX, which ``start`` compiles (and then its loop is stopped)."""
+    if backend == "numpy":
+        return _numpy_engine(g)
+    from tpuraft.core.engine import MultiRaftEngine
+    from tpuraft.options import TickOptions
+
+    e = MultiRaftEngine(TickOptions(max_groups=g, max_peers=4,
+                                    backend="jax"))
+    from tpuraft.util.metrics import Histogram
+
+    await e.start()
+    await e.shutdown()
+    e.tick_hists = {k: Histogram() for k in e.tick_hists}   # past warm-up
+    return e
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+async def test_tick_phase_histograms_count_ticks(backend):
+    e = await _engine(backend)
     for _ in range(5):
         e.tick_once()
     hists = e.tick_histograms()
-    assert set(hists) == {"tick_total_ms", "tick_build_ms",
-                          "tick_device_ms", "tick_apply_ms"}
-    assert all(h["count"] == 5 for h in hists.values())
+    assert set(hists) == PER_TICK_HISTS | {"tick_late_ms",
+                                           "fence_resolve_ms"}
+    assert all(hists[k]["count"] == 5 for k in PER_TICK_HISTS)
+    # the loop's lateness counts the loop's own ticks, a fence's wait
+    # counts fences: tick_once by hand gives neither
+    assert hists["tick_late_ms"]["count"] == 0
+    assert hists["fence_resolve_ms"]["count"] == 0
     assert hists["tick_total_ms"]["p99"] >= 0.0
     assert "tick_p99_ms" in e.describe()
+    # the device phase in its three parts, off the same clock reads
+    h = e.tick_hists
+    parts = h["tick_state_ms"].total + h["tick_call_ms"].total \
+        + h["tick_fetch_ms"].total
+    assert parts <= h["tick_device_ms"].total * (1 + 1e-9)
+    assert parts == pytest.approx(h["tick_device_ms"].total)
+    if backend == "numpy":
+        assert h["tick_state_ms"].total == 0.0    # the twin builds none
+    else:
+        assert min(h[k].total for k in ("tick_state_ms", "tick_call_ms",
+                                        "tick_fetch_ms")) > 0.0
+    assert h["tick_heartbeat_ms"].total == 0.0     # no leader, no beat
+    assert h["tick_heartbeat_ms"].total <= h["tick_apply_ms"].total
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+async def test_tick_sections_nest_under_the_one_tracer(backend):
+    from tpuraft.util.trace import TRACER
+
+    e = await _engine(backend)
+    TRACER.configure(enabled=True)
+    try:
+        for _ in range(4):
+            e.tick_once()
+        table = TRACER.section_table()
+    finally:
+        TRACER.configure(enabled=False)
+        TRACER.reset()
+    assert set(table) == {"tick.build", "tick.call", "tick.fetch",
+                          "tick.apply"}
+    assert all(calls == 4 for calls, _b, _s in table.values())
+    # the sections tile the tick: their seconds make up tick_total_ms
+    tiled = sum(busy for _c, busy, _s in table.values())
+    assert tiled == pytest.approx(e.tick_hists["tick_total_ms"].total / 1e3,
+                                  rel=0.05)
+    # tick.build holds the GroupState build, which tick_build_ms leaves
+    # to the device phase
+    assert table["tick.build"][1] >= \
+        e.tick_hists["tick_build_ms"].total / 1e3
+
+
+@pytest.mark.parametrize("wake", ["timed", "dirty"])
+async def test_tick_late_ms_counts_both_wake_kinds(wake):
+    """A wake is late by what the loop took past the time it was due:
+    the timeout of a timed wait, the ``mark_dirty`` of a dirty one."""
+    import time
+
+    e = _numpy_engine()
+    loop = asyncio.get_running_loop()
+
+    def hog():                  # the loop thread, busy with other work
+        if wake == "dirty":
+            e.mark_dirty()
+        time.sleep(0.05)
+
+    loop.call_later(0.005, hog)
+    await e._wait_dirty(0.010 if wake == "timed" else 5.0)
+    # due 5 ms into a 50 ms hog (timed) or at its very start (dirty)
+    assert 0.030 <= e._late_s <= 0.2
+    # a mark that is already there wakes nothing and is late by nothing
+    e._late_s = 0.0
+    await e._wait_dirty(5.0 if wake == "dirty" else 0.0)
+    if wake == "dirty":
+        assert e._late_s == 0.0
+    # the loop books the lateness once per tick it runs
+    e.opts.tick_interval_ms = 5
+    e._task = asyncio.ensure_future(e._loop())
+    try:
+        for _ in range(100):
+            e.mark_dirty()
+            await asyncio.sleep(0.005)
+            if e.tick_hists["tick_late_ms"].count >= 3:
+                break
+    finally:
+        await e.shutdown()
+    late = e.tick_hists["tick_late_ms"]
+    assert 3 <= late.count <= e.ticks
+    assert late.total >= 0.0
+
+
+class _Fence:
+    done = False
+
+    def __init__(self) -> None:
+        self.confirmed = 0
+
+    def note_quorum(self) -> None:
+        self.confirmed += 1
+        self.done = True
+
+
+@pytest.mark.parametrize("tracing", [False, True])
+def test_fence_resolve_ms_fills_only_while_tracing(tracing):
+    from tpuraft.util.trace import TRACER
+
+    e = _numpy_engine()
+    waited = e.tick_hists["fence_resolve_ms"]
+    TRACER.configure(enabled=tracing)
+    try:
+        fences = [_Fence(), _Fence()]
+        for f in fences:
+            e.arm_read_fence(2, f)
+        e.tick_q_ack[2] = e.now_ms() + 1     # a quorum acked since
+        e._resolve_fences(2)
+    finally:
+        TRACER.configure(enabled=False)
+        TRACER.reset()
+    assert [f.confirmed for f in fences] == [1, 1]
+    assert e.fence_lane_resolves == 2
+    assert waited.count == (2 if tracing else 0)
+    assert waited.total >= 0.0
 
 
 def test_lane_stats_matches_engine_arrays():
@@ -564,30 +703,6 @@ def test_lane_stats_matches_engine_arrays():
     assert ls["quiescent"] == 3
     assert ls["hibernation_fraction"] == pytest.approx(3 / 8)
     assert ls["q_ack_age_ms_p99"] >= 0.0
-
-
-def test_profile_ticks_window_exports_perfetto_timeline(tmp_path):
-    e = _numpy_engine()
-    out = tmp_path / "ticks.json"
-    assert e.export_tick_timeline(str(out)) == 0   # nothing armed
-    e.profile_ticks(3)
-    for _ in range(5):                              # window is 3 ticks
-        e.tick_once()
-    n = e.export_tick_timeline(str(out))
-    assert n == 3 * 4   # root + build/device/apply per tick
-    doc = json.loads(out.read_text())
-    evs = [ev for ev in doc["traceEvents"] if ev["ph"] == "X"]
-    names = {ev["name"] for ev in evs}
-    assert names == {"tick", "tick_build", "tick_device", "tick_apply"}
-    roots = [ev for ev in evs if ev["name"] == "tick"]
-    assert [r["args"]["seq"] for r in roots] == [1, 2, 3]
-    # phase spans nest inside their tick span
-    t0 = min(ev["ts"] for ev in evs)
-    root0 = min(roots, key=lambda r: r["ts"])
-    assert root0["ts"] == t0
-    # disarmed after the window: later ticks record nothing more
-    e.tick_once()
-    assert e.export_tick_timeline(str(out)) == 3 * 4
 
 
 async def test_tick_occupancy_matches_quiescent_count(tmp_path):

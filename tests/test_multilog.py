@@ -471,3 +471,65 @@ async def test_cluster_on_shared_log_engine(tmp_path):
             assert eng.sync_count <= eng.append_count
     finally:
         await c.stop_all()
+
+
+@pytest.mark.parametrize("path", ["inline", "round"])
+async def test_flush_returns_the_fsync_interval_read_in_its_thread(
+        tmp_path, path):
+    """(t0, t1, off_loop) around the fsync itself.  The inline path runs
+    it on the caller's loop; a round runs it in an executor thread and
+    hands every waiter of the round the same interval, which ends before
+    any of them resumes."""
+    import threading
+    import time
+
+    stores = [mk_storage(tmp_path, f"iv{k}") for k in range(8)]
+    for s in stores:
+        s.init()
+    try:
+        eng = stores[0].engine
+        gc = eng.group_commit
+        sync_threads = []
+        real_sync = eng.sync
+
+        def spying_sync():
+            sync_threads.append(threading.get_ident())
+            time.sleep(0.002)
+            real_sync()
+
+        eng.sync = spying_sync
+        if path == "inline":
+            # idle, fast disk, nobody waiting: the fsync is taken inline
+            gc._cost_ewma = 0.0
+            gc._last_sync = 0.0
+            before = time.perf_counter()
+            got = [await stores[0].append_entries_async(
+                mk_entries(1, 2, term=1), sync=True)]
+            resumed = [time.perf_counter()]
+            assert sync_threads == [threading.get_ident()]
+        else:
+            gc._cost_ewma = 1.0          # the inline path is banned
+            before = time.perf_counter()
+            resumed = []
+
+            async def one(k):
+                iv = await stores[k].append_entries_async(
+                    mk_entries(1, 2, term=1), sync=True)
+                resumed.append(time.perf_counter())
+                return iv
+
+            got = await asyncio.gather(*(one(k) for k in range(8)))
+            assert threading.get_ident() not in sync_threads
+            # the eight joined far fewer rounds, each round one interval
+            assert len(set(got)) == len(sync_threads) < 8
+        for t0, t1, off_loop in got:
+            assert off_loop is (path == "round")
+            assert before <= t0 and t1 - t0 >= 0.002
+            assert t1 <= min(resumed)
+        # nothing to sync, nothing to time
+        assert await stores[0].append_entries_async([], sync=True) is None
+        assert await stores[0].append_entries_async(
+            mk_entries(3, 1, term=1), sync=False) is None
+    finally:
+        for s in stores:
+            s.shutdown()

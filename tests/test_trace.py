@@ -25,6 +25,7 @@ from tpuraft.rpc.messages import (
     encode_message,
 )
 from tpuraft.util.trace import (
+    ANCHOR_EVENT,
     RECORDER,
     TRACER,
     FlightRecorder,
@@ -272,7 +273,9 @@ def test_chrome_export_schema(tmp_path):
     assert isinstance(evs, list)
     x = [e for e in evs if e["ph"] == "X"]
     metas = [e for e in evs if e["ph"] == "M"]
-    assert len(x) == 2 and len(metas) == 2   # two procs named
+    # the clock anchor leads, then the two procs are named
+    assert len(x) == 2 and len(metas) == 3
+    assert metas[0]["name"] == ANCHOR_EVENT
     for e in x:
         for key in ("name", "ph", "ts", "dur", "pid", "tid", "args"):
             assert key in e
@@ -281,6 +284,239 @@ def test_chrome_export_schema(tmp_path):
     # the two spans of one op share a tid row, on different pid rows
     assert x[0]["tid"] == x[1]["tid"]
     assert x[0]["pid"] != x[1]["pid"]
+
+
+# ---------------------------------------------------------------------------
+# loop sections: what the loop thread runs
+# ---------------------------------------------------------------------------
+
+
+class _Clock:
+    """A scripted perf_counter / thread_time pair for the tracer."""
+
+    def __init__(self, t: float = 100.0) -> None:
+        self.t = t
+        self.cpu = 0.0
+
+    def run(self, seconds: float, cpu_share: float = 1.0) -> None:
+        self.t += seconds
+        self.cpu += seconds * cpu_share
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    from tpuraft.util import trace
+
+    c = _Clock()
+    monkeypatch.setattr(trace, "_pc", lambda: c.t)
+    monkeypatch.setattr(trace, "_thread_time", lambda: c.cpu)
+    return c
+
+
+def _section(t: Tracer, name: str):
+    """The call sites' pattern: one attribute test while tracing is off."""
+    return t.enter(name) if t.enabled else None
+
+
+@pytest.mark.parametrize("children", [
+    [],                                        # a leaf: self == inclusive
+    [("raft.heartbeat", 0.25)],                # one child
+    [("raft.heartbeat", 0.25), ("raft.ack", 0.125)],   # two siblings
+    [("tick.apply", 0.5)],                     # the same name nested
+])
+def test_nested_sections_self_is_inclusive_minus_children(clock, children):
+    t = Tracer().configure(enabled=True)
+    outer = _section(t, "tick.apply")
+    clock.run(0.1)
+    for name, dur in children:
+        sec = _section(t, name)
+        clock.run(dur)
+        t.leave(sec)
+    clock.run(0.2)
+    t.leave(outer)
+    table = t.section_table()
+    in_children = sum(d for _n, d in children)
+    own = [d for n, d in children if n == "tick.apply"]
+    calls, busy, self_s = table["tick.apply"]
+    assert calls == 1 + len(own)
+    # inclusive seconds count a nested entry of the same name twice;
+    # self seconds never do
+    assert busy == pytest.approx(0.3 + in_children + sum(own))
+    assert self_s == pytest.approx(0.3 + sum(own))
+    for name, dur in children:
+        if name != "tick.apply":
+            assert table[name] == (1, pytest.approx(dur),
+                                   pytest.approx(dur))
+    assert sum(v[2] for v in table.values()) \
+        == pytest.approx(0.3 + in_children)
+
+
+def test_disabled_tracer_opens_no_section_and_records_nothing():
+    t = Tracer()
+    assert not t.enabled
+    for _ in range(3):
+        assert _section(t, "kv.batch") is None
+    assert t.section_table() == {}
+    assert t._sec_stack == []
+    assert t.spans() == []
+    assert not any(k.startswith("trace_section") for k in t.counters())
+
+
+def test_rollups_sum_to_self_seconds_within_one_bucket(clock):
+    t = Tracer().configure(enabled=True, ring=4096)
+    u = 1 / 256                 # binary fractions: the sums are exact
+    want = {"kv.batch": 0.0, "raft.propose": 0.0, "raft.ack": 0.0}
+    # 10.3 s of work in steps of 9 u: 4 u of kv.batch holding 1 u of
+    # raft.propose, 2 u of raft.ack, 3 u outside any section of which
+    # 2 u are spent in the selector (no CPU)
+    for _ in range(293):
+        a = _section(t, "kv.batch")
+        clock.run(2 * u)
+        b = _section(t, "raft.propose")
+        clock.run(u)
+        t.leave(b)
+        clock.run(u)
+        t.leave(a)
+        c = _section(t, "raft.ack")
+        clock.run(2 * u)
+        t.leave(c)
+        clock.run(u)
+        clock.run(2 * u, cpu_share=0.0)
+        want["kv.batch"] += 3 * u
+        want["raft.propose"] += u
+        want["raft.ack"] += 2 * u
+    spans = t.spans()
+    assert {s["proc"] for s in spans} == {"loop"}
+    by_name: dict = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    assert set(by_name) == {"loop.kv.batch", "loop.raft.propose",
+                            "loop.raft.ack", "loop.kv", "loop.raft",
+                            "loop.cpu"}
+    # ten whole buckets, a second apart; the last partial one is dropped
+    assert {len(rows) for rows in by_name.values()} == {10}
+    starts = [s["ts_s"] for s in by_name["loop.cpu"]]
+    assert [b - a for a, b in zip(starts, starts[1:])] == [1.0] * 9
+    for name, total in want.items():
+        rolled = sum(s["dur_s"] for s in by_name["loop." + name])
+        in_one_bucket = total / 10.3
+        assert total - in_one_bucket <= rolled <= total
+    for k in range(10):
+        # a layer's share is the sum of its sections' self seconds
+        assert by_name["loop.raft"][k]["dur_s"] \
+            == by_name["loop.raft.propose"][k]["dur_s"] \
+            + by_name["loop.raft.ack"][k]["dur_s"]
+        assert by_name["loop.kv"][k]["dur_s"] \
+            == by_name["loop.kv.batch"][k]["dur_s"]
+        # 7 u of 9 burn CPU; the sections cover 6 u of 9 (a bucket
+        # closes at the first exit past its second: a step's slack)
+        cpu = by_name["loop.cpu"][k]
+        assert cpu["dur_s"] == pytest.approx(7 / 9, abs=9 * u)
+        assert cpu["args"]["busy_s"] == pytest.approx(6 / 9, abs=9 * u)
+        assert cpu["args"]["busy_s"] == sum(
+            by_name[n][k]["dur_s"] for n in ("loop.kv", "loop.raft"))
+    # in the perfetto export every roll-up name has a row of its own
+    rows = {e["args"]["name"] for e in t.chrome_events()
+            if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert rows == set(by_name)
+
+
+def test_a_second_with_no_section_exit_shares_the_delta(clock):
+    t = Tracer().configure(enabled=True)
+    sec = _section(t, "fsm.apply")
+    clock.run(3.5)                       # one stretch over three seconds
+    t.leave(sec)
+    rows = [s for s in t.spans() if s["name"] == "loop.fsm.apply"]
+    assert len(rows) == 3
+    assert sum(s["dur_s"] for s in rows) == pytest.approx(3.5)
+    assert sum(s["args"]["n"] for s in rows) == pytest.approx(1)
+
+
+def test_anchor_pair_is_in_chrome_events():
+    import time
+
+    before = time.perf_counter_ns(), time.time_ns()
+    t = Tracer().configure(enabled=True)
+    t.leave(_section(t, "kv.batch"))     # the annotation re-reads it
+    after = time.perf_counter_ns(), time.time_ns()
+    first = t.chrome_events()[0]
+    assert first["ph"] == "M" and first["name"] == ANCHOR_EVENT
+    assert first["args"] == {"perf_counter_ns": t.anchor[0],
+                             "time_ns": t.anchor[1]}
+    assert before[0] <= t.anchor[0] <= after[0]
+    assert before[1] <= t.anchor[1] <= after[1]
+
+
+def test_sections_accumulate_with_jax_unimportable(monkeypatch, clock):
+    import sys
+
+    # None in sys.modules makes ``import jax...`` raise ImportError
+    for mod in ("jax", "jax.profiler"):
+        monkeypatch.setitem(sys.modules, mod, None)
+    t = Tracer().configure(enabled=True)
+    for _ in range(3):
+        sec = _section(t, "fsm.apply")
+        assert sec is not None and sec[1] is None    # no annotation
+        clock.run(0.4)
+        t.leave(sec)
+    assert t._annotation is False
+    assert t.section_table()["fsm.apply"] == (3, pytest.approx(1.2),
+                                              pytest.approx(1.2))
+    assert [s["name"] for s in t.spans()] == ["loop.fsm.apply", "loop.fsm",
+                                              "loop.cpu"]
+
+
+@pytest.mark.parametrize("how", ["flag_cleared", "reset", "rearmed"])
+def test_a_section_left_after_tracing_went_off_leaves_cleanly(how):
+    t = Tracer().configure(enabled=True)
+    outer = _section(t, "kv.batch")
+    inner = _section(t, "raft.propose")
+    if how == "flag_cleared":        # how the benchmark disarms
+        t.enabled = False
+    elif how == "reset":
+        t.reset()
+    else:
+        t.configure(enabled=True)
+    t.leave(inner)
+    t.leave(outer)
+    assert t._sec_stack == []
+    assert inner[1] is None and outer[1] is None     # annotations closed
+    if how == "flag_cleared":
+        assert set(t.section_table()) == {"kv.batch", "raft.propose"}
+    else:                            # the accumulators started over
+        assert t.section_table() == {}
+    assert _section(t, "kv.batch") is None or how != "flag_cleared"
+
+
+def test_a_skipped_leave_is_unwound_by_the_enclosing_one(clock):
+    t = Tracer().configure(enabled=True)
+    outer = _section(t, "tick.apply")
+    _section(t, "raft.heartbeat")        # an exception skipped its leave
+    clock.run(0.5)
+    t.leave(outer)
+    assert t._sec_stack == []
+    assert t.section_table()["raft.heartbeat"][0] == 1
+    assert t.section_table()["tick.apply"][2] == pytest.approx(0.0)
+
+
+def test_sections_are_confined_to_the_loop_thread():
+    t = Tracer().configure(enabled=True)
+    t.leave(_section(t, "fsm.apply"))
+    got = []
+    th = threading.Thread(target=lambda: got.append(_section(t, "fsm.apply")))
+    th.start()
+    th.join()
+    assert got == [None]
+    assert t.section_table()["fsm.apply"][0] == 1
+
+
+def test_section_table_rides_the_counters():
+    t = Tracer().configure(enabled=True)
+    t.leave(_section(t, "kv.read_round"))
+    c = t.counters()
+    assert c["trace_section_calls_kv.read_round"] == 1
+    assert c["trace_section_busy_seconds_kv.read_round"] >= 0.0
+    assert c["trace_section_self_seconds_kv.read_round"] >= 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -539,6 +775,19 @@ async def test_traced_put_end_to_end(tmp_path):
                       "srv_propose", "quorum_commit", "log_flush",
                       "fsm_apply", "follower_append"):
             assert stage in names, (stage, names)
+        # every log_flush envelope comes with its two parts: the fsync
+        # in the thread that ran it (here an executor's) and from its
+        # end to the waiter's resumption on the loop
+        for proc in store_procs:
+            flush, fsync, wake = (
+                [s for s in mine if s["proc"] == proc and s["name"] == n]
+                for n in ("log_flush", "log_fsync", "log_wake"))
+            assert len(flush) == len(fsync) == len(wake) >= 1, proc
+            for f, w in zip(fsync, wake):
+                assert w["ts_s"] == pytest.approx(f["ts_s"] + f["dur_s"])
+        # and the loop's sections saw the layers the put went through
+        layers = {name.split(".")[0] for name in TRACER.section_table()}
+        assert {"client", "kv", "raft", "log", "fsm", "rpc"} <= layers
         path = str(tmp_path / "put.json")
         TRACER.export_chrome(path)
         with open(path) as f:
@@ -612,6 +861,33 @@ async def test_metrics_text_and_describe_metrics_rpc():
         assert "tpuraft_kv_batch_rpcs" in remote
         assert f'store="{store.server_id}"' in remote
     finally:
+        await kv.shutdown()
+        await c.stop_all()
+
+
+async def test_metrics_text_renders_the_section_table():
+    """Loop share by layer for an operator's Prometheus, no profiler:
+    calls, busy and self seconds per section, as counters."""
+    c, kv = await _kv_cluster()
+    try:
+        TRACER.configure(enabled=True, sample_rate=0.0, seed=0)
+        assert await kv.put(b"k", b"v")
+        assert await kv.get(b"k") == b"v"
+        TRACER.enabled = False
+        store = next(iter(c.stores.values()))
+        store.opts.metrics_cache_ttl_ms = 0
+        text = store.metrics_text()
+        for section in ("kv_batch", "raft_propose", "log_stage",
+                        "fsm_apply", "rpc_inproc", "client_send"):
+            for what in ("calls", "busy_seconds", "self_seconds"):
+                name = f"tpuraft_trace_section_{what}_{section}"
+                assert f"# TYPE {name} counter" in text, name
+        calls = [ln for ln in text.splitlines()
+                 if ln.startswith("tpuraft_trace_section_calls_kv_batch{")]
+        assert len(calls) == 1 and float(calls[0].rsplit(" ", 1)[1]) >= 2
+    finally:
+        TRACER.configure(enabled=False)
+        TRACER.reset()
         await kv.shutdown()
         await c.stop_all()
 

@@ -49,6 +49,7 @@ from tpuraft.rpc.messages import ErrorResponse, StoreAppendRequest
 from tpuraft.rpc.transport import RpcError, is_no_method
 from tpuraft.util import clock as clockmod
 from tpuraft.util.metrics import MetricRegistry
+from tpuraft.util.trace import TRACER as _TRACE
 
 LOG = logging.getLogger(__name__)
 
@@ -198,6 +199,7 @@ class AppendBatcher:
         rows: list = []
         routes: list = []           # (replicator, frame count)
         timeout_ms = 0.0
+        sec = _TRACE.enter("raft.replicate") if _TRACE.enabled else None
         for rep, reqs, tmo in batch:
             rows.extend(reqs)
             routes.append((rep, len(reqs)))
@@ -208,11 +210,13 @@ class AppendBatcher:
         self.rounds += 1
         self.rows += len(rows)
         self.entries += sum(len(r.entries) for r in rows)
+        request = StoreAppendRequest(rows=rows)
+        if sec is not None:
+            _TRACE.leave(sec)
         t0 = self.clock.monotonic()
         try:
             resp = await transport.call(
-                dst, "store_append", StoreAppendRequest(rows=rows),
-                timeout_ms=timeout_ms)
+                dst, "store_append", request, timeout_ms=timeout_ms)
         except asyncio.CancelledError:
             # shutdown mid-RPC: nothing was dispatched yet — fail the
             # whole batch so no replicator stays _pending forever

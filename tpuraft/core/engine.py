@@ -56,6 +56,7 @@ from tpuraft.entity import PeerId
 from tpuraft.options import TickOptions
 from tpuraft.util import clock as clockmod
 from tpuraft.util.trace import RECORDER as _RECORDER
+from tpuraft.util.trace import TRACER as _TRACE
 from tpuraft.ops.ballot import NEG_INF_I32 as _NEG_I32
 from tpuraft.ops.tick import (
     ROLE_CANDIDATE,
@@ -825,6 +826,11 @@ class MultiRaftEngine:
         self._free = list(range(g - 1, -1, -1))
         self._dirty = False
         self._dirty_event = asyncio.Event()
+        # perf_counter of the mark_dirty that set the event: a dirty
+        # wake's lateness counts from here (tick_late_ms)
+        self._dirty_at = 0.0
+        # how late the loop resumed the tick task from its last wait
+        self._late_s = 0.0
         self._task: Optional[asyncio.Task] = None
         self._stopped = False
         self._tick_fn = None  # jitted raft_tick outputs (None => numpy path)
@@ -839,24 +845,38 @@ class MultiRaftEngine:
         self.eager_commits = 0
         # device-tick profiling (fleet observability): per-tick wall
         # time attributed to the three phases every tick pays — host
-        # state build, device dispatch (jit call + output transfer, or
-        # the numpy twin), host apply (commit callbacks + protocol
-        # scheduling).  Always on: four locked histogram updates per
-        # TICK (not per op) — ticks are paced by their own cost, so
-        # this stays noise even at max cadence.
+        # mirror upkeep and the relative views (build), the device
+        # phase (GroupState build + jit call + wait and download of the
+        # outputs, or the numpy twin), host apply (commit callbacks +
+        # protocol scheduling).  Always on: locked histogram updates
+        # per TICK (not per op) — ticks are paced by their own cost, so
+        # this stays noise even at max cadence.  The benchmark bounds
+        # what it costs end to end (BENCHMARK.json, PERF.md).
         from tpuraft.util.metrics import Histogram
         self.tick_hists = {
             "tick_total_ms": Histogram(),
             "tick_build_ms": Histogram(),
             "tick_device_ms": Histogram(),
             "tick_apply_ms": Histogram(),
+            # tick_device_ms in its three parts (they sum to it: the
+            # same clock reads): GroupState build from the mirrors; the
+            # jitted call (argument transfer + enqueue; the whole numpy
+            # twin on that backend); wait for the device + download
+            "tick_state_ms": Histogram(),
+            "tick_call_ms": Histogram(),
+            "tick_fetch_ms": Histogram(),
+            # _flush_heartbeats inside tick_apply_ms (0 on a tick with
+            # no beat due)
+            "tick_heartbeat_ms": Histogram(),
+            # how late the event loop resumed the tick task from the
+            # wait before this tick: past the time it asked for (timed
+            # wake) or past the mark_dirty that woke it
+            "tick_late_ms": Histogram(),
+            # per resolved device read fence, arm to the tick that
+            # resolved it; per waiter, so only while tracing is on
+            "fence_resolve_ms": Histogram(),
         }
-        # --profile-ticks window: a dedicated Tracer capturing one span
-        # per tick phase for the next N ticks (perfetto timeline export
-        # through the trace plane's exporter); None = disarmed (the
-        # hot-path cost is one attribute test per tick)
-        self._tick_tracer = None
-        self._tick_prof_left = 0
+        self._hb_flush_s = 0.0
         # protocol params: [G] rows — each registered node's NodeOptions
         # timeouts apply to ITS groups only (mixed-timeout engines, e.g.
         # a PD group + region groups in one process, run correct
@@ -1235,7 +1255,9 @@ class MultiRaftEngine:
 
     def mark_dirty(self) -> None:
         self._dirty = True
-        self._dirty_event.set()
+        if not self._dirty_event.is_set():
+            self._dirty_at = time.perf_counter()
+            self._dirty_event.set()
 
     # -- device read-fence plane (ReadConfirmBatcher rounds) -----------------
 
@@ -1277,10 +1299,15 @@ class MultiRaftEngine:
             return
         qa = int(self.tick_q_ack[s])
         keep = []
+        waited = self.tick_hists["fence_resolve_ms"] if _TRACE.enabled \
+            else None
+        now = self.now_ms() if waited is not None else 0
         for start, fence in waiters:
             if start <= qa:
                 self.fence_lane_resolves += 1
                 fence.note_quorum()
+                if waited is not None:
+                    waited.update(float(now - start))
             elif not fence.done:
                 keep.append((start, fence))
         if keep:
@@ -1408,54 +1435,6 @@ class MultiRaftEngine:
             stats["q_ack_age_ms_p99"] = 0.0
             stats["q_ack_age_ms_max"] = 0.0
         return stats
-
-    def profile_ticks(self, n: int) -> None:
-        """Arm a profiling window: the next ``n`` ticks each record a
-        root span + build/device/apply phase spans into a dedicated
-        tracer (sample_rate=1, no slow trigger), exportable as a
-        perfetto timeline via :meth:`export_tick_timeline`.  Disarmed
-        (the steady state) the tick pays one attribute test."""
-        from tpuraft.util.trace import Tracer
-
-        if n <= 0:
-            self._tick_tracer = None
-            self._tick_prof_left = 0
-            return
-        self._tick_tracer = Tracer().configure(
-            enabled=True, sample_rate=1.0, seed=0,
-            ring=max(4096, 4 * n + 8), slow_trigger=False)
-        self._tick_prof_left = n
-
-    def _profile_tick(self, t0: float, t1: float, t2: float, t3: float,
-                      advanced: int) -> None:
-        # direct-emit path (odd tid = "record unconditionally"): the
-        # spans carry their own measured [t0,t1] intervals, so staging
-        # through begin_op/end_op would mis-stamp the root.  One tid
-        # for the whole window keeps every tick on one perfetto track,
-        # with the phase spans nesting inside each tick span.
-        tr = self._tick_tracer
-        tid = 1
-        tr.span(tid, "tick", t0, t3, proc="engine", seq=self.ticks,
-                advanced=advanced,
-                groups=int(self.has_ctrl.sum()),
-                quiescent=int((self.quiescent & self.has_ctrl).sum()))
-        tr.span(tid, "tick_build", t0, t1, proc="engine")
-        tr.span(tid, "tick_device", t1, t2, proc="engine")
-        tr.span(tid, "tick_apply", t2, t3, proc="engine")
-        self._tick_prof_left -= 1
-        if self._tick_prof_left <= 0:
-            self._tick_prof_left = 0
-            # keep the tracer for export; stop recording
-            self._tick_tracer, self._tick_trace_done = None, tr
-
-    def export_tick_timeline(self, path: str) -> int:
-        """Write the captured (or in-flight) --profile-ticks window as
-        perfetto-loadable chrome trace JSON; returns the span count
-        (0 = no window was armed)."""
-        tr = self._tick_tracer or getattr(self, "_tick_trace_done", None)
-        if tr is None:
-            return 0
-        return tr.export_chrome(path)
 
     # -- tick loop -----------------------------------------------------------
 
@@ -1603,11 +1582,18 @@ class MultiRaftEngine:
         max_idle_s = self.opts.tick_interval_ms / 1000.0
         min_pace_s = self.opts.min_tick_interval_ms / 1000.0
         while not self._stopped:
+            # the [G]-row deadline scan every wake pays: the tick
+            # layer's host work too, on the loop's clock
+            sec = _TRACE.enter("tick.build") if _TRACE.enabled else None
             now = self.now_ms()
             due = self._next_deadline() <= now
+            if sec is not None:
+                _TRACE.leave(sec)
             if self._dirty or due:
                 self._dirty_event.clear()
                 self._dirty = False
+                self.tick_hists["tick_late_ms"].update(self._late_s * 1e3)
+                self._late_s = 0.0
                 t0 = time.perf_counter()
                 advanced = 0
                 try:
@@ -1634,23 +1620,36 @@ class MultiRaftEngine:
                     # added ~1.5ms to the low-load commit-ack path.
                     # Debounce briefly (bounds tick spin under dirty
                     # storms), then let a dirty mark cut the remainder.
-                    await asyncio.sleep(min(pace, 0.0003))
-                    try:
-                        await asyncio.wait_for(self._dirty_event.wait(),
-                                               pace)
-                    except asyncio.TimeoutError:
-                        pass
+                    await self._sleep(min(pace, 0.0003))
+                    await self._wait_dirty(pace)
                 else:
-                    await asyncio.sleep(pace)
+                    await self._sleep(pace)
                 continue
             wait = min(max_idle_s,
                        max(0.0, (self._next_deadline() - now) / 1000.0))
             if self._dirty:
                 continue
-            try:
-                await asyncio.wait_for(self._dirty_event.wait(), wait)
-            except asyncio.TimeoutError:
-                pass
+            await self._wait_dirty(wait)
+
+    async def _sleep(self, seconds: float) -> None:
+        """A timed wake: late by what the loop took past ``seconds``."""
+        t = time.perf_counter()
+        await asyncio.sleep(seconds)
+        self._late_s = max(0.0, time.perf_counter() - t - seconds)
+
+    async def _wait_dirty(self, timeout_s: float) -> None:
+        """Wait for a dirty mark or ``timeout_s``.  A dirty wake is late
+        from the ``mark_dirty`` that set the event, a timed one from its
+        timeout; a mark that was already there wakes nothing."""
+        if self._dirty_event.is_set():
+            return
+        t = time.perf_counter()
+        try:
+            await asyncio.wait_for(self._dirty_event.wait(), timeout_s)
+            due = self._dirty_at
+        except asyncio.TimeoutError:
+            due = t + timeout_s
+        self._late_s = max(0.0, time.perf_counter() - due)
 
     # -- the tick ------------------------------------------------------------
 
@@ -1667,39 +1666,69 @@ class MultiRaftEngine:
         """One batched device tick for all groups: commit advancement,
         election/heartbeat scheduling, lease & step-down.  Returns the
         number of groups whose commit advanced."""
-        t0 = time.perf_counter()
-        now = self.now_ms()
-        self._maybe_time_rebase(now)
-        now = self.now_ms()
-        self._rebase()
-        # the leader's own slot counts as acked *now* (tick.py contract)
-        lead_rows = np.nonzero((self.role == ROLE_LEADER)
-                               & (self.self_col >= 0))[0]
-        if lead_rows.size:
-            self.last_ack[lead_rows, self.self_col[lead_rows]] = now
-        rel, commit_rel_now = self._rel_views()
+        pc = time.perf_counter
+        t0 = pc()
+        # one loop section open at a time, switched at the clock reads
+        # the histograms take: tick.build | tick.call | tick.fetch |
+        # tick.apply (None while tracing is off)
+        sec = _TRACE.enter("tick.build", t0) if _TRACE.enabled else None
+        try:
+            now = self.now_ms()
+            self._maybe_time_rebase(now)
+            now = self.now_ms()
+            self._rebase()
+            # the leader's own slot counts as acked *now* (tick.py
+            # contract)
+            lead_rows = np.nonzero((self.role == ROLE_LEADER)
+                                   & (self.self_col >= 0))[0]
+            if lead_rows.size:
+                self.last_ack[lead_rows, self.self_col[lead_rows]] = now
+            rel, commit_rel_now = self._rel_views()
 
-        t1 = time.perf_counter()
-        if self._tick_fn is not None:
-            out = self._device_tick(rel, commit_rel_now, now)
-        else:  # numpy fallback (tiny deployments / no jax)
-            out = self._np_tick(rel, commit_rel_now, now)
-        t2 = time.perf_counter()
+            t1 = pc()
+            if self._tick_fn is not None:
+                state = self._group_state(rel, commit_rel_now)
+                ts = pc()
+                if sec is not None:
+                    sec = _TRACE.switch(sec, "tick.call", ts)
+                out = self._call_tick(state, now)
+                tc = pc()
+                if sec is not None:
+                    sec = _TRACE.switch(sec, "tick.fetch", tc)
+                out = self._fetch(out)
+            else:  # numpy twin (tiny deployments / no jax): all "call"
+                ts = t1
+                if sec is not None:
+                    sec = _TRACE.switch(sec, "tick.call", ts)
+                out = self._np_tick(rel, commit_rel_now, now)
+                tc = pc()
+                if sec is not None:
+                    sec = _TRACE.switch(sec, "tick.fetch", tc)
+            t2 = pc()
 
-        self.ticks += 1
-        # publish the read-plane lane: the fused q_ack reduce is exactly
-        # what per-read lease checks need, and the row it replaces is a
-        # per-read [P] copy+sort on the hot GET path
-        np.copyto(self.tick_q_ack, np.asarray(out.q_ack))
-        advanced = self._apply_commits(out)
-        self._apply_protocol(out, now)
-        t3 = time.perf_counter()
-        self.tick_hists["tick_build_ms"].update((t1 - t0) * 1e3)
-        self.tick_hists["tick_device_ms"].update((t2 - t1) * 1e3)
-        self.tick_hists["tick_apply_ms"].update((t3 - t2) * 1e3)
-        self.tick_hists["tick_total_ms"].update((t3 - t0) * 1e3)
-        if self._tick_tracer is not None:
-            self._profile_tick(t0, t1, t2, t3, advanced)
+            self.ticks += 1
+            # publish the read-plane lane: the fused q_ack reduce is
+            # exactly what per-read lease checks need, and the row it
+            # replaces is a per-read [P] copy+sort on the hot GET path
+            np.copyto(self.tick_q_ack, np.asarray(out.q_ack))
+            if sec is not None:
+                sec = _TRACE.switch(sec, "tick.apply")
+            self._hb_flush_s = 0.0
+            advanced = self._apply_commits(out)
+            self._apply_protocol(out, now)
+            t3 = pc()
+        finally:
+            if sec is not None:
+                _TRACE.leave(sec)
+        hists = self.tick_hists
+        hists["tick_build_ms"].update((t1 - t0) * 1e3)
+        hists["tick_device_ms"].update((t2 - t1) * 1e3)
+        hists["tick_apply_ms"].update((t3 - t2) * 1e3)
+        hists["tick_total_ms"].update((t3 - t0) * 1e3)
+        hists["tick_state_ms"].update((ts - t1) * 1e3)
+        hists["tick_call_ms"].update((tc - ts) * 1e3)
+        hists["tick_fetch_ms"].update((t2 - tc) * 1e3)
+        hists["tick_heartbeat_ms"].update(self._hb_flush_s * 1e3)
         return advanced
 
     def _rel_views(self) -> tuple[np.ndarray, np.ndarray]:
@@ -1738,6 +1767,14 @@ class MultiRaftEngine:
         )
 
     def _device_tick(self, rel, commit_rel_now, now):
+        """State build, jitted call and download in one go: what
+        ``tick_once`` does in three timed steps."""
+        return self._fetch(self._call_tick(
+            self._group_state(rel, commit_rel_now), now))
+
+    def _call_tick(self, state, now):
+        """The jitted call: hands the numpy rows over and enqueues the
+        program; returns device arrays that may not be computed yet."""
         import jax
 
         from tpuraft.ops.tick import TickParams
@@ -1745,9 +1782,14 @@ class MultiRaftEngine:
         if self._params_dev is None:
             self._params_dev = TickParams.make(self.eto_ms, self.hb_ms,
                                                self.lease_ms, self.snap_ms)
-        state = self._group_state(rel, commit_rel_now)
         with jax.profiler.TraceAnnotation("tpuraft.raft_tick"):
-            out = self._tick_fn(state, np.int32(now), self._params_dev)
+            return self._tick_fn(state, np.int32(now), self._params_dev)
+
+    @staticmethod
+    def _fetch(out):
+        """Wait for the device and download every output row."""
+        import jax
+
         return jax.tree_util.tree_map(np.asarray, out)
 
     def _np_tick(self, rel, commit_rel_now, now) -> _NpOutputs:
@@ -1902,7 +1944,16 @@ class MultiRaftEngine:
             self._resolve_fences(int(s))
         hb_slots = np.nonzero(np.asarray(out.hb_due) & hc)[0]
         if hb_slots.size:
-            self._flush_heartbeats(hb_slots, now)
+            h0 = time.perf_counter()
+            sec = _TRACE.enter("raft.heartbeat", h0) if _TRACE.enabled \
+                else None
+            try:
+                self._flush_heartbeats(hb_slots, now)
+            finally:
+                h1 = time.perf_counter()
+                if sec is not None:
+                    _TRACE.leave(sec, h1)
+                self._hb_flush_s = h1 - h0
         snap_slots = np.nonzero(np.asarray(out.snap_due) & hc)[0]
         for s in snap_slots:
             ctrl = self._ctrls[s]

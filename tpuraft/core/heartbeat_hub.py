@@ -82,6 +82,7 @@ from tpuraft.rpc.messages import (
     encode_message,
 )
 from tpuraft.rpc.transport import RpcError, is_no_method
+from tpuraft.util.trace import TRACER as _TRACE
 
 if TYPE_CHECKING:
     from tpuraft.core.replicator import Replicator
@@ -512,6 +513,14 @@ class HeartbeatHub:
         leadership of the NEW term from a node that is now a follower
         (observed as spurious "two leaders in one term" conflicts on
         receivers).  No awaits may separate the check from the build."""
+        sec = _TRACE.enter("raft.heartbeat") if _TRACE.enabled else None
+        try:
+            self._pulse(replicators)
+        finally:
+            if sec is not None:
+                _TRACE.leave(sec)
+
+    def _pulse(self, replicators: list["Replicator"]) -> None:
         by_dst_fast: dict[str, list[tuple["Replicator", CompactBeat]]] = {}
         classic: list["Replicator"] = []
         for r in replicators:
@@ -667,6 +676,17 @@ class HeartbeatHub:
             self.fast_fallbacks += len(reps)
             self._pulse_classic(reps)
             return
+        sec = _TRACE.enter("raft.heartbeat") if _TRACE.enabled else None
+        try:
+            self._note_beat_acks(dst, pairs, resp, t0)
+        finally:
+            if sec is not None:
+                _TRACE.leave(sec)
+
+    def _note_beat_acks(self, dst: str, pairs: list, resp,
+                        t0: float) -> None:
+        """Ack bookkeeping of one fast-beat RPC, and the classic
+        follow-up for the rows that deviated."""
         now = self.clock.monotonic()
         if resp.items:
             self._note_peer_clock(dst, resp.items[0], t0, now)

@@ -598,26 +598,13 @@ class Node:
                 good.append(task)
             if not good:
                 return
-            entries = [LogEntry(type=EntryType.DATA, data=t.data,
-                                trace_id=t.trace_id)
-                       for t in good]
-            self._ctrl.note_activity()  # a write instantly wakes a
-            # hibernating leader group (quiescence)
-            term = self.current_term
-            last_id = self.log_manager.stage_leader_entries(entries, term)
-            first_index = last_id.index - len(good) + 1
-            if TRACER.enabled:
-                now = time.perf_counter()
-                for i, task in enumerate(good):
-                    if task.trace_id:
-                        self._trace_quorum[first_index + i] = (
-                            task.trace_id, now)
-            for i, task in enumerate(good):
-                if task.done:
-                    self.fsm_caller.append_pending_closure(
-                        first_index + i, task.done,
-                        ack_at_commit=task.ack_at_commit)
-            self.replicators.wake_all()
+            sec = TRACER.enter("raft.propose") if TRACER.enabled else None
+            try:
+                term = self.current_term
+                last_id = self._stage_proposals(good, term)
+            finally:
+                if sec is not None:
+                    TRACER.leave(sec)
         # fsync outside the lock; batched with concurrent appliers
         try:
             await self.log_manager.flush_staged(last_id.index)
@@ -632,9 +619,39 @@ class Node:
                     and self.current_term == term:
                 self._commit_at_self(last_id.index)
 
+    def _stage_proposals(self, good: list, term: int) -> LogId:  # graftcheck: holds(_lock)
+        """One log entry per task: staged in memory, its closure queued
+        for the commit, the replicators woken.  Returns the last id."""
+        entries = [LogEntry(type=EntryType.DATA, data=t.data,
+                            trace_id=t.trace_id)
+                   for t in good]
+        self._ctrl.note_activity()  # a write instantly wakes a
+        # hibernating leader group (quiescence)
+        last_id = self.log_manager.stage_leader_entries(entries, term)
+        first_index = last_id.index - len(good) + 1
+        if TRACER.enabled:
+            now = time.perf_counter()
+            for i, task in enumerate(good):
+                if task.trace_id:
+                    self._trace_quorum[first_index + i] = (
+                        task.trace_id, now)
+        for i, task in enumerate(good):
+            if task.done:
+                self.fsm_caller.append_pending_closure(
+                    first_index + i, task.done,
+                    ack_at_commit=task.ack_at_commit)
+        self.replicators.wake_all()
+        return last_id
+
     def _commit_at_self(self, index: int) -> None:  # graftcheck: holds(_lock)
-        self.ballot_box.commit_at(
-            self.server_id, index, self.conf_entry.conf, self.conf_entry.old_conf)
+        sec = TRACER.enter("raft.ack") if TRACER.enabled else None
+        try:
+            self.ballot_box.commit_at(
+                self.server_id, index, self.conf_entry.conf,
+                self.conf_entry.old_conf)
+        finally:
+            if sec is not None:
+                TRACER.leave(sec)
 
     async def snapshot(self) -> Status:
         if not self.snapshot_executor:
@@ -750,7 +767,14 @@ class Node:
             # update_conf just pruned — a later wipe+re-add of the same
             # peer would inherit the stale row and commit on a phantom ack
             return
-        self.ballot_box.commit_at(peer, match_index, e.conf, e.old_conf)
+        # the ack's way to the commit: ballot row, eager quorum close,
+        # _on_committed and the closures acked at commit
+        sec = TRACER.enter("raft.ack") if TRACER.enabled else None
+        try:
+            self.ballot_box.commit_at(peer, match_index, e.conf, e.old_conf)
+        finally:
+            if sec is not None:
+                TRACER.leave(sec)
 
     def on_peer_ack(self, peer: PeerId, when: float) -> None:
         self._ctrl.record_ack(peer, when)
@@ -1371,64 +1395,41 @@ class Node:
                     multi_hb=mh,
                     term=self.current_term, success=False,
                     last_log_index=self.log_manager.last_log_index())
-            self._last_leader_timestamp = self._clock.monotonic()
-            self._ctrl.note_leader_contact()
-            # an incoming full-semantics append (entries, probe, or
-            # classic beat) means the leader is ACTIVE: a quiescent
-            # follower wakes — heals the asymmetric state left by an
-            # aborted quiesce handshake within one beat instead of one
-            # store-lease expiry
-            self._ctrl.note_activity()
-
             lm = self.log_manager
-            if not req.entries:
-                # heartbeat / probe
-                local_prev_term = lm.get_term(req.prev_log_index)
-                if req.prev_log_index > lm.last_log_index() or (
-                        req.prev_log_index >= lm.first_log_index() - 1
-                        and local_prev_term != req.prev_log_term
-                        and req.prev_log_index != lm.last_snapshot_id().index):
-                    # term mismatch (not merely a short log): tell the
-                    # leader where our conflicting term run starts
-                    hint = 0
-                    if (req.prev_log_index <= lm.last_log_index()
-                            and local_prev_term != 0):
-                        hint = lm.conflict_hint(req.prev_log_index,
-                                                local_prev_term)
-                    return AppendEntriesResponse(
-                        multi_hb=mh,
-                        term=self.current_term, success=False,
-                        last_log_index=lm.last_log_index(),
-                        conflict_index=hint)
-                self.ballot_box.set_last_committed_index(
-                    min(req.committed_index, req.prev_log_index))
-                if self._note_attested is not None and \
-                        req.prev_log_index >= lm.last_log_index():
-                    # heartbeat AT our tail: whole log prefix-matches
-                    # the leader's (replica-plane attestation)
-                    self._note_attested(req.term)
-                return AppendEntriesResponse(
-                    multi_hb=mh,
-                    term=self.current_term, success=True,
-                    last_log_index=lm.last_log_index())
+            sec = TRACER.enter("raft.follower") if TRACER.enabled else None
+            try:
+                self._last_leader_timestamp = self._clock.monotonic()
+                self._ctrl.note_leader_contact()
+                # an incoming full-semantics append (entries, probe, or
+                # classic beat) means the leader is ACTIVE: a quiescent
+                # follower wakes — heals the asymmetric state left by
+                # an aborted quiesce handshake within one beat instead
+                # of one store-lease expiry
+                self._ctrl.note_activity()
+                if not req.entries:
+                    return self._answer_probe(req, mh)
+                if self._note_append_start is not None:
+                    self._note_append_start(req.term)
+                entries = list(req.entries)
+                if self.options.witness:
+                    # metadata-only journal: strip any payload that
+                    # still arrived full (a mixed-fleet leader that
+                    # predates witness-aware stripping) — CRC-verify the
+                    # wire blob FIRST so a corrupt frame can't journal
+                    # bad metadata
+                    from tpuraft.entity import strip_entry_payload
 
-            if self._note_append_start is not None:
-                self._note_append_start(req.term)
-            entries = list(req.entries)
-            if self.options.witness:
-                # metadata-only journal: strip any payload that still
-                # arrived full (a mixed-fleet leader that predates
-                # witness-aware stripping) — CRC-verify the wire blob
-                # FIRST so a corrupt frame can't journal bad metadata
-                from tpuraft.entity import strip_entry_payload
-
-                entries = [strip_entry_payload(e) for e in entries]
-            # trace plane: wire-borne contexts join the follower-side
-            # append (incl. its fsync wait) to the originating trace
-            tr0 = 0.0
-            if TRACER.enabled and req.trace_ctx:
-                adopt_entry_ctx(entries, req.trace_ctx)
-                tr0 = time.perf_counter()
+                    entries = [strip_entry_payload(e) for e in entries]
+                # trace plane: wire-borne contexts join the
+                # follower-side append (incl. its fsync wait) to the
+                # originating trace
+                tr0 = 0.0
+                if TRACER.enabled and req.trace_ctx:
+                    adopt_entry_ctx(entries, req.trace_ctx)
+                    tr0 = time.perf_counter()
+            finally:
+                if sec is not None:
+                    TRACER.leave(sec)
             try:
                 ok = await lm.append_entries_follower(
                     req.prev_log_index, req.prev_log_term, entries)
@@ -1482,6 +1483,38 @@ class Node:
                 multi_hb=mh,
                 term=self.current_term, success=True,
                 last_log_index=lm.last_log_index())
+
+    def _answer_probe(self, req: AppendEntriesRequest, mh: bool) -> AppendEntriesResponse:  # graftcheck: holds(_lock)
+        """An AppendEntries with no entries: heartbeat or probe."""
+        lm = self.log_manager
+        local_prev_term = lm.get_term(req.prev_log_index)
+        if req.prev_log_index > lm.last_log_index() or (
+                req.prev_log_index >= lm.first_log_index() - 1
+                and local_prev_term != req.prev_log_term
+                and req.prev_log_index != lm.last_snapshot_id().index):
+            # term mismatch (not merely a short log): tell the
+            # leader where our conflicting term run starts
+            hint = 0
+            if (req.prev_log_index <= lm.last_log_index()
+                    and local_prev_term != 0):
+                hint = lm.conflict_hint(req.prev_log_index,
+                                        local_prev_term)
+            return AppendEntriesResponse(
+                multi_hb=mh,
+                term=self.current_term, success=False,
+                last_log_index=lm.last_log_index(),
+                conflict_index=hint)
+        self.ballot_box.set_last_committed_index(
+            min(req.committed_index, req.prev_log_index))
+        if self._note_attested is not None and \
+                req.prev_log_index >= lm.last_log_index():
+            # heartbeat AT our tail: whole log prefix-matches
+            # the leader's (replica-plane attestation)
+            self._note_attested(req.term)
+        return AppendEntriesResponse(
+            multi_hb=mh,
+            term=self.current_term, success=True,
+            last_log_index=lm.last_log_index())
 
     def _refresh_conf_from_log(self) -> None:  # graftcheck: holds(_lock)
         last = self.log_manager.conf_manager.last()

@@ -16,6 +16,7 @@ from tpuraft.entity import PeerId
 from tpuraft.rpc.messages import BatchResponse, BeatAck
 from tpuraft.errors import RaftError, Status
 from tpuraft.rpc.transport import RpcError, RpcServer
+from tpuraft.util.trace import TRACER as _TRACE
 
 LOG = logging.getLogger(__name__)
 
@@ -88,8 +89,16 @@ class NodeManager:
         committed) row just touches the election deadline.  Any
         deviation answers ok=False and the sender follows up with a
         classic full-semantics beat for that group only."""
+        sec = _TRACE.enter("raft.heartbeat") if _TRACE.enabled else None
+        try:
+            return BatchResponse(items=self._answer_fast_beats(request.items))
+        finally:
+            if sec is not None:
+                _TRACE.leave(sec)
+
+    def _answer_fast_beats(self, beats: list) -> list:
         acks = []
-        for b in request.items:
+        for b in beats:
             node = self._nodes.get((b.group_id, b.peer_id))
             if (node is not None
                     and node.state == State.FOLLOWER
@@ -128,7 +137,7 @@ class NodeManager:
                     ok=False,
                     term=node.current_term if node is not None else 0,
                     clock_ms=self._clock_ms()))
-        return BatchResponse(items=acks)
+        return acks
 
     def _clock_ms(self) -> int:
         """This store's clock reading (monotonic ms) for ack piggyback —

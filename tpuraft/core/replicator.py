@@ -163,7 +163,17 @@ class Replicator:
     def _wake_run(self) -> None:
         self._wake_scheduled = False
         if self._running:
+            self._pump_from_loop()
+
+    def _pump_from_loop(self) -> None:
+        """``pump`` as a loop callback (a wake or a delayed retry): the
+        stretch the loop thread spends building this peer's frames."""
+        sec = _TRACE.enter("raft.replicate") if _TRACE.enabled else None
+        try:
             self.pump()
+        finally:
+            if sec is not None:
+                _TRACE.leave(sec)
 
     def _delayed_pump(self, delay_s: float) -> None:
         if not self._running or self._delay_handle is not None:
@@ -173,7 +183,7 @@ class Replicator:
         def fire():
             self._delay_handle = None
             if self._running:
-                self.pump()
+                self._pump_from_loop()
 
         self._delay_handle = loop.call_later(delay_s, fire)
 

@@ -260,9 +260,10 @@ class _StoreSender:
             batch = keep
             if not batch:
                 return
+        rpc0 = 0.0
+        sec = TRACER.enter("client.send") if TRACER.enabled else None
         req = KVCommandBatchRequest(
             items=[row[2] for row in batch])
-        rpc0 = 0.0
         if TRACER.enabled:
             rpc0 = time.perf_counter()
             for row in batch:
@@ -272,6 +273,8 @@ class _StoreSender:
             # per-item contexts as the trailing wire field (b"" when
             # nothing in the batch is traced)
             req.trace_ctx = pack_ctx([row[5] for row in batch])
+        if sec is not None:
+            TRACER.leave(sec)
         t0 = asyncio.get_running_loop().time()
         try:
             resp = await client.transport.call(
@@ -297,6 +300,17 @@ class _StoreSender:
             return
         client.batch_rpcs += 1
         client.batch_items += len(batch)
+        sec = TRACER.enter("client.deliver") if TRACER.enabled else None
+        try:
+            self._deliver(batch, resp, rpc0, t0)
+        finally:
+            if sec is not None:
+                TRACER.leave(sec)
+
+    def _deliver(self, batch: list, resp, rpc0: float, t0: float) -> None:
+        """The reply's way back: decode each item's outcome and resolve
+        its caller's future."""
+        client = self._client
         if rpc0:
             rpc1 = time.perf_counter()
             for row in batch:
@@ -574,50 +588,42 @@ class RheaKVStore:
         _call_region probing every endpoint within one attempt cycle:
         a cold leader cache costs extra round trips, never the outer
         backoff sleep."""
-        if TRACER.enabled:
-            # one trace per (region, op) dispatch cycle: the root span
-            # opens here (sampling + slow-op candidacy decided inside)
-            # and closes when the cycle's outcome lands below
-            for _region, op in pairs:
-                if not op.trace_id:
-                    op.trace_id = TRACER.begin_op("kv_op", proc="client")
         outs: list = [None] * len(pairs)
         direct: list[int] = []
         live: list[list] = []   # [pair index, candidates, cursor, spread]
-        for i, (region, op) in enumerate(pairs):
-            if (not self._batch_ok
-                    or (self.read_from == "any"
-                        and op.op in _READONLY_OPS)):
-                direct.append(i)
-                continue
-            spread = (self.read_from in ("follower", "learner")
-                      and op.op in _READONLY_OPS)
-            cands = (self._read_candidates(region, attempt) if spread
-                     else self._store_candidates(region, attempt))
-            live.append([i, cands, 0, spread])
+        sec = TRACER.enter("client.send") if TRACER.enabled else None
+        try:
+            if TRACER.enabled:
+                # one trace per (region, op) dispatch cycle: the root
+                # span opens here (sampling + slow-op candidacy decided
+                # inside) and closes when the cycle's outcome lands
+                for _region, op in pairs:
+                    if not op.trace_id:
+                        op.trace_id = TRACER.begin_op("kv_op",
+                                                      proc="client")
+            for i, (region, op) in enumerate(pairs):
+                if (not self._batch_ok
+                        or (self.read_from == "any"
+                            and op.op in _READONLY_OPS)):
+                    direct.append(i)
+                    continue
+                spread = (self.read_from in ("follower", "learner")
+                          and op.op in _READONLY_OPS)
+                cands = (self._read_candidates(region, attempt) if spread
+                         else self._store_candidates(region, attempt))
+                live.append([i, cands, 0, spread])
+        finally:
+            if sec is not None:
+                TRACER.leave(sec)
         # the per-op escape hatch still needs real tasks (one coroutine
         # each); batched pairs never do
         direct_gather = asyncio.gather(
             *(self._call_region_outcome(*pairs[i]) for i in direct)) \
             if direct else None
         while live:
-            futs = [self._sender(_endpoint(row[1][row[2]])).submit(
-                        pairs[row[0]][0], row[1][row[2]],
-                        pairs[row[0]][1], spread=row[3])
-                    for row in live]
-            round_outs = await asyncio.gather(*futs)
-            nxt = []
-            for row, out in zip(live, round_outs):
-                outs[row[0]] = out
-                # a mid-flight ENOMETHOD downgrade means the sender
-                # already served the item through the per-op path:
-                # outcome is final regardless of shape
-                if (self._batch_ok
-                        and isinstance(out, _Retry) and not out.refresh
-                        and row[2] + 1 < len(row[1])):
-                    row[2] += 1
-                    nxt.append(row)
-            live = nxt
+            round_outs = await asyncio.gather(
+                *self._submit_round(pairs, live))
+            live = self._settle_round(live, round_outs, outs)
         if direct_gather is not None:
             for i, out in zip(direct, await direct_gather):
                 outs[i] = out
@@ -626,6 +632,39 @@ class RheaKVStore:
                 if op.trace_id:
                     TRACER.end_op(op.trace_id, ok=isinstance(out, tuple))
         return outs
+
+    def _submit_round(self, pairs: list, live: list) -> list:
+        """Hand every live pair to its candidate store's sender (the op
+        blobs are encoded here); one future per pair."""
+        sec = TRACER.enter("client.send") if TRACER.enabled else None
+        try:
+            return [self._sender(_endpoint(row[1][row[2]])).submit(
+                        pairs[row[0]][0], row[1][row[2]],
+                        pairs[row[0]][1], spread=row[3])
+                    for row in live]
+        finally:
+            if sec is not None:
+                TRACER.leave(sec)
+
+    def _settle_round(self, live: list, round_outs: list,
+                      outs: list) -> list:
+        """Record a round's outcomes; the pairs bounced retryably with a
+        candidate store left go into the next round."""
+        nxt = []
+        sec = TRACER.enter("client.deliver") if TRACER.enabled else None
+        for row, out in zip(live, round_outs):
+            outs[row[0]] = out
+            # a mid-flight ENOMETHOD downgrade means the sender
+            # already served the item through the per-op path:
+            # outcome is final regardless of shape
+            if (self._batch_ok
+                    and isinstance(out, _Retry) and not out.refresh
+                    and row[2] + 1 < len(row[1])):
+                row[2] += 1
+                nxt.append(row)
+        if sec is not None:
+            TRACER.leave(sec)
+        return nxt
 
     # ------------------------------------------------------------------
     # client-side batcher flushes (one drain round)
@@ -642,36 +681,49 @@ class RheaKVStore:
         for attempt in range(self.max_retries):
             groups: dict[int, tuple[Region, list]] = {}
             unroutable: list = []
-            for item, fut in pending:
-                try:
-                    r = self.route_table.find_region_by_key(key_fn(item))
-                except Exception as e:  # noqa: BLE001 — malformed key:
-                    # fail ITS caller, not the whole chunk
-                    if not fut.done():
-                        fut.set_exception(RheaKVError(Status.error(
-                            RaftError.EINVAL, f"malformed key: {e!r}")))
-                    continue
-                if r is None:
-                    unroutable.append((item, fut))
-                else:
-                    groups.setdefault(r.id, (r, []))[1].append((item, fut))
+            sec = TRACER.enter("client.send") if TRACER.enabled else None
+            try:
+                for item, fut in pending:
+                    try:
+                        r = self.route_table.find_region_by_key(
+                            key_fn(item))
+                    except Exception as e:  # noqa: BLE001 — malformed
+                        # key: fail ITS caller, not the whole chunk
+                        if not fut.done():
+                            fut.set_exception(RheaKVError(Status.error(
+                                RaftError.EINVAL, f"malformed key: {e!r}")))
+                        continue
+                    if r is None:
+                        unroutable.append((item, fut))
+                    else:
+                        groups.setdefault(r.id, (r, []))[1].append(
+                            (item, fut))
+                parts = list(groups.values())
+                region_ops = [(region, op_fn(items))
+                              for region, items in parts]
+            finally:
+                if sec is not None:
+                    TRACER.leave(sec)
             retry: list = list(unroutable)
             need_refresh = bool(unroutable)
-            parts = list(groups.values())
-            outcomes = await self._dispatch_region_ops(
-                [(region, op_fn(items)) for region, items in parts], attempt)
-            for (region, items), out in zip(parts, outcomes):
-                if isinstance(out, tuple):
-                    deliver(items, out[1])
-                elif isinstance(out, _Retry):
-                    need_refresh = need_refresh or out.refresh
-                    if out.status is not None:
-                        last = out.status
-                    retry.extend(items)
-                else:   # hard error fails ITS region's calls only
-                    for _, fut in items:
-                        if not fut.done():
-                            fut.set_exception(out)
+            outcomes = await self._dispatch_region_ops(region_ops, attempt)
+            sec = TRACER.enter("client.deliver") if TRACER.enabled else None
+            try:
+                for (region, items), out in zip(parts, outcomes):
+                    if isinstance(out, tuple):
+                        deliver(items, out[1])
+                    elif isinstance(out, _Retry):
+                        need_refresh = need_refresh or out.refresh
+                        if out.status is not None:
+                            last = out.status
+                        retry.extend(items)
+                    else:   # hard error fails ITS region's calls only
+                        for _, fut in items:
+                            if not fut.done():
+                                fut.set_exception(out)
+            finally:
+                if sec is not None:
+                    TRACER.leave(sec)
             if not retry:
                 return
             pending = retry

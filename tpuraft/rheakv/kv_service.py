@@ -462,6 +462,28 @@ class KVCommandProcessor:
     async def _handle_batch_admitted(self, req: KVCommandBatchRequest
                                      ) -> KVCommandBatchResponse:
         replies: list[bytes] = [b""] * len(req.items)
+        sec = TRACER.enter("kv.batch") if TRACER.enabled else None
+        try:
+            groups = self._decode_batch(req, replies)
+            lite, tasks = self._start_regions(groups, replies)
+        finally:
+            if sec is not None:
+                TRACER.leave(sec)
+        if lite or tasks:
+            results = await asyncio.gather(
+                *(f for _, f in lite), *tasks, return_exceptions=True)
+            sec = TRACER.enter("kv.batch") if TRACER.enabled else None
+            try:
+                _encode_write_replies(lite, results, replies)
+            finally:
+                if sec is not None:
+                    TRACER.leave(sec)
+        return KVCommandBatchResponse(items=replies)
+
+    def _decode_batch(self, req: KVCommandBatchRequest, replies: list
+                      ) -> dict[int, list[tuple[int, KVOperation]]]:
+        """Decode and validate every item (a refused one gets its reply
+        here) and group the admitted ops by region."""
         groups: dict[int, list[tuple[int, KVOperation]]] = {}
         # trace plane: per-item contexts ride the trailing trace_ctx
         # field; adopting them onto the decoded ops lets the propose /
@@ -506,6 +528,12 @@ class KVCommandProcessor:
                     TRACER.span(tid, "srv_validate", v0, v1,
                                 proc=self._proc)
         self.batch_regions += len(groups)
+        return groups
+
+    def _start_regions(self, groups: dict, replies: list) -> tuple:
+        """Queue each pure-write region's ONE MULTI entry (a plain
+        future each: ``lite``) and build the coroutine of every region
+        with reads in it (``tasks``)."""
 
         async def run_region(rid: int, items: list) -> None:
             engine = self._se.get_region_engine(rid)
@@ -586,19 +614,27 @@ class KVCommandProcessor:
                             served += 1
                             out_bytes += len(replies[i])
                 else:
-                    for i, op in reads:
-                        s0 = time.perf_counter() if op.trace_id else 0.0
-                        code, msg, result = _serve_read_local(rs, op)
-                        if op.trace_id:
-                            TRACER.span(op.trace_id, "srv_read_serve", s0,
-                                        time.perf_counter(), proc=self._proc)
-                        replies[i] = (
-                            encode_batch_reply(0,
-                                               result=encode_result(result))
-                            if code == 0 else encode_batch_reply(code, msg))
-                        if code == 0:
-                            served += 1
-                            out_bytes += len(replies[i])
+                    sec = TRACER.enter("kv.batch") if TRACER.enabled \
+                        else None
+                    try:
+                        for i, op in reads:
+                            s0 = time.perf_counter() if op.trace_id else 0.0
+                            code, msg, result = _serve_read_local(rs, op)
+                            if op.trace_id:
+                                TRACER.span(op.trace_id, "srv_read_serve",
+                                            s0, time.perf_counter(),
+                                            proc=self._proc)
+                            replies[i] = (
+                                encode_batch_reply(
+                                    0, result=encode_result(result))
+                                if code == 0
+                                else encode_batch_reply(code, msg))
+                            if code == 0:
+                                served += 1
+                                out_bytes += len(replies[i])
+                    finally:
+                        if sec is not None:
+                            TRACER.leave(sec)
                 if served and self._heat is not None:
                     self._heat.note_read(rid, served, out_bytes)
 
@@ -636,26 +672,27 @@ class KVCommandProcessor:
                 tasks.append(run_region(rid, items))
             else:
                 lite.append((items, fut))
-        if lite or tasks:
-            results = await asyncio.gather(
-                *(f for _, f in lite), *tasks, return_exceptions=True)
-            for (items, _f), res in zip(lite, results):
-                if isinstance(res, KVStoreError):
-                    for i, _ in items:
-                        replies[i] = encode_batch_reply(res.status.code,
-                                                        res.status.error_msg)
-                elif isinstance(res, BaseException):
-                    for i, _ in items:
-                        replies[i] = encode_batch_reply(
-                            int(RaftError.EINTERNAL), str(res))
-                else:
-                    for (i, _), (st, result) in zip(items, res):
-                        replies[i] = (
-                            encode_batch_reply(0,
-                                               result=encode_result(result))
-                            if st.is_ok()
-                            else encode_batch_reply(st.code, st.error_msg))
-        return KVCommandBatchResponse(items=replies)
+        return lite, tasks
+
+
+def _encode_write_replies(lite: list, results: list, replies: list) -> None:
+    """The replies of the pure-write regions, from their futures'
+    results (``results`` leads with them, in ``lite``'s order)."""
+    for (items, _f), res in zip(lite, results):
+        if isinstance(res, KVStoreError):
+            for i, _ in items:
+                replies[i] = encode_batch_reply(res.status.code,
+                                                res.status.error_msg)
+        elif isinstance(res, BaseException):
+            for i, _ in items:
+                replies[i] = encode_batch_reply(
+                    int(RaftError.EINTERNAL), str(res))
+        else:
+            for (i, _), (st, result) in zip(items, res):
+                replies[i] = (
+                    encode_batch_reply(0, result=encode_result(result))
+                    if st.is_ok()
+                    else encode_batch_reply(st.code, st.error_msg))
 
 
 def _serve_read_local(rs, op: KVOperation) -> tuple[int, str, object]:

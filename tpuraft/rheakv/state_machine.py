@@ -18,6 +18,7 @@ from tpuraft.errors import RaftError, Status
 from tpuraft.rheakv.kv_operation import KVOp, KVOperation
 from tpuraft.rheakv.metadata import Region
 from tpuraft.rheakv.raw_store import RawKVStore
+from tpuraft.util.trace import TRACER
 
 LOG = logging.getLogger(__name__)
 
@@ -167,7 +168,13 @@ class KVStoreStateMachine(StateMachine):
         dones.clear()
 
     async def on_apply(self, it: Iterator) -> None:
-        self.on_lane_applied(self.apply_sync(it))
+        # the apply body on the loop thread, native KV calls included
+        sec = TRACER.enter("fsm.apply") if TRACER.enabled else None
+        try:
+            self.on_lane_applied(self.apply_sync(it))
+        finally:
+            if sec is not None:
+                TRACER.leave(sec)
 
     def on_lane_applied(self, applied_ops: int) -> None:
         """Post-apply bookkeeping that must stay on the loop (the heat

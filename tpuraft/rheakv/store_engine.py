@@ -462,8 +462,32 @@ class ReadConfirmBatcher:
         build — the HeartbeatHub invariant), dispatch one RPC per
         destination, tally."""
         self.rounds += 1
-        groups: dict[int, _GroupFence] = {}
         order: list[_GroupFence] = []
+        by_dst: dict[str, list] = {}
+        classic: list = []
+        try:
+            sec = TRACER.enter("kv.read_round") if TRACER.enabled else None
+            try:
+                self._build_round(batch, order, by_dst, classic)
+            finally:
+                if sec is not None:
+                    TRACER.leave(sec)
+            await asyncio.gather(
+                *(self._beat_dst(dst, rows) for dst, rows in by_dst.items()),
+                *(self._classic(st, r) for st, r in classic))
+        finally:
+            sec = TRACER.enter("kv.read_round") if TRACER.enabled else None
+            try:
+                self._finish_round(order, len(by_dst) + len(classic))
+            finally:
+                if sec is not None:
+                    TRACER.leave(sec)
+
+    def _build_round(self, batch: list, order: list, by_dst: dict,
+                     classic: list) -> None:
+        """One fence per group with pending readers, armed on the device
+        lane where the engine drives it, and its beats by destination."""
+        groups: dict[int, _GroupFence] = {}
         for node, fut in batch:
             st = groups.get(id(node))
             if st is None:
@@ -471,83 +495,79 @@ class ReadConfirmBatcher:
                 order.append(st)
             else:
                 st.futs.append(fut)
-        by_dst: dict[str, list] = {}
-        classic: list = []
-        try:
-            for st in order:
-                node = st.node
-                if not node.is_leader():
-                    st.resolve(False)
-                    continue
-                # engine-backed group: the quorum tally rides the device
-                # tick's fused q_ack reduction (the fence_ok lane) — the
-                # beats below still go out (they ARE the acks the lane
-                # counts), but the per-ack host set arithmetic is skipped
-                ctrl = getattr(node, "_ctrl", None)
-                if ctrl is not None and getattr(ctrl, "drives_read_fences",
-                                                False):
-                    ctrl.arm_read_fence(st)
-                    st.device = True
-                    self.device_fences += 1
-                voters = st.new_peers | st.old_peers
-                committed = node.ballot_box.last_committed_index
-                for r in node.replicators.all():
-                    if r.peer not in voters:
-                        continue   # a learner's ack proves nothing
-                    if (r.peer_multi_hb and r._matched
-                            and self._fast_ok.get(r.peer.endpoint, True)):
-                        beat = CompactBeat(
-                            group_id=node.group_id,
-                            server_id=str(node.server_id),
-                            peer_id=str(r.peer),
-                            term=st.term,
-                            committed_index=min(committed, r.match_index))
-                        by_dst.setdefault(r.peer.endpoint, []
-                                          ).append((st, r, beat))
-                    else:
-                        classic.append((st, r))
-                if not st.device:
-                    st.note_ack(node.server_id)  # self-only quorum case
-            await asyncio.gather(
-                *(self._beat_dst(dst, rows) for dst, rows in by_dst.items()),
-                *(self._classic(st, r) for st, r in classic))
-        finally:
-            # device fences: the RPCs completed, so every ack this round
-            # can produce is already in the engine's last_ack rows — one
-            # forced tick per distinct engine reduces them and fires
-            # fence_ok NOW (the adaptive loop's own tick may be a task
-            # behind), so resolution is deterministic before the sweep
-            dev_pending = [st for st in order
-                           if st.device and not st.done]
-            if dev_pending:
-                engines = {id(st.node._ctrl.engine): st.node._ctrl.engine
-                           for st in dev_pending}
-                for eng in engines.values():
-                    try:
-                        eng.tick_once()
-                    except Exception:  # noqa: BLE001 — fall to the sweep
-                        LOG.exception("fence-resolve tick failed")
-            failed_groups = 0
-            for st in order:
-                if st.device:
-                    # the fence dies with the round either way; a void
-                    # entry left armed would pin fence_start and spin
-                    # dirty marks on every later ack
-                    ctrl = getattr(st.node, "_ctrl", None)
-                    if ctrl is not None:
-                        ctrl.engine.discard_read_fence(ctrl.slot, st)
-                if not st.done:
-                    self.failed += 1
-                    failed_groups += 1
+        for st in order:
+            node = st.node
+            if not node.is_leader():
                 st.resolve(False)
-            if failed_groups:
-                # fence-round outcome (flight recorder): one event per
-                # round with failures, not per group — a total
-                # partition at region density must not churn the ring
-                # with thousands of identical rows per round
-                RECORDER.record("fence_round_failed", "",
-                                groups=failed_groups,
-                                beats=len(by_dst) + len(classic))
+                continue
+            # engine-backed group: the quorum tally rides the device
+            # tick's fused q_ack reduction (the fence_ok lane) — the
+            # beats below still go out (they ARE the acks the lane
+            # counts), but the per-ack host set arithmetic is skipped
+            ctrl = getattr(node, "_ctrl", None)
+            if ctrl is not None and getattr(ctrl, "drives_read_fences",
+                                            False):
+                ctrl.arm_read_fence(st)
+                st.device = True
+                self.device_fences += 1
+            voters = st.new_peers | st.old_peers
+            committed = node.ballot_box.last_committed_index
+            for r in node.replicators.all():
+                if r.peer not in voters:
+                    continue   # a learner's ack proves nothing
+                if (r.peer_multi_hb and r._matched
+                        and self._fast_ok.get(r.peer.endpoint, True)):
+                    beat = CompactBeat(
+                        group_id=node.group_id,
+                        server_id=str(node.server_id),
+                        peer_id=str(r.peer),
+                        term=st.term,
+                        committed_index=min(committed, r.match_index))
+                    by_dst.setdefault(r.peer.endpoint, []
+                                      ).append((st, r, beat))
+                else:
+                    classic.append((st, r))
+            if not st.device:
+                st.note_ack(node.server_id)  # self-only quorum case
+
+    def _finish_round(self, order: list, beats: int) -> None:
+        """The round's close, reached also when it raised or was
+        cancelled: resolve, count and disarm every fence."""
+        # device fences: the RPCs completed, so every ack this round
+        # can produce is already in the engine's last_ack rows — one
+        # forced tick per distinct engine reduces them and fires
+        # fence_ok NOW (the adaptive loop's own tick may be a task
+        # behind), so resolution is deterministic before the sweep
+        dev_pending = [st for st in order
+                       if st.device and not st.done]
+        if dev_pending:
+            engines = {id(st.node._ctrl.engine): st.node._ctrl.engine
+                       for st in dev_pending}
+            for eng in engines.values():
+                try:
+                    eng.tick_once()
+                except Exception:  # noqa: BLE001 — fall to the sweep
+                    LOG.exception("fence-resolve tick failed")
+        failed_groups = 0
+        for st in order:
+            if st.device:
+                # the fence dies with the round either way; a void
+                # entry left armed would pin fence_start and spin
+                # dirty marks on every later ack
+                ctrl = getattr(st.node, "_ctrl", None)
+                if ctrl is not None:
+                    ctrl.engine.discard_read_fence(ctrl.slot, st)
+            if not st.done:
+                self.failed += 1
+                failed_groups += 1
+            st.resolve(False)
+        if failed_groups:
+            # fence-round outcome (flight recorder): one event per
+            # round with failures, not per group — a total
+            # partition at region density must not churn the ring
+            # with thousands of identical rows per round
+            RECORDER.record("fence_round_failed", "",
+                            groups=failed_groups, beats=beats)
 
     async def _beat_dst(self, dst: str, rows: list) -> None:
         node = rows[0][0].node
@@ -576,18 +596,24 @@ class ReadConfirmBatcher:
             return
         now = self.clock.monotonic()
         fallback: list = []
-        for (st, r, _b), ack in zip(rows, resp.items):
-            if getattr(ack, "ok", False):
-                # inline ack bookkeeping, exactly like the hub's fast
-                # path: the lease plane sees the (peer, when) write too
-                # (for device fences on_peer_ack IS the tally — it lands
-                # in the engine's last_ack row the fence_ok lane reduces)
-                r.last_rpc_ack = now
-                st.node.on_peer_ack(r.peer, now)
-                if not st.device:
-                    st.note_ack(r.peer)
-            else:
-                fallback.append((st, r))
+        sec = TRACER.enter("kv.read_round") if TRACER.enabled else None
+        try:
+            for (st, r, _b), ack in zip(rows, resp.items):
+                if getattr(ack, "ok", False):
+                    # inline ack bookkeeping, exactly like the hub's
+                    # fast path: the lease plane sees the (peer, when)
+                    # write too (for device fences on_peer_ack IS the
+                    # tally — it lands in the engine's last_ack row the
+                    # fence_ok lane reduces)
+                    r.last_rpc_ack = now
+                    st.node.on_peer_ack(r.peer, now)
+                    if not st.device:
+                        st.note_ack(r.peer)
+                else:
+                    fallback.append((st, r))
+        finally:
+            if sec is not None:
+                TRACER.leave(sec)
         if fallback:
             # full-semantics follow-up: ok=False may just mean the
             # follower's committed lags (restart) — a classic beat still

@@ -17,6 +17,7 @@ import random
 from typing import Any, Awaitable, Callable, Optional
 
 from tpuraft.errors import RaftError, Status
+from tpuraft.util.trace import TRACER as _TRACE
 
 
 class RpcError(Exception):
@@ -202,6 +203,29 @@ class InProcNetwork:
                 self._rng.uniform(0.0, self.reorder_max_delay_ms) / 1000.0)
         if self.delay_ms:
             await asyncio.sleep(self.delay_ms / 1000.0)
+        # the fabric's own work between caller and handler
+        sec = _TRACE.enter("rpc.inproc") if _TRACE.enabled else None
+        try:
+            server = self._route(src, dst, method, request, timeout_ms)
+        finally:
+            if sec is not None:
+                _TRACE.leave(sec)
+        if server is None:
+            # unreachable: behave like a connect/request timeout
+            await asyncio.sleep(min(timeout_ms, 50) / 1000.0)
+            raise RpcError(
+                Status.error(RaftError.EHOSTDOWN, f"{dst} unreachable from {src}"))
+        try:
+            return await asyncio.wait_for(
+                server.dispatch(method, request), timeout_ms / 1000.0)
+        except asyncio.TimeoutError:
+            raise RpcError(Status.error(RaftError.ETIMEDOUT, f"{method} to {dst}"))
+
+
+    def _route(self, src: str, dst: str, method: str, request: Any,
+               timeout_ms: float) -> Optional[RpcServer]:
+        """The server a frame reaches, or None where the fault knobs
+        say it is lost; a duplicated frame is sent on its way here."""
         if (
             dst not in self._servers
             or dst in self._down
@@ -209,10 +233,7 @@ class InProcNetwork:
             or (src, dst) in self._blocked_pairs
             or (self.drop_rate and self._rng.random() < self.drop_rate)
         ):
-            # unreachable: behave like a connect/request timeout
-            await asyncio.sleep(min(timeout_ms, 50) / 1000.0)
-            raise RpcError(
-                Status.error(RaftError.EHOSTDOWN, f"{dst} unreachable from {src}"))
+            return None
         server = self._servers[dst]
         if self.duplicate_rate and self._rng.random() < self.duplicate_rate:
             # the wire delivered the frame twice: the receiver executes
@@ -220,11 +241,7 @@ class InProcNetwork:
             dup = asyncio.ensure_future(asyncio.wait_for(
                 server.dispatch(method, request), timeout_ms / 1000.0))
             dup.add_done_callback(lambda t: t.cancelled() or t.exception())
-        try:
-            return await asyncio.wait_for(
-                server.dispatch(method, request), timeout_ms / 1000.0)
-        except asyncio.TimeoutError:
-            raise RpcError(Status.error(RaftError.ETIMEDOUT, f"{method} to {dst}"))
+        return server
 
 
 class TransportBase:

@@ -318,6 +318,20 @@ class LogManager:
         new_entries = entries[keep_from:]
         if not new_entries:
             return True
+        sec = _TRACE.enter("log.stage") if _TRACE.enabled else None
+        try:
+            if not self._stage_follower_entries(new_entries):
+                return False
+        finally:
+            if sec is not None:
+                _TRACE.leave(sec)
+        await self._enqueue_flush(new_entries)
+        self._wake_waiters()
+        return True
+
+    def _stage_follower_entries(self, new_entries: list[LogEntry]) -> bool:
+        """CRC-check and stage in memory what a follower is about to
+        journal; False refuses the whole batch."""
         # Deferred wire-CRC check, once per entry actually staged (the
         # wire decode skips it for speed): a blob corrupted past TCP's
         # 16-bit checksum must NOT reach the journal — recovery scans
@@ -336,8 +350,6 @@ class LogManager:
             self._last_index = e.id.index
             if e.type == EntryType.CONFIGURATION:
                 self._track_conf(e)
-        await self._enqueue_flush(new_entries)
-        self._wake_waiters()
         return True
 
     def _track_conf(self, e: LogEntry) -> None:
@@ -400,12 +412,11 @@ class LogManager:
                             # IN the executor thread and feeds the EMA
                             # itself (StoreEngine wires the probe);
                             # begin/end here covers only the stall age.
-                            # The awaited envelope is the best span
-                            # available here (the commit round is
-                            # shared, not per-group).
+                            # It hands back the round's own fsync
+                            # interval; f0..f1 is the awaited envelope.
                             f0 = time.perf_counter()
-                            await append_async(entries, self._sync)
-                            f1 = time.perf_counter()
+                            fsync = await append_async(entries, self._sync)
+                            f1 = woke = time.perf_counter()
                         elif health is not None or tids:
                             # time the append+fsync IN the executor
                             # thread: end-to-end (awaited) duration
@@ -419,6 +430,8 @@ class LogManager:
                                 return t0, time.perf_counter()
 
                             f0, f1 = await loop.run_in_executor(None, _timed)
+                            woke = time.perf_counter()
+                            fsync = (f0, f1, True)
                             if health is not None:
                                 health.disk.note(f1 - f0)
                         else:
@@ -429,10 +442,20 @@ class LogManager:
                         if tok is not None:
                             health.disk.end(tok)
                     if tids:
+                        # the awaited envelope, then its two parts: the
+                        # fsync in the thread that ran it (the disk)
+                        # and, where that was an executor thread, from
+                        # its end to this resumption (the loop)
                         for tid in tids:
                             _TRACE.span(tid, "log_flush", f0, f1,
                                         proc=self._trace_proc,
                                         entries=len(entries))
+                            if isinstance(fsync, tuple):
+                                _TRACE.span(tid, "log_fsync", fsync[0],
+                                            fsync[1], proc=self._trace_proc)
+                                if fsync[2]:
+                                    _TRACE.span(tid, "log_wake", fsync[1],
+                                                woke, proc=self._trace_proc)
                     self._stable_index = max(self._stable_index, entries[-1].id.index)
                     if self._disk_budget is not None:
                         # ~32B/entry framing+index overhead on top of

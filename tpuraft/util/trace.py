@@ -7,17 +7,32 @@ Every stage the benches used to probe externally (client queue →
 ``_StoreSender`` batch → ``kv_command_batch`` RPC → server validate →
 propose → log flush → quorum ack → FSM apply → client ack) emits a span
 when tracing is enabled; disabled, every call site costs ONE attribute
-branch (``if _TRACE.enabled``) — the zero-cost claim ``make bench-gate``
-enforces.  Retention is two-tier: a seeded probabilistic sample keeps a
+branch (``if _TRACE.enabled``).  The contract is the benchmark's:
+``BENCHMARK.json`` bounds the end-to-end metrics with tracing off, and
+``PERF.md`` states what a traced run costs against an untraced one.
+Retention is two-tier: a seeded probabilistic sample keeps a
 deterministic fraction of ops end to end (full stage spans, context on
 the wire), and an adaptive slow-op trigger force-retains any op slower
 than a rolling p99 EMA even when the sampler skipped it — root span
 with duration and a ``slow`` flag, because the tail is exactly what
 you want attributed but universal candidacy must cost one clock read
-per op, not a span pipeline (``make bench-gate``'s 5% sampled-tracing
-budget is the contract).
+per op, not a span pipeline.
 Spans live in a bounded ring and export as Chrome trace-event JSON
-(``chrome://tracing`` / perfetto-loadable) via bench/soak ``--trace``.
+(``chrome://tracing`` / perfetto-loadable) via soak ``--trace``.
+
+Op spans are WAITING intervals on ``perf_counter``: they overlap and
+say nothing of what the loop thread runs meanwhile.  **Sections** do:
+a section is a synchronous stretch of the loop thread (``enter`` /
+``leave`` on that thread, never across an ``await``), named
+``<layer>.<what>``.  Each is a ``jax.profiler.TraceAnnotation`` — so it
+lands on the profiler's host line, on the device trace's clock — and
+adds to its name's calls, inclusive seconds and SELF seconds (inclusive
+minus what child sections cover).  Once a second the tracer rolls the
+self seconds of each section and of each layer prefix into the ring as
+``loop.<name>`` records, with ``loop.cpu`` (the thread's CPU seconds
+over the same second) beside them.  A ``tpuraft.trace_anchor``
+annotation carries one simultaneous (``perf_counter_ns``, ``time_ns``)
+pair, so the op spans can be laid over the profiler's trace offline.
 
 A trace context (one i64: ``seq << 1 | sampled``) rides the KV batch
 item and the ``AppendEntriesRequest`` as TRAILING defaulted wire fields
@@ -45,13 +60,23 @@ import random
 import threading
 import time
 from collections import deque
-from typing import Optional
+from typing import Optional, Union
 
 from tpuraft.util import describer
 
 # perf_counter is the span clock (monotonic, ns resolution); one wall
 # anchor taken at configure() maps it to absolute µs for the export
 _pc = time.perf_counter
+_thread_time = time.thread_time
+_get_ident = threading.get_ident
+
+# every roll-up record rides the one unconditional (odd) context
+_LOOP_TID = 1
+_LOOP_PROC = "loop"
+ANCHOR_EVENT = "tpuraft.trace_anchor"
+# a roll-up that finds this many whole seconds gone by was idle, not
+# busy: one record takes the lot and the buckets realign
+_MAX_SPREAD_BUCKETS = 8
 
 
 # graftcheck: loop-confined — created and consumed only by the Tracer
@@ -110,6 +135,13 @@ class Tracer:
         self.ops_slow_retained = 0
         self.ops_dropped = 0
         self.spans_recorded = 0
+        # loop sections: open frames innermost last ([name, annotation,
+        # child seconds, t0])
+        self._sec_stack: list = []
+        # None = not looked for yet, False = no JAX here, else the
+        # TraceAnnotation class (imported when a section first enters)
+        self._annotation: Union[None, bool, type] = None
+        self._arm_sections()
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -128,7 +160,31 @@ class Tracer:
         # NOTE: the wall/perf anchor is NOT re-taken here — spans store
         # offsets relative to the anchor, so re-anchoring mid-process
         # would shift every already-recorded span in the export
+        if enabled:
+            self._arm_sections()
         return self
+
+    def _arm_sections(self) -> None:
+        """Accumulators and buckets start here.  The thread that arms is
+        taken for the loop thread until a section says otherwise."""
+        self._close_open_sections()
+        # per name [calls, inclusive s, self s]
+        self._sec_acc: dict[str, list] = {}
+        self._sec_tid = _get_ident()
+        # the once-a-second roll-up: the open bucket's start on both
+        # clocks, and the accumulators as the last roll-up left them
+        self._bucket_t0 = _pc()
+        self._bucket_cpu0 = _thread_time()
+        self._bucket_snap: dict[str, tuple] = {}
+        self.anchor = (time.perf_counter_ns(), time.time_ns())
+        self._anchor_noted = False
+
+    def _close_open_sections(self) -> None:
+        while self._sec_stack:
+            frame = self._sec_stack.pop()
+            if frame[1] is not None:
+                frame[1].__exit__(None, None, None)
+                frame[1] = None
 
     def reset(self) -> None:
         """Drop all recorded/staged spans and counters (test isolation)."""
@@ -141,6 +197,138 @@ class Tracer:
         self.ops_seen = self.ops_sampled = 0
         self.ops_slow_retained = self.ops_dropped = 0
         self.spans_recorded = 0
+        self._arm_sections()
+
+    # -- loop sections (what the loop thread runs) ---------------------------
+
+    def enter(self, name: str, now: float = 0.0) -> Optional[list]:
+        """Open section ``name`` on the loop thread; the caller tests
+        ``enabled`` first (``sec = T.enter(n) if T.enabled else None``)
+        and hands the frame back to :meth:`leave` before any ``await``.
+        ``now`` is a ``perf_counter`` reading the caller already took.
+        None from a thread other than the loop's (an apply lane, an
+        executor): sections are loop-confined."""
+        if _get_ident() != self._sec_tid:
+            if self._sec_stack or self._sec_acc:
+                return None
+            # armed from another thread than the one that runs sections
+            self._sec_tid = _get_ident()
+            self._bucket_t0 = _pc()
+            self._bucket_cpu0 = _thread_time()
+        cls = self._annotation
+        if cls is None:
+            cls = self._find_annotation()
+        ann = None
+        if cls:
+            if not self._anchor_noted:
+                self._note_anchor(cls)
+            ann = cls(name)
+            ann.__enter__()
+        frame = [name, ann, 0.0, now or _pc()]
+        self._sec_stack.append(frame)
+        return frame
+
+    def leave(self, frame: list, now: float = 0.0) -> None:
+        """Close a section :meth:`enter` opened, also after ``enabled``
+        was cleared under it."""
+        t1 = now or _pc()
+        stack = self._sec_stack
+        if not stack or stack[-1] is not frame:
+            if frame not in stack:
+                # reset() or configure() ran under it: already closed
+                return
+            while stack[-1] is not frame:      # a leave was skipped above
+                self._pop(stack.pop(), t1)
+        self._pop(stack.pop(), t1)
+        if t1 - self._bucket_t0 >= 1.0 and self.enabled:
+            self._roll(t1)
+
+    def switch(self, frame: list, name: str, now: float = 0.0) -> list:
+        """Leave ``frame`` and enter ``name`` at one instant."""
+        now = now or _pc()
+        self.leave(frame, now)
+        return self.enter(name, now)
+
+    def _pop(self, frame: list, t1: float) -> None:
+        name, ann, child, t0 = frame
+        if ann is not None:
+            ann.__exit__(None, None, None)
+            frame[1] = None
+        dur = t1 - t0
+        acc = self._sec_acc.get(name)
+        if acc is None:
+            acc = self._sec_acc[name] = [0, 0.0, 0.0]
+        acc[0] += 1
+        acc[1] += dur
+        acc[2] += dur - child
+        if self._sec_stack:
+            self._sec_stack[-1][2] += dur
+
+    def _find_annotation(self):
+        """Import JAX's annotation when a section first enters.  A
+        process with no JAX (a store on the numpy twin) still traces:
+        accumulators and roll-ups, no annotation."""
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            self._annotation = False
+        else:
+            self._annotation = TraceAnnotation
+        return self._annotation
+
+    def _note_anchor(self, cls) -> None:
+        """One instant on three clocks: the annotation's own timestamp
+        is the profiler's, its metadata the other two."""
+        self._anchor_noted = True
+        self.anchor = pair = (time.perf_counter_ns(), time.time_ns())
+        with cls(ANCHOR_EVENT, perf_counter_ns=pair[0], time_ns=pair[1]):
+            pass
+
+    def _roll(self, now: float) -> None:
+        """Close the bucket(s) that ended before ``now``: one record per
+        section and per layer prefix with the self seconds spent there,
+        and ``loop.cpu`` with the thread's CPU seconds.  Whole seconds
+        with no section exit in them share the delta evenly."""
+        cpu = _thread_time()
+        whole = int(now - self._bucket_t0)
+        n_buckets = whole if whole <= _MAX_SPREAD_BUCKETS else 1
+        rows: dict[str, list] = {}
+        snap = self._bucket_snap
+        total_n, total_self = 0, 0.0
+        for name, acc in self._sec_acc.items():
+            n0, busy0, self0 = snap.get(name, (0, 0.0, 0.0))
+            n, busy, self_s = acc[0] - n0, acc[1] - busy0, acc[2] - self0
+            if not n:
+                continue
+            snap[name] = (acc[0], acc[1], acc[2])
+            rows[name] = [n, busy, self_s]
+            prefix, dot, _what = name.partition(".")
+            if dot:
+                layer = rows.setdefault(prefix, [0, 0.0, 0.0])
+                layer[0] += n
+                layer[1] += busy
+                layer[2] += self_s
+            total_n += n
+            total_self += self_s
+        # busy_s of loop.cpu: the wall seconds the sections account for
+        rows["cpu"] = [total_n, total_self, cpu - self._bucket_cpu0]
+        for k in range(n_buckets):
+            rel0 = self._bucket_t0 + k - self._pc0
+            for name, (n, busy, self_s) in rows.items():
+                self._ring.append(
+                    (_LOOP_TID, "loop." + name, _LOOP_PROC, rel0,
+                     max(0.0, self_s / n_buckets),
+                     {"n": n / n_buckets if n_buckets > 1 else n,
+                      "busy_s": busy / n_buckets}))
+            self.spans_recorded += len(rows)
+        self._bucket_t0 += whole
+        self._bucket_cpu0 = cpu
+
+    def section_table(self) -> dict:
+        """name -> (calls, inclusive seconds, self seconds) since the
+        tracer was armed."""
+        return {name: tuple(acc)
+                for name, acc in list(self._sec_acc.items())}
 
     # -- op lifecycle (locally-originated traces) ----------------------------
 
@@ -252,9 +440,15 @@ class Tracer:
 
     def chrome_events(self) -> list[dict]:
         """Chrome trace-event ("X" complete events + process_name
-        metadata) — the format chrome://tracing and perfetto load."""
+        metadata) — the format chrome://tracing and perfetto load.  The
+        first event states the clock anchor; the ``loop`` process holds
+        one row per roll-up name, a bar a second."""
         pids: dict[str, int] = {}
-        events: list[dict] = []
+        loop_rows: dict[str, int] = {}
+        events: list[dict] = [{
+            "ph": "M", "name": ANCHOR_EVENT, "pid": 0, "tid": 0,
+            "args": {"perf_counter_ns": self.anchor[0],
+                     "time_ns": self.anchor[1]}}]
         for ev_tid, name, proc, rel0, dur, args in list(self._ring):
             pid = pids.get(proc)
             if pid is None:
@@ -262,8 +456,16 @@ class Tracer:
                 events.append({"ph": "M", "name": "process_name",
                                "pid": pid, "tid": 0,
                                "args": {"name": proc}})
+            row = ev_tid >> 1
+            if proc == _LOOP_PROC:
+                row = loop_rows.get(name)
+                if row is None:
+                    row = loop_rows[name] = len(loop_rows) + 1
+                    events.append({"ph": "M", "name": "thread_name",
+                                   "pid": pid, "tid": row,
+                                   "args": {"name": name}})
             ev = {"ph": "X", "name": name, "pid": pid,
-                  "tid": ev_tid >> 1,
+                  "tid": row,
                   "ts": round((self._wall0 + rel0) * 1e6, 3),
                   "dur": round(dur * 1e6, 3),
                   "args": {"trace_id": ev_tid, **(args or {})}}
@@ -280,14 +482,23 @@ class Tracer:
 
     def counters(self) -> dict:
         """Monotonic series only (Prometheus 'counter' semantics —
-        rate()/increase() must never see a decrease)."""
-        return {
+        rate()/increase() must never see a decrease; re-arming the
+        tracer reads as a counter reset).  The section table rides
+        along, so a scrape sees the loop's share by layer with no
+        profiler attached: ``rate(trace_section_self_seconds_<name>)``
+        is that section's share of the loop thread."""
+        out = {
             "trace_ops_seen": self.ops_seen,
             "trace_ops_sampled": self.ops_sampled,
             "trace_ops_slow_retained": self.ops_slow_retained,
             "trace_ops_dropped": self.ops_dropped,
             "trace_spans_recorded": self.spans_recorded,
         }
+        for name, (n, busy, self_s) in self.section_table().items():
+            out[f"trace_section_calls_{name}"] = n
+            out[f"trace_section_busy_seconds_{name}"] = round(busy, 6)
+            out[f"trace_section_self_seconds_{name}"] = round(self_s, 6)
+        return out
 
     def gauges(self) -> dict:
         """Point-in-time series (toggles, ring occupancy, EMAs)."""
