@@ -252,10 +252,11 @@ async def drive_lanes(groups: int, devices: int, duration_s: float,
     # where the rows live: one direct call of the compiled tick on the
     # same mirrors, outputs left on the device — every device must hold
     # its G/devices rows, not everything on device 0
-    out = eng._tick_fn(eng._group_state(*eng._rel_views()),
-                       np.int32(eng.now_ms()), eng._params_dev)
-    shards = out.commit_rel.addressable_shards
-    rows_per_shard = [int(sh.data.shape[0]) for sh in shards]
+    # (G is the last axis of the one packed array a single device
+    # returns, and the only one of a mesh's [G] rows)
+    out = eng._call_tick(eng._group_state(*eng._rel_views()), eng.now_ms())
+    shards = jax.tree_util.tree_leaves(out)[0].addressable_shards
+    rows_per_shard = [int(sh.data.shape[-1]) for sh in shards]
     shard_devices = sorted(sh.device.id for sh in shards)
     n_dev = max(devices, 1)
     stats = eng.lane_stats()

@@ -15,6 +15,7 @@ from __future__ import annotations
 import asyncio
 import json
 
+import numpy as np
 import pytest
 
 from tpuraft.util.heat import (RegionHeatTracker, decode_heat_rows,
@@ -537,7 +538,7 @@ PER_TICK_HISTS = {"tick_total_ms", "tick_build_ms", "tick_device_ms",
                   "tick_fetch_ms", "tick_heartbeat_ms"}
 
 
-async def _engine(backend: str, g: int = 8):
+async def _engine(backend: str, g: int = 8, p: int = 4):
     """numpy: the twin, no loop needed.  jax: the jitted tick on CPU
     JAX, which ``start`` compiles (and then its loop is stopped)."""
     if backend == "numpy":
@@ -545,7 +546,7 @@ async def _engine(backend: str, g: int = 8):
     from tpuraft.core.engine import MultiRaftEngine
     from tpuraft.options import TickOptions
 
-    e = MultiRaftEngine(TickOptions(max_groups=g, max_peers=4,
+    e = MultiRaftEngine(TickOptions(max_groups=g, max_peers=p,
                                     backend="jax"))
     from tpuraft.util.metrics import Histogram
 
@@ -561,7 +562,7 @@ async def test_tick_phase_histograms_count_ticks(backend):
     for _ in range(5):
         e.tick_once()
     hists = e.tick_histograms()
-    assert set(hists) == PER_TICK_HISTS | {"tick_late_ms",
+    assert set(hists) == PER_TICK_HISTS | {"tick_late_ms", "tick_transfers",
                                            "fence_resolve_ms"}
     assert all(hists[k]["count"] == 5 for k in PER_TICK_HISTS)
     # the loop's lateness counts the loop's own ticks, a fence's wait
@@ -583,6 +584,62 @@ async def test_tick_phase_histograms_count_ticks(backend):
                                         "tick_fetch_ms")) > 0.0
     assert h["tick_heartbeat_ms"].total == 0.0     # no leader, no beat
     assert h["tick_heartbeat_ms"].total <= h["tick_apply_ms"].total
+    # one array up and one down a tick on the device path, each a
+    # sample of its bytes; the twin moves none
+    per_tick = 0 if backend == "numpy" else 2
+    assert h["tick_transfers"].count == 5 * per_tick
+    assert h["tick_transfers"].total == 5 * per_tick * 4 * (
+        (3 * e.P + 10 + 3) * e.G) / 2
+    assert e.lane_stats()["tick_transfers"] == per_tick
+
+
+def _assert_device_tick_is_the_twin(e, now: int) -> None:
+    from tpuraft.core.engine import _NpOutputs
+
+    rel, commit_rel = e._rel_views()
+    dev = e._device_tick(rel, commit_rel, now)
+    twin = e._np_tick(rel, commit_rel, now)
+    for name in _NpOutputs.__slots__:
+        a, b = getattr(dev, name), np.asarray(getattr(twin, name))
+        assert isinstance(a, np.ndarray) and a.shape == b.shape, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+async def test_packed_device_tick_equals_twin_across_a_grow(seed):
+    """One buffer up, one down: the jitted tick on live mirrors is the
+    numpy twin row for row, before and after ``_grow`` (buffer
+    reallocated, one recompile for the new shape)."""
+    from tests.test_ops_tick import randomize_mirrors
+    from tpuraft.ops.tick import packed_state_shape, raft_tick_packed_jit
+
+    rng = np.random.default_rng(seed)
+    g, p = 24 + 8 * seed, 5              # shapes no other test compiles
+    e = await _engine("jax", g=g, p=p)
+    assert e._tick_fn is raft_tick_packed_jit
+    buf = e._tick_buf
+    assert buf.shape == packed_state_shape(g, p) and buf.dtype == np.int32
+    for now in (700, 1100):
+        randomize_mirrors(e, rng)
+        _assert_device_tick_is_the_twin(e, now)
+        assert e._tick_buf is buf                    # reused tick to tick
+        assert e.lane_stats()["tick_transfers"] == 2
+    compiled = raft_tick_packed_jit._cache_size()
+
+    e._grow()
+    assert e.G == 2 * g and e._tick_buf is None
+    e.tick_once()
+    assert e._tick_buf.shape == packed_state_shape(2 * g, p)
+    for now in (900, 1300):
+        randomize_mirrors(e, rng)
+        _assert_device_tick_is_the_twin(e, now)
+    h = e.tick_hists
+    crossed = h["tick_transfers"].count
+    e.tick_once()
+    assert raft_tick_packed_jit._cache_size() == compiled + 1
+    assert h["tick_transfers"].count == crossed + 2 == 2 * (4 + 2)
+    assert h["tick_state_ms"].total + h["tick_call_ms"].total \
+        + h["tick_fetch_ms"].total == pytest.approx(h["tick_device_ms"].total)
 
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])

@@ -12,6 +12,7 @@ from tpuraft.ops.tick import (  # noqa: E402
     ROLE_INACTIVE,
     ROLE_LEADER,
     GroupState,
+    TickOutputs,
     TickParams,
     raft_tick,
 )
@@ -155,83 +156,185 @@ def test_jit_and_large_g():
     assert ns.match_rel.shape == (G, 8)
 
 
+TICK_OUTPUT_ROWS = tuple(TickOutputs.__dataclass_fields__)
+
+
+def randomize_mirrors(eng, rng) -> None:
+    """Live-looking rows in every mirror and parameter row the tick
+    reads, in place (no controls registered, so a tick applies nothing
+    from its outputs)."""
+    from tpuraft.core.engine import _NEG_I32
+
+    G, P = eng.G, eng.P
+    # per-group protocol params ([G] rows, VERDICT r2 #5): the twin
+    # and the device tick must agree under MIXED timeouts too
+    eng.eto_ms[:] = rng.integers(200, 2000, G)
+    eng.hb_ms[:] = rng.integers(20, 200, G)
+    eng.lease_ms[:] = rng.integers(100, 1800, G)
+    eng.snap_ms[:] = rng.integers(0, 2, G) * 700
+    eng._params_dev = None
+    eng.role[:] = rng.integers(0, 4, G)
+    eng.pending_rel[:] = rng.integers(1, 20, G)
+    eng.match_abs[:] = rng.integers(0, 100, (G, P))
+    eng.commit_abs[:] = rng.integers(0, 40, G)
+    eng.voter_mask[:] = rng.random((G, P)) < 0.7
+    eng.old_voter_mask[:] = np.where(
+        (rng.random(G) < 0.2)[:, None], rng.random((G, P)) < 0.5, False)
+    eng.granted[:] = rng.random((G, P)) < 0.4
+    for row in (eng.elect_deadline, eng.hb_deadline, eng.snap_deadline,
+                eng.stepdown_deadline):
+        row[:] = rng.integers(0, 2000, G)
+    eng.last_ack[:] = np.where(rng.random((G, P)) < 0.8,
+                               rng.integers(0, 1500, (G, P)), _NEG_I32)
+    # quiescence lane: hibernating groups must suppress hb_due /
+    # election_due identically in both formulations (step_down and
+    # lease_valid stay LIVE for quiescent leaders)
+    eng.quiescent[:] = rng.random(G) < 0.3
+    # witness lane (ISSUE 19): witness columns clamp the commit
+    # reduce to the best data-replica match in both formulations
+    eng.witness_mask[:] = rng.random((G, P)) < 0.2
+    eng._n_witness_slots = int(eng.witness_mask.any(axis=1).sum())
+    # read-fence lane
+    eng.fence_start[:] = np.where(rng.random(G) < 0.4,
+                                  rng.integers(0, 1500, G), _NEG_I32)
+
+
+def _random_engine(rng, G, P):
+    """A numpy-backend engine on randomized mirrors, and the
+    (rel, commit_rel, now) its tick would see."""
+    from tpuraft.core.engine import MultiRaftEngine
+    from tpuraft.options import TickOptions
+
+    eng = MultiRaftEngine(TickOptions(
+        max_groups=G, max_peers=P, backend="numpy"))
+    randomize_mirrors(eng, rng)
+    return eng, *eng._rel_views(), int(rng.integers(500, 1500))
+
+
 def test_numpy_twin_matches_device_tick_randomized():
     """The engine's no-jax fallback (MultiRaftEngine._np_tick) must stay
     BIT-IDENTICAL to ops.tick.raft_tick — quorum semantics now live in
     several formulations (jnp kernel, numpy twin, scalar BallotBox) and
     this differential test is the drift tripwire for the first two."""
-    import numpy as np
-
-    from tpuraft.core.engine import MultiRaftEngine, _NEG_I32
-    from tpuraft.options import TickOptions
-    from tpuraft.ops.tick import GroupState, TickParams, raft_tick
-
     rng = np.random.default_rng(42)
     G, P = 64, 5
     for trial in range(10):
-        eng = MultiRaftEngine(TickOptions(
-            max_groups=G, max_peers=P, backend="numpy"))
-        # per-group protocol params ([G] rows, VERDICT r2 #5): the twin
-        # and the device tick must agree under MIXED timeouts too
-        eng.eto_ms = rng.integers(200, 2000, G)
-        eng.hb_ms = rng.integers(20, 200, G)
-        eng.lease_ms = rng.integers(100, 1800, G)
-        eng.role = rng.integers(0, 4, G).astype(np.int32)
-        eng.pending_rel = rng.integers(1, 20, G).astype(np.int32)
-        eng.voter_mask = rng.random((G, P)) < 0.7
-        eng.old_voter_mask = np.where(
-            (rng.random(G) < 0.2)[:, None], rng.random((G, P)) < 0.5, False)
-        eng.granted = rng.random((G, P)) < 0.4
-        eng.elect_deadline = rng.integers(0, 2000, G)
-        eng.hb_deadline = rng.integers(0, 2000, G)
-        eng.last_ack = np.where(rng.random((G, P)) < 0.8,
-                                rng.integers(0, 1500, (G, P)), _NEG_I32)
-        # quiescence lane: hibernating groups must suppress hb_due /
-        # election_due identically in both formulations (step_down and
-        # lease_valid stay LIVE for quiescent leaders)
-        eng.quiescent = rng.random(G) < 0.3
-        # witness lane (ISSUE 19): witness columns clamp the commit
-        # reduce to the best data-replica match in both formulations
-        eng.witness_mask = rng.random((G, P)) < 0.2
-        eng._n_witness_slots = int(eng.witness_mask.any(axis=1).sum())
-        # stepdown/priority + read-fence lanes
-        eng.stepdown_deadline = rng.integers(0, 2000, G)
-        eng.fence_start = np.where(rng.random(G) < 0.4,
-                                   rng.integers(0, 1500, G), _NEG_I32)
-        rel = rng.integers(0, 100, (G, P)).astype(np.int32)
-        commit_now = rng.integers(0, 40, G).astype(np.int32)
-        now = int(rng.integers(500, 1500))
-
+        eng, rel, commit_now, now = _random_engine(rng, G, P)
         np_out = eng._np_tick(rel, commit_now, now)
-
-        state = GroupState(
-            role=eng.role.copy(),
-            commit_rel=commit_now.copy(),
-            pending_rel=eng.pending_rel.copy(),
-            match_rel=rel.copy(),
-            granted=eng.granted.copy(),
-            voter_mask=eng.voter_mask.copy(),
-            old_voter_mask=eng.old_voter_mask.copy(),
-            elect_deadline=eng.elect_deadline.astype(np.int32),
-            hb_deadline=eng.hb_deadline.astype(np.int32),
-            last_ack=eng.last_ack.astype(np.int32),
-            snap_deadline=eng.snap_deadline.astype(np.int32),
-            quiescent=eng.quiescent.copy(),
-            witness_mask=eng.witness_mask.copy(),
-            stepdown_deadline=eng.stepdown_deadline.astype(np.int32),
-            fence_start=eng.fence_start.astype(np.int32),
-        )
-        _, dev_out = raft_tick(state, np.int32(now),
+        _, dev_out = raft_tick(eng._group_state(rel, commit_now),
+                               np.int32(now),
                                TickParams.make(eng.eto_ms, eng.hb_ms,
                                                eng.lease_ms, eng.snap_ms))
-        for field in ("commit_rel", "commit_advanced", "elected",
-                      "election_due", "step_down", "hb_due",
-                      "lease_valid", "snap_due", "q_ack",
-                      "stepdown_due", "fence_ok"):
+        for field in TICK_OUTPUT_ROWS:
             np.testing.assert_array_equal(
                 np.asarray(getattr(dev_out, field)),
                 np.asarray(getattr(np_out, field)),
                 err_msg=f"trial {trial}: {field} diverged")
+
+
+def _extreme_state(rng, G, P):
+    """A GroupState of numpy rows over the whole int32 range: all four
+    roles, NEG_INF acks and fences, joint and witness masks, quiescent
+    rows, negative and near-2**31 values."""
+    from tpuraft.ops.ballot import NEG_INF_I32
+
+    lo, hi = np.iinfo(np.int32).min, np.iinfo(np.int32).max
+    edge = np.array([lo, lo + 1, -1, 0, 1, hi - 1, hi], np.int32)
+
+    def ints(shape, neg_inf_share=0.0):
+        a = np.where(rng.random(shape) < 0.3, rng.choice(edge, shape),
+                     rng.integers(lo, hi, shape, np.int32, endpoint=True))
+        return np.where(rng.random(shape) < neg_inf_share,
+                        np.int32(NEG_INF_I32), a).astype(np.int32)
+
+    return GroupState(
+        role=rng.permutation(np.arange(G) % 4).astype(np.int32),
+        commit_rel=ints(G),
+        pending_rel=ints(G),
+        match_rel=ints((G, P)),
+        granted=rng.random((G, P)) < 0.5,
+        voter_mask=rng.random((G, P)) < 0.7,
+        old_voter_mask=np.where((rng.random(G) < 0.3)[:, None],
+                                rng.random((G, P)) < 0.5, False),
+        elect_deadline=ints(G),
+        hb_deadline=ints(G),
+        last_ack=ints((G, P), neg_inf_share=0.3),
+        snap_deadline=ints(G),
+        quiescent=rng.random(G) < 0.3,
+        witness_mask=rng.random((G, P)) < 0.3,
+        stepdown_deadline=ints(G),
+        fence_start=ints(G, neg_inf_share=0.5),
+    )
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("G,P", [(8, 3), (128, 4), (2048, 4), (64, 7)])
+def test_packed_layout_is_the_identity_and_the_same_tick(G, P, seed):
+    """One int32 buffer each way (ops/tick.py): pack -> unpack gives the
+    GroupState back bit for bit, and the packed program's unpacked
+    outputs are raft_tick_outputs' and the numpy twin's, row for row."""
+    from tpuraft.ops.tick import (
+        PACKED_OUTPUT_MASKS, pack_outputs, pack_state,
+        packed_state_shape, raft_tick_outputs_jit, raft_tick_packed_jit,
+        unpack_outputs, unpack_state)
+
+    rng = np.random.default_rng(1000 * G + 10 * P + seed)
+    lo, hi = np.iinfo(np.int32).min, np.iinfo(np.int32).max
+    state = _extreme_state(rng, G, P)
+    now = int(rng.choice([lo, -7, 0, 12345, hi]))
+
+    def same_state(back, back_now):
+        assert int(back_now) == now
+        for name in GroupState.__dataclass_fields__:
+            a, b = np.asarray(getattr(back, name)), getattr(state, name)
+            assert a.dtype == b.dtype and a.shape == b.shape, name
+            np.testing.assert_array_equal(a, b, err_msg=name)
+
+    # into a dirty reused buffer, as the engine packs
+    buf = np.full(packed_state_shape(G, P), -1, np.int32)
+    assert pack_state(state, now, buf) is buf
+    assert buf.shape == (3 * P + 10, G)
+    np.testing.assert_array_equal(pack_state(state, now)[:-1], buf[:-1])
+    same_state(*unpack_state(buf))                    # the numpy inverse
+    same_state(*jax.jit(unpack_state)(buf))           # as the program does
+
+    # outputs: the device packer against its numpy inverse
+    rows = {name: rng.random(G) < 0.5 for name in PACKED_OUTPUT_MASKS}
+    rows["commit_rel"] = state.commit_rel
+    rows["q_ack"] = state.fence_start
+    packed = np.asarray(jax.jit(pack_outputs)(TickOutputs(**rows)))
+    assert packed.shape == (3, G) and packed.dtype == np.int32
+    back = unpack_outputs(packed)
+    assert set(back) == set(TICK_OUTPUT_ROWS)
+    for name in TICK_OUTPUT_ROWS:
+        assert back[name].dtype == rows[name].dtype, name
+        np.testing.assert_array_equal(back[name], rows[name], err_msg=name)
+
+    # the packed program is the same tick, on the extreme rows ...
+    params = TickParams.make(rng.integers(200, 2000, G),
+                             rng.integers(20, 200, G),
+                             rng.integers(100, 1800, G),
+                             rng.integers(0, 2, G) * 500)
+    want = raft_tick_outputs_jit(state, np.int32(now), params)
+    got = unpack_outputs(np.asarray(raft_tick_packed_jit(buf, params)))
+    for name in TICK_OUTPUT_ROWS:
+        np.testing.assert_array_equal(
+            got[name], np.asarray(getattr(want, name)), err_msg=name)
+
+    # ... and on an engine's rows, where it is also the numpy twin's
+    eng, rel, commit_now, now = _random_engine(rng, G, P)
+    state = eng._group_state(rel, commit_now)
+    params = TickParams.make(eng.eto_ms, eng.hb_ms, eng.lease_ms,
+                             eng.snap_ms)
+    want = raft_tick_outputs_jit(state, np.int32(now), params)
+    twin = eng._np_tick(rel, commit_now, now)
+    got = unpack_outputs(np.asarray(
+        raft_tick_packed_jit(pack_state(state, now), params)))
+    for name in TICK_OUTPUT_ROWS:
+        np.testing.assert_array_equal(
+            got[name], np.asarray(getattr(want, name)), err_msg=name)
+        np.testing.assert_array_equal(
+            got[name], np.asarray(getattr(twin, name)), err_msg=name)
 
 
 def test_witness_clamp_enumeration_matches_host_and_quorum_math():

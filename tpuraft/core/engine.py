@@ -717,7 +717,8 @@ class EngineControl:
 
 
 class _NpOutputs:
-    """numpy TickOutputs twin (backend="numpy" fallback)."""
+    """TickOutputs as numpy rows: what the numpy twin returns, and what
+    ``_fetch`` unpacks the device tick's one downloaded buffer into."""
 
     __slots__ = ("commit_rel", "commit_advanced", "elected", "election_due",
                  "step_down", "hb_due", "lease_valid", "snap_due", "q_ack",
@@ -834,6 +835,15 @@ class MultiRaftEngine:
         self._task: Optional[asyncio.Task] = None
         self._stopped = False
         self._tick_fn = None  # jitted raft_tick outputs (None => numpy path)
+        # single-device jax path: the ONE host array a tick uploads
+        # (ops/tick.py pack_state), allocated on first use and again
+        # after _grow.  Reused from tick to tick only because _fetch has
+        # waited for the program before tick_once returns: on the CPU
+        # backend JAX may alias host memory instead of copying it.
+        self._packed = False
+        self._tick_buf: Optional[np.ndarray] = None
+        # arrays the last tick moved across the host/device boundary
+        self._tick_transfers = 0
         self._deadline_fold = None  # mesh mode: sharded earliest-deadline min
         self._params_dev = None
         self.ticks = 0
@@ -860,11 +870,18 @@ class MultiRaftEngine:
             "tick_apply_ms": Histogram(),
             # tick_device_ms in its three parts (they sum to it: the
             # same clock reads): GroupState build from the mirrors; the
-            # jitted call (argument transfer + enqueue; the whole numpy
-            # twin on that backend); wait for the device + download
+            # jitted call (pack into the one upload buffer + its
+            # transfer + enqueue; the whole numpy twin on that backend);
+            # wait for the device + the one download and its unpacking
             "tick_state_ms": Histogram(),
             "tick_call_ms": Histogram(),
             "tick_fetch_ms": Histogram(),
+            # NOT per tick but per ARRAY that crosses the host/device
+            # boundary (host arrays handed to the call + arrays
+            # downloaded), sampled with its bytes: count / ticks is 2
+            # packed, 27 over the mesh, 0 on the numpy twin.  The count
+            # is what a tick pays for, the bytes are not (PERF.md 6)
+            "tick_transfers": Histogram(),
             # _flush_heartbeats inside tick_apply_ms (0 on a tick with
             # no beat due)
             "tick_heartbeat_ms": Histogram(),
@@ -1127,6 +1144,7 @@ class MultiRaftEngine:
         self.snap_ms = pad(self.snap_ms)
         self.snap_deadline = pad(self.snap_deadline)
         self._params_dev = None  # [G] rows must match the grown shape
+        self._tick_buf = None    # and so must the upload buffer
         self._peer_cols.extend(dict() for _ in range(old_g))
         self._boxes.extend([None] * old_g)
         self._ctrls.extend([None] * old_g)
@@ -1413,6 +1431,7 @@ class MultiRaftEngine:
             "witness_groups": self._n_witness_slots,
             "stepdown_ticks": self.stepdown_ticks,
             "tick_failures": self.tick_failures,
+            "tick_transfers": self._tick_transfers,
             "fence_lane_armed": self.fence_lane_armed,
             "fence_lane_resolves": self.fence_lane_resolves,
             "fences_pending": sum(len(w) for w
@@ -1459,7 +1478,7 @@ class MultiRaftEngine:
         if self._resolve_backend() != "numpy":
             import jax
 
-            from tpuraft.ops.tick import raft_tick_outputs_jit
+            from tpuraft.ops.tick import raft_tick_packed_jit
             from tpuraft.util.jax_cache import ensure_compile_cache
 
             ensure_compile_cache()
@@ -1493,8 +1512,10 @@ class MultiRaftEngine:
             else:
                 # the PROCESS-WIDE jitted instance: all engines share one
                 # trace cache, so only the first engine (per [G, P]
-                # shape) pays a compile
-                self._tick_fn = raft_tick_outputs_jit
+                # shape) pays a compile.  One device: one packed array
+                # up, one down (_call_tick / _fetch)
+                self._tick_fn = raft_tick_packed_jit
+                self._packed = True
             # warm the compile NOW, before any node registers: a first
             # tick mid-protocol would block the event loop for the
             # compile and miss every group's heartbeat window at once
@@ -1697,6 +1718,7 @@ class MultiRaftEngine:
                     sec = _TRACE.switch(sec, "tick.fetch", tc)
                 out = self._fetch(out)
             else:  # numpy twin (tiny deployments / no jax): all "call"
+                self._tick_transfers = 0
                 ts = t1
                 if sec is not None:
                     sec = _TRACE.switch(sec, "tick.call", ts)
@@ -1741,11 +1763,11 @@ class MultiRaftEngine:
         return rel, commit_rel_now
 
     def _group_state(self, rel, commit_rel_now):
-        """The tick's input built from the host mirrors.  numpy goes
-        STRAIGHT into the jitted call — jit commits it to the device
-        itself, and an explicit jnp.asarray per field doubles the
-        per-tick host overhead (profiled: the asarray+device_put pair
-        dominated small-G tick cost)."""
+        """The tick's input as a GroupState of numpy rows, built from
+        the host mirrors (int32 views of the int64 time rows).  Nothing
+        crosses to the device here: ``_call_tick`` packs the rows into
+        its one upload buffer (the mesh path hands them over as they
+        are)."""
         from tpuraft.ops.tick import GroupState
 
         return GroupState(
@@ -1773,24 +1795,51 @@ class MultiRaftEngine:
             self._group_state(rel, commit_rel_now), now))
 
     def _call_tick(self, state, now):
-        """The jitted call: hands the numpy rows over and enqueues the
-        program; returns device arrays that may not be computed yet."""
+        """The jitted call: copies the fifteen rows and ``now`` into the
+        one int32 upload buffer, hands that array over and enqueues the
+        program; returns one packed device array that may not be
+        computed yet.  Over a mesh the rows go up as they are (sixteen
+        host arrays) and a TickOutputs of device rows comes back.
+        TickParams stay prefetched on the device either way."""
         import jax
 
-        from tpuraft.ops.tick import TickParams
+        from tpuraft.ops.tick import (TickParams, pack_state,
+                                      packed_state_shape)
 
         if self._params_dev is None:
             self._params_dev = TickParams.make(self.eto_ms, self.hb_ms,
                                                self.lease_ms, self.snap_ms)
+        if self._packed:
+            if self._tick_buf is None:
+                self._tick_buf = np.empty(
+                    packed_state_shape(self.G, self.P), np.int32)
+            args = (pack_state(state, now, self._tick_buf),)
+        else:
+            args = (state, np.int32(now))
+        self._tick_transfers = 0
+        for a in jax.tree_util.tree_leaves(args):
+            self._note_transfer(a)
         with jax.profiler.TraceAnnotation("tpuraft.raft_tick"):
-            return self._tick_fn(state, np.int32(now), self._params_dev)
+            return self._tick_fn(*args, self._params_dev)
 
-    @staticmethod
-    def _fetch(out):
-        """Wait for the device and download every output row."""
-        import jax
+    def _fetch(self, out) -> _NpOutputs:
+        """Wait for the device, download the one packed output array and
+        name its eleven rows (over a mesh: eleven downloads)."""
+        from tpuraft.ops.tick import unpack_outputs
 
-        return jax.tree_util.tree_map(np.asarray, out)
+        if self._packed:
+            self._note_transfer(out)
+            return _NpOutputs(**unpack_outputs(np.asarray(out)))
+        rows = {name: np.asarray(getattr(out, name))
+                for name in _NpOutputs.__slots__}
+        for a in rows.values():
+            self._note_transfer(a)
+        return _NpOutputs(**rows)
+
+    def _note_transfer(self, a) -> None:
+        """One array crossed the host/device boundary."""
+        self._tick_transfers += 1
+        self.tick_hists["tick_transfers"].update(a.nbytes)
 
     def _np_tick(self, rel, commit_rel_now, now) -> _NpOutputs:
         """Bit-exact numpy twin of tpuraft.ops.tick.raft_tick (the

@@ -303,6 +303,112 @@ def raft_tick_outputs(state: GroupState, now_ms: jnp.ndarray,
 raft_tick_outputs_jit = jax.jit(raft_tick_outputs)
 
 
+# ---------------------------------------------------------------------------
+# Packed host/device layout: one int32 buffer each way.
+#
+# A transfer costs the host per ARRAY, not per byte (PERF.md section 6,
+# PR 28), so the single-device engine crosses the boundary with one
+# array up and one down.  Field-major: every field is a contiguous row
+# and G stays the minor (lane) axis.
+#
+#   up, int32 [3P + 10, G]:
+#     rows 0..8         the nine [G] fields of PACKED_STATE_ROWS
+#     rows 9..9+P       match_rel.T
+#     rows 9+P..9+2P    last_ack.T
+#     rows 9+2P..9+3P   the four [G, P] masks of PACKED_STATE_MASKS,
+#                       transposed, one bit each (bit i = i-th name)
+#     row  9+3P         now_ms, in element 0
+#   down, int32 [3, G]: commit_rel, q_ack, the nine masks of
+#     PACKED_OUTPUT_MASKS as bits of one row.
+#
+# The layout is a function of (G, P) alone; P is read back off the row
+# count.  pack_state / unpack_outputs run on the host (numpy),
+# unpack_state / pack_outputs inside the jitted program; the unpackers
+# use operators only, so each also inverts its packer in plain numpy.
+# ---------------------------------------------------------------------------
+
+PACKED_STATE_ROWS = ("role", "commit_rel", "pending_rel", "elect_deadline",
+                     "hb_deadline", "snap_deadline", "stepdown_deadline",
+                     "fence_start", "quiescent")
+PACKED_STATE_MASKS = ("granted", "voter_mask", "old_voter_mask",
+                      "witness_mask")
+PACKED_OUTPUT_MASKS = ("commit_advanced", "elected", "election_due",
+                       "step_down", "hb_due", "lease_valid", "snap_due",
+                       "stepdown_due", "fence_ok")
+_N_ROWS = len(PACKED_STATE_ROWS)
+
+
+def packed_state_shape(g: int, p: int) -> tuple[int, int]:
+    return (_N_ROWS + 3 * p + 1, g)
+
+
+def pack_state(state: GroupState, now_ms: int,
+               out: np.ndarray | None = None) -> np.ndarray:
+    """Host side: copy a GroupState of numpy rows and ``now_ms`` into
+    one int32 ``[3P + 10, G]`` buffer (``out``, or a new one)."""
+    g, p = state.match_rel.shape
+    if out is None:
+        out = np.empty(packed_state_shape(g, p), np.int32)
+    for i, name in enumerate(PACKED_STATE_ROWS):
+        out[i] = getattr(state, name)
+    a = _N_ROWS
+    out[a:a + p] = np.asarray(state.match_rel).T
+    out[a + p:a + 2 * p] = np.asarray(state.last_ack).T
+    bits = np.zeros((g, p), np.uint8)
+    for i, name in enumerate(PACKED_STATE_MASKS):
+        bits |= np.asarray(getattr(state, name), bool).view(np.uint8) << i
+    out[a + 2 * p:a + 3 * p] = bits.T
+    out[a + 3 * p, 0] = now_ms
+    return out
+
+
+def unpack_state(buf) -> tuple[GroupState, jnp.ndarray]:
+    """(GroupState, now_ms) from a packed buffer: pack_state's inverse.
+    Slices and operators only, so it traces under jit and also runs on
+    a numpy buffer."""
+    p = (buf.shape[0] - _N_ROWS - 1) // 3
+    a = _N_ROWS
+    fields = {name: buf[i] for i, name in enumerate(PACKED_STATE_ROWS)}
+    fields["quiescent"] = fields["quiescent"] != 0
+    fields["match_rel"] = buf[a:a + p].T
+    fields["last_ack"] = buf[a + p:a + 2 * p].T
+    bits = buf[a + 2 * p:a + 3 * p].T
+    for i, name in enumerate(PACKED_STATE_MASKS):
+        fields[name] = (bits & (1 << i)) != 0
+    return GroupState(**fields), buf[a + 3 * p, 0]
+
+
+def pack_outputs(out: TickOutputs) -> jnp.ndarray:
+    """Device side: the eleven output rows as one int32 ``[3, G]``."""
+    bits = jnp.zeros_like(out.commit_rel)
+    for i, name in enumerate(PACKED_OUTPUT_MASKS):
+        bits |= getattr(out, name).astype(jnp.int32) << i
+    return jnp.stack([out.commit_rel, out.q_ack, bits])
+
+
+def unpack_outputs(buf) -> dict:
+    """The eleven named rows of a packed ``[3, G]`` output buffer:
+    pack_outputs' inverse (numpy in, numpy out)."""
+    rows = {"commit_rel": buf[0], "q_ack": buf[1]}
+    for i, name in enumerate(PACKED_OUTPUT_MASKS):
+        rows[name] = (buf[2] & (1 << i)) != 0
+    return rows
+
+
+def _raft_tick_packed(buf: jnp.ndarray, params: TickParams) -> jnp.ndarray:
+    state, now_ms = unpack_state(buf)
+    return pack_outputs(raft_tick(state, now_ms, params)[1])
+
+
+# The device program keeps the name the trace readers key on
+# (jit_raft_tick_outputs: raft_tick_us, raft_tick_roofline): unpack,
+# raft_tick and pack are ONE program per tick.
+_raft_tick_packed.__name__ = raft_tick_outputs.__name__
+# process-wide like raft_tick_outputs_jit above: one trace cache for
+# every engine of a [G, P] shape
+raft_tick_packed_jit = jax.jit(_raft_tick_packed)
+
+
 def witness_lanes_available() -> bool:
     """Does the loaded device plane carry the witness/priority/fence
     parity lanes?  StoreEngine consults this before accepting a witness
