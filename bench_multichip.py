@@ -95,6 +95,11 @@ class _StubCtrl:
     def schedule(self, name: str, handler) -> None:
         self.counts[name] = self.counts.get(name, 0) + 1
 
+    def priority_rounds_accrue(self) -> bool:
+        # every stepdown_due fire reaches the handler, fresh row or not:
+        # the lane's deliveries are what drive_lanes counts
+        return True
+
     def maybe_quiesce(self, now: int) -> None:
         pass
 

@@ -256,6 +256,62 @@ async def test_engine_step_down_mask_on_quorum_loss():
         await c.stop_all()
 
 
+async def test_stepdown_lane_in_one_pass_and_the_event_counters():
+    """The stepdown_due lane asks a node only where this tick's q_ack row
+    is stale (or priority rounds accrue): a healthy leader's rounds fire
+    and re-arm with no ``_check_dead_nodes`` call at all, and a leader
+    that has lost its quorum is still asked and still steps down within
+    its timeout.  Elections started, leader step-downs (by lane) and beat
+    rows are counted one sample an event in ``tick_hists``."""
+    c = MultiRaftCluster(3, 2, election_timeout_ms=600)
+    await c.start_all()
+    try:
+        leaders = [await c.wait_leader(g) for g in c.groups]
+        leader = leaders[0]
+        eng = c.engines[leader.server_id.endpoint]
+        started = sum(e.tick_hists["elections_started"].count
+                      for e in c.engines.values())
+        assert started >= len(c.groups)     # one real election a group
+        asked: list = []
+        orig = type(leader)._check_dead_nodes
+
+        async def counting(self):
+            asked.append(self.group_id)
+            return await orig(self)
+
+        type(leader)._check_dead_nodes = counting
+        try:
+            before = eng.stepdown_ticks
+            rows = eng.tick_hists["beat_rows"].count
+            await asyncio.sleep(1.0)        # three rounds at eto/2
+            assert eng.stepdown_ticks > before
+            assert asked == []              # every round settled in the pass
+            assert leader.state == State.LEADER
+            # 2 followers a led group every 60 ms
+            assert eng.tick_hists["beat_rows"].count - rows >= 10
+            assert eng.lane_stats()["leader_stepdowns"] == {
+                "quorum": 0, "term": 0, "other": 0}
+            for ep in c.endpoints:
+                if ep != leader.server_id:
+                    c.net.stop_endpoint(ep.endpoint)
+            deadline = asyncio.get_running_loop().time() + 5
+            while asyncio.get_running_loop().time() < deadline:
+                if leader.state != State.LEADER:
+                    break
+                await asyncio.sleep(0.05)
+            assert leader.state != State.LEADER
+            assert leader.group_id in asked
+            mine = [ld for ld in leaders if ld.server_id == leader.server_id]
+            assert eng.lane_stats()["leader_stepdowns"]["quorum"] >= 1
+            assert 1 <= eng.tick_hists["leader_stepdowns"].count <= len(mine)
+        finally:
+            type(leader)._check_dead_nodes = orig
+    finally:
+        for ep in c.endpoints:
+            c.net.start_endpoint(ep.endpoint)
+        await c.stop_all()
+
+
 async def test_engine_lease_from_ack_plane():
     """LEASE_BASED validity comes from the engine's last_ack rows (the
     same rows the device lease_valid mask reduces): healthy -> valid;

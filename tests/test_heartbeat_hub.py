@@ -4,7 +4,7 @@ reference counterpart)."""
 
 import asyncio
 
-import pytest  # noqa: F401
+import pytest
 
 from tests.cluster import TestCluster
 from tests.test_engine import MultiRaftCluster
@@ -174,6 +174,7 @@ def _fake_beat_rep(transport, peer_ep="dst:1"):
         peer=SimpleNamespace(endpoint=peer_ep),
         match_index=7,
         last_rpc_ack=0.0,
+        _beats_inflight=0,
     )
 
 
@@ -275,6 +276,74 @@ async def test_fast_beat_enomethod_counts_fallbacks_and_pins_classic():
     assert snap["hub.rpcs_sent"] == hub.rpcs_sent
     # counters() (the soak stats line's view) agrees with the gauges
     assert hub.counters()["fast_fallbacks"] == 3
+
+
+class _HeldTransport:
+    """Beat RPCs that stay on the wire until released; records what each
+    carried."""
+
+    def __init__(self):
+        self.sent: list = []            # (method, number of beats)
+        self.release = asyncio.Event()
+
+    async def call(self, dst, method, request, timeout_ms=None):
+        from tpuraft.rpc.messages import (BatchResponse,
+                                          MultiHeartbeatResponse)
+
+        fast = method == "multi_beat_fast"
+        n = len(request.items if fast else request.beats)
+        self.sent.append((method, n))
+        await self.release.wait()
+        if fast:
+            return BatchResponse(items=[SimpleNamespace(ok=True)] * n)
+        return MultiHeartbeatResponse(acks=[])      # short: read as silence
+
+
+def _classic_beat_rep(transport):
+    from tpuraft.rpc.messages import AppendEntriesRequest
+
+    r = _fake_beat_rep(transport)
+    r._matched = False                  # not probed yet: classic beats
+    r.build_heartbeat_request = lambda: AppendEntriesRequest(
+        group_id="g", server_id="srv:1", peer_id="dst:1", term=3,
+        prev_log_index=7, prev_log_term=3, committed_index=7)
+    return r
+
+
+@pytest.mark.parametrize("make_rep, per_rpc, method", [
+    (_fake_beat_rep, "max_fast_beats_per_rpc", "multi_beat_fast"),
+    (_classic_beat_rep, "max_beats_per_rpc", "multi_heartbeat"),
+])
+async def test_a_beat_in_flight_silences_its_own_replicators_only(
+        make_rep, per_rpc, method):
+    """What kept 4,096 leaders from holding (ISSUE 29): the in-flight guard
+    was keyed by a chunk's POSITION in the pulse, so while one slow RPC was
+    out, whichever groups came to sit at that position next pulse were
+    dropped without a beat, pulse after pulse, until their followers
+    started elections and their leaders read a dead quorum.  It is counted
+    per replicator: a pulse leaves out exactly those whose last beat is
+    unanswered."""
+    hub = HeartbeatHub()
+    setattr(hub, per_rpc, 2)
+    tr = _HeldTransport()
+    first = [make_rep(tr) for _ in range(2)]
+    second = [make_rep(tr) for _ in range(2)]
+    hub.pulse(first)
+    await asyncio.sleep(0)
+    assert tr.sent == [(method, 2)]
+    hub.pulse(second)                   # same destination, same position
+    await asyncio.sleep(0)
+    assert tr.sent == [(method, 2), (method, 2)]
+    hub.pulse(first + second)           # all four still unanswered
+    await asyncio.sleep(0)
+    assert len(tr.sent) == 2 and hub.beats_skipped == 4
+    tr.release.set()
+    await asyncio.sleep(0.02)
+    assert not hub._inflight
+    assert all(r._beats_inflight == 0 for r in first + second)
+    hub.pulse(first + second)
+    await asyncio.sleep(0.02)
+    assert tr.sent[2:] == [(method, 2), (method, 2)]
 
 
 class AutoMultiRaftCluster(MultiRaftCluster):

@@ -562,8 +562,11 @@ async def test_tick_phase_histograms_count_ticks(backend):
     for _ in range(5):
         e.tick_once()
     hists = e.tick_histograms()
-    assert set(hists) == PER_TICK_HISTS | {"tick_late_ms", "tick_transfers",
-                                           "fence_resolve_ms"}
+    events = {"elections_started", "leader_stepdowns", "beat_rows"}
+    assert set(hists) == PER_TICK_HISTS | events | {
+        "tick_late_ms", "tick_transfers", "fence_resolve_ms"}
+    # one sample an event, not a tick: no node, no leader, none of them
+    assert all(hists[k]["count"] == 0 for k in events)
     assert all(hists[k]["count"] == 5 for k in PER_TICK_HISTS)
     # the loop's lateness counts the loop's own ticks, a fence's wait
     # counts fences: tick_once by hand gives neither

@@ -533,3 +533,89 @@ async def test_flush_returns_the_fsync_interval_read_in_its_thread(
     finally:
         for s in stores:
             s.shutdown()
+
+
+def test_the_shared_handles_of_a_directory_resolve_its_path_once(
+        tmp_path, monkeypatch):
+    """Every group of a store opens the same directory string, and a
+    ``realpath`` is one ``lstat`` a path component (20,000 lstats of a
+    4,096-region store's boot): the registries of the shared log engine and
+    the shared meta journal resolve a string once while its handle is
+    live, still find the one handle through another spelling of the
+    directory, and resolve afresh after the handle is closed."""
+    import os
+
+    from tpuraft.storage import meta_multilog, multilog
+    from tpuraft.util import dirkeys
+
+    resolved = []
+    real = os.path.realpath
+    monkeypatch.setattr(dirkeys.os.path, "realpath",
+                        lambda p: resolved.append(p) or real(p))
+    d = str(tmp_path / "mlog")
+    other = os.path.join(str(tmp_path), ".", "mlog")
+    for get, release in ((multilog.get_engine, multilog._release_engine),
+                         (meta_multilog.get_journal,
+                          meta_multilog._release_journal)):
+        resolved.clear()
+        handles = [get(d) for _ in range(64)]
+        assert resolved == [d]
+        assert all(h is handles[0] for h in handles)
+        assert get(other) is handles[0]
+        assert resolved == [d, other]
+        for h in handles + [handles[0]]:
+            release(h)
+        resolved.clear()
+        again = get(d)                  # closed in between: resolved anew
+        assert resolved == [d] and again is not handles[0]
+        release(again)
+
+
+async def test_a_rounds_stall_token_ages_from_the_hand_off_to_the_fsyncs_end(
+        tmp_path):
+    """The gray-failure disk probe's stall token of a flush round: taken
+    when the round is handed to the executor, given back in the thread at
+    the fsync's end.  A round queued behind a blocked executor ages it (a
+    saturated executor IS a gray signal, and a hung fsync never returns
+    it); a loop that resumes the waiter late does not (ISSUE 29: held to
+    the resumption, a 0.06 ms disk read as stalled whenever the loop ran
+    half a second late)."""
+    import threading
+    import time
+    from concurrent.futures import ThreadPoolExecutor
+
+    from tpuraft.util.health import DiskLatencyProbe
+
+    store = mk_storage(tmp_path, "tok")
+    store.init()
+    loop = asyncio.get_running_loop()
+    pool = ThreadPoolExecutor(max_workers=1)
+    loop.set_default_executor(pool)
+    try:
+        gc = store.engine.group_commit
+        gc.health_probe = probe = DiskLatencyProbe()
+        gc._cost_ewma = 1.0              # the inline path is banned
+        gate = threading.Event()
+        pool.submit(gate.wait)           # the one thread is taken
+        flush = asyncio.ensure_future(store.append_entries_async(
+            mk_entries(1, 2, term=1), sync=True))
+        await asyncio.sleep(0.05)
+        assert not flush.done()
+        _, age_ms, samples = probe.snapshot()
+        assert age_ms >= 40 and samples == 0     # queued, never started
+        # the executor frees up; the fsync ends in its thread while this
+        # loop is busy elsewhere: no token is left to age with the loop
+        synced = threading.Event()
+        real_sync = store.engine.sync
+        store.engine.sync = lambda: (real_sync(), synced.set())
+        gate.set()
+        assert synced.wait(5)
+        time.sleep(0.05)                 # the loop, late for its waiter
+        assert not flush.done()
+        assert probe.snapshot()[1] == 0.0
+        await flush
+        _, age_ms, samples = probe.snapshot()
+        assert age_ms == 0.0 and samples == 1
+    finally:
+        store.shutdown()
+        pool.shutdown(wait=False)

@@ -1037,3 +1037,39 @@ async def test_read_after_leader_kill_sees_acked_write():
                 f"read_index={idx}: {applied}")
         finally:
             await c.stop_all()
+
+
+async def test_a_vote_round_that_times_out_probes_again_at_once():
+    """Reference NodeImpl#handleVoteTimeout: step down AND pre-vote.  A
+    candidate whose round found no quorum already waited one election
+    timeout; waiting a second one as a follower made every split vote
+    cost two (33 s at kv3x4096's 16 s density floor)."""
+    c = TestCluster(3, election_timeout_ms=60_000)  # no timer fires by itself
+    await c.start(c.peers[0])           # alone: no vote can be granted
+    node = c.nodes[c.peers[0]]
+    async with node._lock:
+        await node._elect_self()
+    assert node.state == State.CANDIDATE
+    term = node.current_term
+    probes = []
+    pre_vote = node._pre_vote
+
+    async def counted():
+        probes.append(node.state)
+        await pre_vote()
+
+    node._pre_vote = counted
+    await node._handle_vote_timeout()
+    # stepped down in the same term, and probed as a follower, once
+    assert probes == [State.FOLLOWER]
+    assert node.state == State.FOLLOWER and node.current_term == term
+    await node._handle_vote_timeout()   # not a candidate: nothing more
+    assert probes == [State.FOLLOWER]
+    # the two peers come up: the probe after the next round wins
+    await c.start(c.peers[1])
+    await c.start(c.peers[2])
+    async with node._lock:
+        await node._elect_self()
+    leader = await c.wait_leader()
+    assert leader.current_term > term
+    await c.stop_all()

@@ -479,3 +479,39 @@ async def test_install_snapshot_on_multilog_scheme(tmp_path):
         assert node.log_manager.first_log_index() > 1
     finally:
         await c.stop_all()
+
+
+def test_a_first_boots_snapshot_root_is_known_empty_without_a_listing(
+        tmp_path, monkeypatch):
+    """A store's first boot makes every replica's snapshot root itself, so
+    there is nothing to sweep and nothing to open: no ``listdir`` and no
+    ``stat`` of ``temp`` (0.1 to 0.2 ms each on the chip host, times 12,288
+    replicas).  A root that was there is swept and opened as before."""
+    import os
+
+    from tpuraft.rpc.messages import SnapshotMeta
+    from tpuraft.storage import snapshot as snapmod
+
+    root = str(tmp_path / "r1" / "snapshot")
+    st = snapmod.LocalSnapshotStorage(root)
+    listed = []
+    real_listdir = os.listdir
+    monkeypatch.setattr(snapmod.os, "listdir",
+                        lambda p: listed.append(p) or real_listdir(p))
+    st.init()
+    assert os.path.isdir(root)
+    assert st.open() is None
+    assert listed == []
+    # a commit ends that: the next open lists and finds it
+    w = st.create()
+    w.write_file("data", b"x")
+    st.commit(w, SnapshotMeta(last_included_index=7, last_included_term=1))
+    reader = st.open()
+    assert reader is not None and reader.meta.last_included_index == 7
+    assert listed
+    # a second boot finds the root there: sweeps (temp dropped), opens
+    os.makedirs(os.path.join(root, "temp"))
+    again = snapmod.LocalSnapshotStorage(root)
+    again.init()
+    assert not os.path.exists(os.path.join(root, "temp"))
+    assert again.open().meta.last_included_index == 7

@@ -672,6 +672,16 @@ def test_histogram_cached_sort_invalidation():
     assert h.snapshot()["max"] == 5
 
 
+def test_histogram_update_counts_n_events_with_one_sample():
+    from tpuraft.util.metrics import Histogram
+
+    h = Histogram(max_samples=4)
+    h.update(1, 8192)       # a beat round: 8,192 rows, one ring slot
+    h.update(3)
+    assert (h.count, h.total, h._samples) == (8193, 8195, [1, 3])
+    assert h.snapshot()["count"] == 8193
+
+
 def test_metric_registry_thread_safety():
     from tpuraft.util.metrics import MetricRegistry
 

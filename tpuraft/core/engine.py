@@ -52,7 +52,8 @@ from typing import Callable, Optional
 import numpy as np
 
 from tpuraft.conf import Configuration
-from tpuraft.entity import PeerId
+from tpuraft.entity import ElectionPriority, PeerId
+from tpuraft.errors import RaftError
 from tpuraft.options import TickOptions
 from tpuraft.util import clock as clockmod
 from tpuraft.util.trace import RECORDER as _RECORDER
@@ -71,6 +72,14 @@ _REBASE_LIMIT = 1 << 28
 _TIME_REBASE_MS = 1 << 30        # epoch-shift threshold (int32 headroom)
 # protocol-param defaults for slots no node has registered yet
 _DEF_ETO_MS, _DEF_HB_MS, _DEF_LEASE_MS = 1000, 100, 900
+# status code of a leader's step-down -> lane_stats()["leader_stepdowns"]
+_STEPDOWN_LANES = {
+    int(RaftError.ERAFTTIMEDOUT): "quorum",
+    int(RaftError.EHIGHERTERMREQUEST): "term",
+    int(RaftError.EHIGHERTERMRESPONSE): "term",
+    int(RaftError.ENEWLEADER): "term",
+    int(RaftError.ELEADERCONFLICT): "term",
+}
 
 
 class TpuBallotBox:
@@ -220,9 +229,7 @@ class EngineControl:
         # The (1 - rho) factor is the clock-drift safety margin (ISSUE
         # 18): the quorum granted us eto*ratio on THEIR clocks; ours may
         # run up to rho fast, so we only trust that fraction of it.
-        self._lease_ms = int(self._eto_ms
-                             * opts.raft_options.leader_lease_time_ratio
-                             * (1.0 - opts.raft_options.clock_drift_bound))
+        self._lease_ms = self._lease_ms_of(self._eto_ms)
         self._jitter_range = max(1, min(opts.raft_options.max_election_delay_ms,
                                         self._eto_ms))
         self._jitter = random.randrange(self._jitter_range)
@@ -241,12 +248,15 @@ class EngineControl:
             eto_ms=self._eto_ms,
             hb_ms=max(1, self._eto_ms
                       // opts.raft_options.election_heartbeat_factor),
-            lease_ms=int(self._eto_ms
-                         * opts.raft_options.leader_lease_time_ratio
-                         * (1.0 - opts.raft_options.clock_drift_bound)),
+            lease_ms=self._lease_ms,
             snapshot_ms=snap_ms)
         if eff != self._eto_ms:
             self._adopt_eto(eff)
+
+    def _lease_ms_of(self, eto_ms: int) -> int:
+        ro = self.node.options.raft_options
+        return int(eto_ms * ro.leader_lease_time_ratio
+                   * (1.0 - ro.clock_drift_bound))
 
     def _adopt_eto(self, eff_eto_ms: int) -> None:
         """The engine's density floor raised this group's effective
@@ -261,8 +271,7 @@ class EngineControl:
                      opts.election_timeout_ms, eff_eto_ms)
             opts.election_timeout_ms = eff_eto_ms
         self._eto_ms = eff_eto_ms
-        self._lease_ms = int(eff_eto_ms
-                             * opts.raft_options.leader_lease_time_ratio)
+        self._lease_ms = self._lease_ms_of(eff_eto_ms)
         self._jitter_range = max(1, min(
             opts.raft_options.max_election_delay_ms, eff_eto_ms))
         self._jitter = min(self._jitter, self._jitter_range - 1)
@@ -315,6 +324,8 @@ class EngineControl:
     def on_candidate(self) -> None:
         e = self.engine
         self._clear_quiesce_state()
+        e.tick_hists["elections_started"].update(
+            self.node.current_term + 1)
         e.role[self.slot] = ROLE_CANDIDATE
         self.push_election_deadline()   # vote-round timeout
         e.mark_dirty()
@@ -373,12 +384,25 @@ class EngineControl:
         e.granted[s, :] = False
         e.mark_dirty()
 
-    def on_step_down(self, was_candidate: bool, was_leader: bool) -> None:
+    def on_step_down(self, was_candidate: bool, was_leader: bool,
+                     status=None) -> None:
         self._clear_quiesce_state()
-        self.engine.granted[self.slot, :] = False
+        e = self.engine
+        e.granted[self.slot, :] = False
+        if was_leader:
+            code = status.code if status is not None else 0
+            e.tick_hists["leader_stepdowns"].update(code)
+            e.leader_stepdowns[_STEPDOWN_LANES.get(code, "other")] += 1
 
     def on_follower(self) -> None:
         self.start_follower()
+
+    def priority_rounds_accrue(self) -> bool:
+        """Does a stepdown round of this node do more than re-verify
+        the quorum (Node._maybe_priority_transfer's first gate)?"""
+        node = self.node
+        return (node.server_id.priority != ElectionPriority.DISABLED
+                and node.options.raft_options.priority_transfer_rounds > 0)
 
     # -- ack bookkeeping (replaces Node._peer_acks) --------------------------
 
@@ -892,7 +916,22 @@ class MultiRaftEngine:
             # per resolved device read fence, arm to the tick that
             # resolved it; per waiter, so only while tracing is on
             "fence_resolve_ms": Histogram(),
+            # NOT times but events, one sample each, so ``count`` over a
+            # window is the number (the benchmark's summary line keeps
+            # every histogram's count): real elections this engine's
+            # nodes started (term bumped; sample = the new term),
+            # leaders that stepped down (sample = the status code;
+            # lane_stats splits them by lane), beat rows handed to the
+            # hub or sent direct by _flush_heartbeats
+            "elections_started": Histogram(),
+            "leader_stepdowns": Histogram(),
+            "beat_rows": Histogram(),
         }
+        # leader step-downs by what fired: "quorum" = dead-quorum check
+        # (the device's step_down mask or the stepdown_due cadence, both
+        # through Node._check_dead_nodes), "term" = a higher term or a
+        # new leader seen, "other" = transfer, storage error, removal
+        self.leader_stepdowns = {"quorum": 0, "term": 0, "other": 0}
         self._hb_flush_s = 0.0
         # protocol params: [G] rows — each registered node's NodeOptions
         # timeouts apply to ITS groups only (mixed-timeout engines, e.g.
@@ -1051,6 +1090,22 @@ class MultiRaftEngine:
                      / (max(self.opts.beat_cpu_budget, 1e-3) * 1000.0))
         tick_term = self._tick_cost_ema_s * 1000.0 * factor * 50.0
         return int(max(beat_term, tick_term))
+
+    def settle_floor(self) -> int:
+        """Bring every controlled row to the floor of the density that
+        is registered NOW, and return it.  ``register_ctrl`` re-derives
+        the floor only at geometric counts and re-applies it only past
+        25 % growth, so a burst can end with its rows up to a step
+        behind (4,096 registrations stopped at the floor of 3,389);
+        whoever registers in bulk calls this once the burst is over
+        (``StoreEngine.start`` after its boot batches).  Never lowers a
+        floor in force."""
+        self._floor_cached_ms = self._density_floor_ms()
+        self._floor_next_n = int(self._n_ctrls * 1.25) + 1
+        if self._floor_cached_ms > self._floor_applied_ms:
+            self._floor_applied_ms = self._floor_cached_ms
+            self._reapply_floor()
+        return self._floor_applied_ms
 
     def _apply_floor_slot(self, s: int) -> None:
         floor = self._floor_applied_ms
@@ -1428,6 +1483,11 @@ class MultiRaftEngine:
             "quiescent": quiescent,
             "hibernation_fraction": round(quiescent / n, 4) if n else 0.0,
             "tick_cost_ema_ms": round(self._tick_cost_ema_s * 1e3, 3),
+            # the density floor in force, and how many controlled rows
+            # it raised above what their nodes asked for (0 = silent)
+            "eto_floor_ms": self._floor_applied_ms,
+            "eto_raised": int((self.eto_ms[hc] > self.req_eto_ms[hc]).sum()),
+            "leader_stepdowns": dict(self.leader_stepdowns),
             "witness_groups": self._n_witness_slots,
             "stepdown_ticks": self.stepdown_ticks,
             "tick_failures": self.tick_failures,
@@ -1975,20 +2035,33 @@ class MultiRaftEngine:
             if ctrl is not None:
                 ctrl.schedule("quorum_dead",
                               ctrl.node._on_engine_quorum_dead)
-        for s in np.nonzero(np.asarray(out.stepdown_due) & hc)[0]:
-            ctrl = self._ctrls[s]
-            if ctrl is None:
-                continue
+        sd_slots = np.nonzero(np.asarray(out.stepdown_due) & hc)[0]
+        if sd_slots.size:
             # re-arm the host mirror NOW (the handler runs async; a
             # same-deadline refire every tick would storm) on the
             # timer-mode cadence: eto/2, the reference stepDownTimer.
-            self.stepdown_deadline[s] = now + max(1, int(self.eto_ms[s]) // 2)
-            self.stepdown_ticks += 1
-            # _check_dead_nodes re-verifies the quorum under the node
-            # lock AND accrues priority_transfer_rounds — the exact
-            # handler timer-mode runs, so decay-elected engine leaders
-            # transfer back with zero node-side special casing
-            ctrl.schedule("stepdown_tick", ctrl.node._check_dead_nodes)
+            self.stepdown_deadline[sd_slots] = now + np.maximum(
+                1, self.eto_ms[sd_slots] // 2)
+            self.stepdown_ticks += int(sd_slots.size)
+            # the whole lane in one pass: this tick's q_ack reduction is
+            # a LOWER bound on each leader's quorum-ack time (acks only
+            # arrive), so a leader it puts inside its election timeout
+            # cannot fail _check_dead_nodes' re-verification — at
+            # density that is every leader every eto/2, and one task
+            # plus one node lock each is the cost this saves.  The node
+            # is asked only where the row is stale, or where the round
+            # itself matters: _check_dead_nodes also accrues
+            # priority_transfer_rounds — the exact handler timer-mode
+            # runs, so decay-elected engine leaders transfer back with
+            # zero node-side special casing
+            stale = (now - np.asarray(out.q_ack)[sd_slots]
+                     >= self.eto_ms[sd_slots])
+            for s, is_stale in zip(sd_slots, stale):
+                ctrl = self._ctrls[s]
+                if ctrl is not None and (
+                        is_stale or ctrl.priority_rounds_accrue()):
+                    ctrl.schedule("stepdown_tick",
+                                  ctrl.node._check_dead_nodes)
         for s in np.nonzero(np.asarray(out.fence_ok) & hc)[0]:
             self._resolve_fences(int(s))
         hb_slots = np.nonzero(np.asarray(out.hb_due) & hc)[0]
@@ -2020,13 +2093,6 @@ class MultiRaftEngine:
         (the send-matrix plane — O(endpoints) RPCs, not O(groups))."""
         by_hub: dict[int, tuple[object, list]] = {}
         direct: list = []
-        # phase-align each next beat to its group's hb_ms grid: groups
-        # sharing an interval then fall due on the SAME tick, so one
-        # pulse per interval carries every such group's beat (max hub
-        # batching — staggered per-group beats degrade to ~1 per RPC).
-        # Mirrors the device's deadline advance so masks don't refire.
-        hbs = self.hb_ms[slots]
-        self.hb_deadline[slots] = (now // hbs + 1) * hbs
         for s in slots:
             ctrl = self._ctrls[s]
             if ctrl is None:
@@ -2057,6 +2123,34 @@ class MultiRaftEngine:
                     by_hub.setdefault(id(hub), (hub, []))[1].append(r)
                 else:
                     direct.append(r)
+        rows = sum(len(reps) for _, reps in by_hub.values()) + len(direct)
+        if rows:
+            self.tick_hists["beat_rows"].update(1, rows)
+        # phase-align each next beat to its group's hb_ms grid: groups
+        # sharing an interval then fall due on the SAME tick, so one
+        # pulse per interval carries every such group's beat (max hub
+        # batching — staggered per-group beats degrade to ~1 per RPC).
+        # Mirrors the device's deadline advance so masks don't refire.
+        # A round is ONE beat RPC a peer store: the hub cuts a pulse into
+        # RPCs of max_fast_beats_per_rpc rows a destination and a led
+        # group sends each peer store one row, so the grid has as many
+        # evenly spaced phases as a destination would get RPCs, a slot
+        # on phase slot % k (k = 1: phase 0, the grid).  More rows on
+        # one tick batch no better and hold the loop k times as long:
+        # 4,096 leaders' beats on one tick kept one operation in five
+        # waiting 0.4 s behind them (PERF.md section 6, PR 29).  Each
+        # group still beats once an interval.  Beats sent direct fill no
+        # RPC: the grid.
+        per_rpc = min((hub.max_fast_beats_per_rpc
+                       for hub, _ in by_hub.values()), default=0)
+        k = 1
+        if per_rpc > 0:
+            led = int(np.count_nonzero(
+                (self.role == ROLE_LEADER) & self.has_ctrl))
+            k = max(1, -(-led // per_rpc))
+        hbs = self.hb_ms[slots]
+        phase = (slots % k) * hbs // k
+        self.hb_deadline[slots] = ((now - phase) // hbs + 1) * hbs + phase
         for hub, reps in by_hub.values():
             hub.pulse(reps)
         for r in direct:

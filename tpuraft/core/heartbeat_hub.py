@@ -110,7 +110,13 @@ class HeartbeatHub:
         # registration order never matters
         self._members: dict[int, "Replicator"] = {}
         self._task: Optional[asyncio.Task] = None
-        self._inflight: dict[str, asyncio.Task] = {}  # dst -> send task
+        # beat RPCs on the wire: key -> send task.  The key only names
+        # the task; WHO has a beat outstanding is counted on each
+        # replicator (_launch), so a pulse skips exactly the groups
+        # whose last beat is still unanswered — never a group that
+        # merely sits where another group's slow chunk sat last pulse
+        self._inflight: dict[str, asyncio.Task] = {}
+        self._rpc_seq = 0
         self._interval_s = 0.1
         # chunking bound: enough to collapse idle RPC load by an order of
         # magnitude, small enough that a contended group's slow ack only
@@ -123,6 +129,8 @@ class HeartbeatHub:
         self.beats_sent = 0     # individual group beats carried
         self.fast_beats_sent = 0
         self.fast_fallbacks = 0
+        # rows a pulse left out because their last beat was unanswered
+        self.beats_skipped = 0
         # -- load-adaptive cadence widening ---------------------------------
         # at density (1024 groups x 3 replicas) the hub builds ~2000 beat
         # rows/s of pure standing load; when a pulse carries many rows the
@@ -178,7 +186,8 @@ class HeartbeatHub:
         # snapshot() is what the soak stats line and benches read
         self.metrics = MetricRegistry()
         for name in ("rpcs_sent", "beats_sent", "fast_beats_sent",
-                     "fast_fallbacks", "groups_quiesced", "groups_woken",
+                     "fast_fallbacks", "beats_skipped",
+                     "groups_quiesced", "groups_woken",
                      "lease_rpcs_sent", "lease_acks", "lease_beats_seen",
                      "lease_expiries", "lease_suppressed", "widened_pulses"):
             self.metrics.gauge(f"hub.{name}",
@@ -234,6 +243,7 @@ class HeartbeatHub:
                 f"rpcs_sent={self.rpcs_sent} beats_sent={self.beats_sent} "
                 f"fast_beats_sent={self.fast_beats_sent} "
                 f"fast_fallbacks={self.fast_fallbacks} "
+                f"beats_skipped={self.beats_skipped} "
                 f"quiesced={self.groups_quiesced} woken={self.groups_woken} "
                 f"lease_rpcs={self.lease_rpcs_sent} "
                 f"lease_acks={self.lease_acks} "
@@ -252,6 +262,7 @@ class HeartbeatHub:
             "beats_sent": self.beats_sent,
             "fast_beats_sent": self.fast_beats_sent,
             "fast_fallbacks": self.fast_fallbacks,
+            "beats_skipped": self.beats_skipped,
             "groups_quiesced": self.groups_quiesced,
             "groups_woken": self.groups_woken,
             "lease_rpcs_sent": self.lease_rpcs_sent,
@@ -527,6 +538,12 @@ class HeartbeatHub:
             node = r._node
             if not node.is_leader() or not r._running:
                 continue
+            if r._beats_inflight:
+                # its previous beat is still on the wire (slow or dead
+                # endpoint, a follower behind its node lock): the ack or
+                # the RPC budget (eto/2) settles that one first
+                self.beats_skipped += 1
+                continue
             quiesce_ms = getattr(r, "_quiesce_lease_ms", 0)
             if quiesce_ms:
                 r._quiesce_lease_ms = 0
@@ -581,36 +598,48 @@ class HeartbeatHub:
         for dst, pairs in by_dst_fast.items():
             for ci in range(0, len(pairs), self.max_fast_beats_per_rpc):
                 chunk = pairs[ci:ci + self.max_fast_beats_per_rpc]
-                key = f"fast:{dst}#{ci // self.max_fast_beats_per_rpc}"
-                if key in self._inflight:
-                    continue
-                t = asyncio.ensure_future(self._beat_fast(dst, chunk))
-                self._inflight[key] = t
-                reps = [r for r, _ in chunk]
-                t.add_done_callback(
-                    lambda _t, k=key, rs=reps: self._reap(k, _t, rs))
+                self._launch(f"fast:{dst}", self._beat_fast(dst, chunk),
+                             [r for r, _ in chunk], fast=True)
         if classic:
             self._pulse_classic(classic)
 
-    def _reap(self, key: str, t: asyncio.Task,
-              fallback: Optional[list["Replicator"]] = None) -> None:
-        """Done-callback for beat tasks: always retrieve the exception
+    def _launch(self, name: str, coro, reps: list["Replicator"],
+                fast: bool = False) -> None:
+        """Fire-and-track one beat RPC: each replicator it carries
+        counts a beat outstanding until the task is done (the next
+        pulse skips those, and only those), and the done-callback
+        always runs — cancelled tasks included."""
+        self._rpc_seq += 1
+        key = f"{name}#{self._rpc_seq}"
+        for r in reps:
+            r._beats_inflight += 1
+        t = asyncio.ensure_future(coro)
+        self._inflight[key] = t
+        t.add_done_callback(
+            lambda _t: self._reap(key, _t, reps, fast))
+
+    def _reap(self, key: str, t: asyncio.Task, reps: list["Replicator"],
+              fast: bool = False) -> None:
+        """Done-callback for beat tasks: release the chunk's
+        replicators for the next pulse; always retrieve the exception
         (an unretrieved one is event-loop log spam AND a silently
         missed beat), and give fast-path chunks that died on an
         unexpected error their classic-beat fallback so a persistent
         non-RpcError (e.g. codec failure) can't starve those groups of
         heartbeats until their followers start elections."""
         self._inflight.pop(key, None)
+        for r in reps:
+            r._beats_inflight -= 1
         if t.cancelled():
             return
         exc = t.exception()
         if exc is None:
             return
         LOG.warning("heartbeat batch %s failed: %r", key, exc)
-        if fallback:
-            self._abort_quiesce(fallback)
-            self.fast_fallbacks += len(fallback)
-            self._pulse_classic([r for r in fallback if r._running])
+        if fast:
+            self._abort_quiesce(reps)
+            self.fast_fallbacks += len(reps)
+            self._pulse_classic([r for r in reps if r._running])
 
     def _dispatch_classic(
             self, by_dst: dict[str, list[tuple["Replicator", bytes]]]
@@ -620,18 +649,14 @@ class HeartbeatHub:
         # heartbeats to every other endpoint and trigger elections
         # everywhere), and batches are capped so one contended group's
         # slow ack only couples the fates of its own chunk, not every
-        # group on the endpoint pair.  A chunk whose previous RPC is
-        # still in flight is skipped this tick.
+        # group on the endpoint pair.  A replicator whose previous beat
+        # is still in flight was left out by _pulse; a fast beat's
+        # follow-up rides with its own beat still counted.
         for dst, pairs in by_dst.items():
             for ci in range(0, len(pairs), self.max_beats_per_rpc):
                 chunk = pairs[ci:ci + self.max_beats_per_rpc]
-                key = f"{dst}#{ci // self.max_beats_per_rpc}"
-                if key in self._inflight:
-                    continue
-                t = asyncio.ensure_future(self._beat_endpoint(dst, chunk))
-                self._inflight[key] = t
-                t.add_done_callback(
-                    lambda _t, k=key: self._reap(k, _t))
+                self._launch(dst, self._beat_endpoint(dst, chunk),
+                             [r for r, _ in chunk])
 
     @staticmethod
     def _abort_quiesce(reps: list["Replicator"]) -> None:
@@ -663,7 +688,7 @@ class HeartbeatHub:
                 # receiver predates the beat plane: classic beats only
                 self._fast_ok[dst] = False
                 self.fast_fallbacks += len(reps)
-                self.pulse(reps)
+                self._pulse_classic(reps)
             return  # else: silence — dead-node detection, as direct
         if self.health is not None:
             self.health.note_peer_rtt(dst, self.clock.monotonic() - t0)

@@ -147,7 +147,8 @@ class TimerControl:
         self._acks = {self._node.server_id: self._clock.monotonic()}
         self._stepdown_timer.start()
 
-    def on_step_down(self, was_candidate: bool, was_leader: bool) -> None:
+    def on_step_down(self, was_candidate: bool, was_leader: bool,
+                     status=None) -> None:
         if was_candidate:
             self._vote_timer.stop()
         if was_leader:
@@ -1040,6 +1041,13 @@ class Node:
                 self._ctrl.stop_vote_wait()
                 await self._step_down(self.current_term, Status.error(
                     RaftError.ERAFTTIMEDOUT, "vote timed out"))
+                # probe again at once (reference:
+                # NodeImpl#handleVoteTimeout steps down AND pre-votes):
+                # the vote round already waited one election timeout,
+                # and a second one as a follower made every split vote
+                # cost two — 33 s at the 16 s density floor
+                if self.conf_entry.contains(self.server_id):
+                    await self._pre_vote()
             else:
                 await self._elect_self()  # retry
 
@@ -1170,7 +1178,8 @@ class Node:
                         term=self.current_term, to_term=term,
                         reason=status.error_msg[:80])
         was_leader = self.state in (State.LEADER, State.TRANSFERRING)
-        self._ctrl.on_step_down(self.state == State.CANDIDATE, was_leader)
+        self._ctrl.on_step_down(self.state == State.CANDIDATE, was_leader,
+                                status)
         if was_leader:
             self.replicators.stop_all()
             self.ballot_box.clear_pending()

@@ -76,6 +76,9 @@ class Replicator:
         # the lease horizon; the next hub pulse sends ONE quiesce beat
         # to this peer and clears it (0 = no handshake pending)
         self._quiesce_lease_ms = 0
+        # beat RPCs of this replicator the HeartbeatHub has on the wire
+        # (hub._launch / _reap): a pulse leaves it out while one is
+        self._beats_inflight = 0
         # set while this replicator lingers for a REMOVED peer (it keeps
         # shipping until the peer has the conf entry removing it, or a
         # timeout) — cleared if the peer is re-added meanwhile

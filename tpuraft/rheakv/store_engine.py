@@ -15,6 +15,7 @@ dispatch per tick instead of per-group Python work (SURVEY.md §3.5
 from __future__ import annotations
 
 import asyncio
+import gc
 import logging
 import os
 import threading
@@ -58,6 +59,76 @@ def _dir_usage_bytes(root: str) -> int:
             except OSError:
                 pass
     return total
+
+
+# What a store's boot builds (every region's node, log manager,
+# replicators, state machine: about 120 tracked objects a replica) is
+# moved out of the cyclic collector's reach: gc.freeze after each boot
+# batch, and once more when every region knows a leader
+# (_freeze_when_elected).  A full collection traverses every tracked
+# object of the process, so its pause grows with the replicas hosted:
+# 0.6 s every 3 s at 12,288 replicas (PERF.md section 6, PR 29), during
+# boot and under load alike, for garbage counted in dozens.  What is
+# frozen is still freed by reference counting; only a cycle that dies
+# later (a retired region's graph) waits for the unfreeze at the last
+# store's shutdown.  The freeze also hides those objects from the
+# collector's own brake on full passes (one runs only once a quarter as
+# many objects were promoted as the last one kept), so with 1.5 M
+# frozen a full pass ran on every tenth middle collection, twice as
+# often as unfrozen; _brake_full_collections puts the quarter back,
+# counted over what is frozen.  The collector is the process's, so this
+# state is too: the stores between start() and shutdown() are counted,
+# and the last one out gives back what they all took.
+_gc_lock = threading.Lock()
+_gc_stores = 0                      # guarded-by: _gc_lock
+# the oldest generation's threshold before the first store of this
+# process raised it (None = untouched)
+_gc_oldest_threshold_before: Optional[int] = None   # guarded-by: _gc_lock
+
+
+def _brake_full_collections() -> None:
+    """As many middle collections between two full ones as a quarter of
+    the frozen objects takes to allocate (CPython's own rule for the
+    oldest generation, which cannot see them).  Young and middle
+    collections keep their thresholds; never lowers the oldest one."""
+    global _gc_oldest_threshold_before
+    with _gc_lock:
+        young, middle, oldest = gc.get_threshold()
+        if young <= 0:
+            return  # the collector is off
+        want = gc.get_freeze_count() // (4 * young * max(middle, 1))
+        if want > oldest:
+            if _gc_oldest_threshold_before is None:
+                _gc_oldest_threshold_before = oldest
+            gc.set_threshold(young, middle, want)
+
+
+def _release_full_collections() -> None:
+    global _gc_oldest_threshold_before
+    with _gc_lock:
+        if _gc_oldest_threshold_before is not None:
+            young, middle, _ = gc.get_threshold()
+            gc.set_threshold(young, middle, _gc_oldest_threshold_before)
+            _gc_oldest_threshold_before = None
+
+
+def _gc_store_up() -> None:
+    global _gc_stores
+    with _gc_lock:
+        _gc_stores += 1
+
+
+def _gc_store_down() -> None:
+    """A store's regions are shut down.  Their graphs are garbage now,
+    but the freeze and the brake are also the still-serving stores':
+    only the last store of the process unfreezes and releases."""
+    global _gc_stores
+    with _gc_lock:
+        _gc_stores -= 1
+        last = _gc_stores == 0
+    if last:
+        gc.unfreeze()
+        _release_full_collections()
 
 
 @dataclass
@@ -691,6 +762,8 @@ class StoreEngine:
         # apply backlog) and acted on by the health loop below
         self.health = None
         self._health_task: Optional[asyncio.Task] = None
+        self._gc_settle_task: Optional[asyncio.Task] = None
+        self._gc_counted = False      # between _gc_store_up and _down
         self._evac_round = 0                   # evaluation round counter
         self._evac_cooldown: dict[int, int] = {}  # region -> round gate
         self.evacuations = 0          # transfers triggered by SICK score
@@ -851,6 +924,9 @@ class StoreEngine:
         # store restart time.  Bounded batches keep the task herd small.
         BOOT_BATCH = 128
         regions = list(self.opts.initial_regions)
+        if not self._gc_counted:
+            self._gc_counted = True
+            _gc_store_up()
         for i in range(0, len(regions), BOOT_BATCH):
             # settle the WHOLE batch before failing: a bare gather would
             # abort on the first error while sibling boots keep running
@@ -861,7 +937,18 @@ class StoreEngine:
             for res in results:
                 if isinstance(res, BaseException):
                     raise res
+            gc.freeze()
+        _brake_full_collections()
+        floor_ms = 0
+        if self.multi_raft_engine is not None:
+            # the boot burst is over: every row to the floor of the
+            # density that actually registered (register_ctrl alone may
+            # leave them a step behind)
+            floor_ms = self.multi_raft_engine.settle_floor()
         self._started = True
+        self._gc_settle_task = asyncio.ensure_future(
+            self._freeze_when_elected(
+                max(self.opts.election_timeout_ms, floor_ms)))
         if self.pd_client is not None:
             self._heartbeat_task = asyncio.ensure_future(
                 self._heartbeat_loop())
@@ -871,8 +958,27 @@ class StoreEngine:
             self._health_task = asyncio.ensure_future(self._health_loop())
         if self.opts.metrics_port is not None:
             self._start_metrics_http()
-        LOG.info("store engine %s up with %d regions", self.server_id,
-                 len(self._regions))
+        LOG.info("store engine %s up with %d regions, election timeout "
+                 "floor %d ms", self.server_id, len(self._regions),
+                 floor_ms)
+
+    async def _freeze_when_elected(self, eto_ms: int) -> None:
+        """The boot's second half: once every region knows a leader,
+        freeze what the elections built (each leader's replicators and
+        their tasks, every node's configuration entries and election
+        state: a third as many tracked objects again as the boot's,
+        and as long-lived), or a full collection still traverses them
+        all, 0.3 s at a time at 12,288 replicas.  Gives up waiting
+        after two election timeouts: regions that cannot elect must not
+        keep the rest within the collector's reach."""
+        for _ in range(max(1, 2 * eto_ms // 1000)):     # one look a second
+            await asyncio.sleep(1.0)
+            nodes = [e.node for e in self._regions.values()]
+            if all(n is not None and not n.leader_id.is_empty()
+                   for n in nodes):
+                break
+        gc.freeze()
+        _brake_full_collections()
 
     def _wire_multilog_probe(self) -> None:
         """multilog scheme: the shared group commit times every fsync
@@ -907,6 +1013,9 @@ class StoreEngine:
         if self._health_task is not None:
             self._health_task.cancel()
             self._health_task = None
+        if self._gc_settle_task is not None:
+            self._gc_settle_task.cancel()
+            self._gc_settle_task = None
         if self.health is not None:
             from tpuraft.util import describer
 
@@ -929,6 +1038,9 @@ class StoreEngine:
         for engine in list(self._regions.values()):
             await engine.shutdown()
         self._regions.clear()
+        if self._gc_counted:
+            self._gc_counted = False
+            _gc_store_down()
         if self.multi_raft_engine is not None:
             await self.multi_raft_engine.shutdown()
         if self.apply_lane is not None:

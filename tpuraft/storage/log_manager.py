@@ -67,11 +67,14 @@ class LogManager:
         # node's store endpoint; "" for bare/legacy constructions)
         self._trace_proc = trace_proc or "log"
         # gray-failure signal: the store-level HealthTracker whose disk
-        # probe this flusher times every flush round into (append +
-        # fsync, executor queueing included — CPU saturation IS a gray
-        # signal).  The probe's begin/end also exposes the AGE of a
-        # still-in-flight flush, which is how a fully hung fsync is
-        # detected (it never completes a sample).
+        # probe this flusher times every append + fsync into, IN the
+        # thread that runs it.  The probe's begin/end also exposes the
+        # AGE of a still-in-flight flush, which is how a fully hung
+        # fsync is detected (it never completes a sample): from the
+        # hand-off to the executor (queueing included — CPU saturation
+        # IS a gray signal) to the end of the I/O in its thread, not to
+        # this task's resumption, so a late event loop is not scored as
+        # a disk stall (LoopLagProbe scores the loop).
         self._health = health
         self.conf_manager = conf_manager or ConfigurationManager()
         self._sync = sync
@@ -405,42 +408,49 @@ class LogManager:
                     # store's attribution exactly like it did the EMA)
                     tids = ([e.trace_id for e in entries if e.trace_id]
                             if _TRACE.enabled else [])
-                    tok = health.disk.begin() if health is not None else None
-                    try:
-                        if append_async is not None:
-                            # multilog: the group commit times its fsync
-                            # IN the executor thread and feeds the EMA
-                            # itself (StoreEngine wires the probe);
-                            # begin/end here covers only the stall age.
-                            # It hands back the round's own fsync
-                            # interval; f0..f1 is the awaited envelope.
-                            f0 = time.perf_counter()
-                            fsync = await append_async(entries, self._sync)
-                            f1 = woke = time.perf_counter()
-                        elif health is not None or tids:
-                            # time the append+fsync IN the executor
-                            # thread: end-to-end (awaited) duration
-                            # would fold in executor-queue wait, and a
-                            # co-hosted neighbor's slow disk must not
-                            # score THIS store's disk sick
-                            def _timed(entries=entries):
-                                t0 = time.perf_counter()
+                    if append_async is not None:
+                        # multilog: the group commit times its fsync
+                        # IN the executor thread, feeds the EMA and
+                        # holds the stall token itself (StoreEngine
+                        # wires the probe).  It hands back the round's
+                        # own fsync interval; f0..f1 is the awaited
+                        # envelope.
+                        f0 = time.perf_counter()
+                        fsync = await append_async(entries, self._sync)
+                        f1 = woke = time.perf_counter()
+                    elif health is not None or tids:
+                        # time the append+fsync IN the executor
+                        # thread: end-to-end (awaited) duration
+                        # would fold in executor-queue wait, and a
+                        # co-hosted neighbor's slow disk must not
+                        # score THIS store's disk sick
+                        tok = health.disk.begin() \
+                            if health is not None else None
+
+                        def _timed(entries=entries, tok=tok):
+                            t0 = time.perf_counter()
+                            try:
                                 self._storage.append_entries(entries,
                                                              self._sync)
-                                return t0, time.perf_counter()
+                            finally:
+                                if tok is not None:
+                                    health.disk.end(tok)
+                            return t0, time.perf_counter()
 
-                            f0, f1 = await loop.run_in_executor(None, _timed)
-                            woke = time.perf_counter()
-                            fsync = (f0, f1, True)
-                            if health is not None:
-                                health.disk.note(f1 - f0)
-                        else:
-                            await loop.run_in_executor(
-                                None, self._storage.append_entries, entries,
-                                self._sync)
-                    finally:
-                        if tok is not None:
-                            health.disk.end(tok)
+                        try:
+                            f0, f1 = await loop.run_in_executor(None,
+                                                                _timed)
+                        finally:
+                            if tok is not None:
+                                health.disk.end(tok)    # never started
+                        woke = time.perf_counter()
+                        fsync = (f0, f1, True)
+                        if health is not None:
+                            health.disk.note(f1 - f0)
+                    else:
+                        await loop.run_in_executor(
+                            None, self._storage.append_entries, entries,
+                            self._sync)
                     if tids:
                         # the awaited envelope, then its two parts: the
                         # fsync in the thread that ran it (the disk)

@@ -52,6 +52,7 @@ from tpuraft.storage.log_storage import (
     save_crc_watermark,
 )
 from tpuraft.storage.meta_storage import RaftMetaStorage
+from tpuraft.util.dirkeys import RealPathKeys
 
 _HDR = struct.Struct("<H")      # group / votedFor length prefixes
 _TERM = struct.Struct("<q")
@@ -301,11 +302,12 @@ class MetaJournal:
 
 _journals_lock = threading.Lock()
 _journals: dict[str, MetaJournal] = {}  # guarded-by: _journals_lock
+_journal_keys = RealPathKeys()  # guarded-by: _journals_lock
 
 
 def get_journal(dir_path: str) -> MetaJournal:
-    key = os.path.realpath(dir_path)
     with _journals_lock:
+        key = _journal_keys.key(dir_path)
         j = _journals.get(key)
         if j is None or j._f is None:
             j = MetaJournal(dir_path)
@@ -315,12 +317,13 @@ def get_journal(dir_path: str) -> MetaJournal:
 
 
 def _release_journal(j: MetaJournal) -> None:
-    key = os.path.realpath(j.dir)
     with _journals_lock:
         j._refs -= 1
         if j._refs > 0:
             return
+        key = _journal_keys.key(j.dir)
         _journals.pop(key, None)
+        _journal_keys.forget(key)
         # close INSIDE the registry lock: a concurrent get_journal on
         # the same directory must not reopen (and possibly truncate a
         # torn tail + lower the watermark) while this handle is still

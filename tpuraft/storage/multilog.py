@@ -31,6 +31,7 @@ from typing import Optional
 
 from tpuraft.entity import LogEntry
 from tpuraft.storage.log_storage import CorruptLogError, LogStorage
+from tpuraft.util.dirkeys import RealPathKeys
 from tpuraft.util.trace import TRACER as _TRACE
 
 _FRAME = struct.Struct("<I")
@@ -207,10 +208,17 @@ class _GroupCommit:
             return t0, t1, False
         return await fut
 
-    def _timed_sync(self) -> tuple:
-        """engine.sync() + its pure in-thread interval."""
+    def _timed_sync(self, probe=None, tok=None) -> tuple:
+        """engine.sync() + its pure in-thread interval.  ``tok`` is the
+        health probe's stall token of this round (taken by ``_run`` when
+        it handed the round to the executor): it is given back HERE, in
+        the thread that does the I/O, the moment the fsync ends."""
         t0 = time.perf_counter()
-        self._engine.sync()
+        try:
+            self._engine.sync()
+        finally:
+            if tok is not None:
+                probe.end(tok)
         return t0, time.perf_counter(), True
 
     def _revive(self) -> None:
@@ -233,12 +241,22 @@ class _GroupCommit:
                 batch, self._waiters = self._waiters, []
             exc: Optional[BaseException] = None
             interval: Optional[tuple] = None
+            # the health probe's stall token spans the hand-off to the
+            # executor, the wait for a free thread (a saturated executor
+            # IS a gray signal) and the fsync, and ends in the thread
+            # with the fsync: a hung or never-started fsync ages it.  It
+            # does not span this task's resumption: held until then, a
+            # store whose fsyncs take 0.06 ms read as a stalled disk
+            # whenever its loop ran half a second late, and the loop's
+            # lateness has a probe of its own (LoopLagProbe).
+            probe = self.health_probe
+            tok = probe.begin() if probe is not None else None
             try:
                 # time the fsync IN the executor thread: timing around
                 # the await would fold in the loop round-trip (~2ms) and
                 # permanently ban the inline path on any busy process
-                interval = await loop.run_in_executor(None,
-                                                      self._timed_sync)
+                interval = await loop.run_in_executor(
+                    None, self._timed_sync, probe, tok)
                 dur = interval[1] - interval[0]
                 with self._lock:
                     self._last_sync = time.monotonic()
@@ -246,7 +264,6 @@ class _GroupCommit:
                     # path too: this is how a banned fast path recovers
                     # (re-probing inline would block the loop)
                     self._cost_ewma = 0.7 * self._cost_ewma + 0.3 * dur
-                probe = self.health_probe
                 if probe is not None:
                     probe.note(dur)
             except asyncio.CancelledError:
@@ -269,6 +286,9 @@ class _GroupCommit:
                 raise
             except Exception as e:  # noqa: BLE001 — fail THIS round only
                 exc = e
+            finally:
+                if tok is not None:
+                    probe.end(tok)  # a round that never reached a thread
             for f in batch:
                 if f.get_loop() is loop:
                     _deliver(f, exc, interval)
@@ -352,18 +372,20 @@ _engines_lock = threading.Lock()
 _engines: dict[str, MultiLogEngine] = {}  # guarded-by: _engines_lock
 
 
+_engine_keys = RealPathKeys()  # guarded-by: _engines_lock
+
+
 def peek_engine(dir_path: str) -> Optional[MultiLogEngine]:
     """The live engine for a directory WITHOUT taking a reference —
     observability wiring (the StoreEngine attaching its health probe),
     never ownership."""
-    key = os.path.realpath(dir_path)
     with _engines_lock:
-        return _engines.get(key)
+        return _engines.get(_engine_keys.key(dir_path))
 
 
 def get_engine(dir_path: str, segment_max_bytes: int = 0) -> MultiLogEngine:
-    key = os.path.realpath(dir_path)
     with _engines_lock:
+        key = _engine_keys.key(dir_path)
         eng = _engines.get(key)
         if eng is None or eng._h is None:
             eng = MultiLogEngine(dir_path, segment_max_bytes)
@@ -373,12 +395,13 @@ def get_engine(dir_path: str, segment_max_bytes: int = 0) -> MultiLogEngine:
 
 
 def _release_engine(eng: MultiLogEngine) -> None:
-    key = os.path.realpath(eng.dir)
     with _engines_lock:
         eng._refs -= 1
         if eng._refs > 0:
             return
+        key = _engine_keys.key(eng.dir)
         _engines.pop(key, None)
+        _engine_keys.forget(key)
     # close() serializes against any in-flight fsync via the engine's
     # sync lock (blocks the few ms it needs), so closing here is safe
     # even while a round's executor job is still running; that round's

@@ -46,10 +46,12 @@ class Histogram:
         self.count = 0
         self.total = 0.0
 
-    def update(self, value: float) -> None:
+    def update(self, value: float, n: int = 1) -> None:
+        """One sample; ``n`` > 1 counts ``n`` events of ``value`` each at
+        the cost of one (the ring still takes a single sample)."""
         with self._lock:
-            self.count += 1
-            self.total += value
+            self.count += n
+            self.total += value * n
             if len(self._samples) >= self._max:
                 self._samples[self._next] = value
                 self._next = (self._next + 1) % self._max
