@@ -23,7 +23,7 @@ Pieces:
   checked linearizable (``tpuraft.util.linearizability``).
 
 Tests wrap this through ``tests/proc_cluster.py`` (ephemeral ports +
-pytest teardown); benches through ``examples/rheakv_bench_multiproc``.
+pytest teardown).
 """
 
 from __future__ import annotations
@@ -205,8 +205,7 @@ class StoreProcess:
 def server_argv(endpoint: str, stores: list[str], regions: int, data: str,
                 transport: str = "tcp", store: str = "memory",
                 log_scheme: str = "file", pd: str = "",
-                eto_ms: int = 1000, apply_lane: bool = False,
-                engine: str = "",
+                eto_ms: int = 1000, engine: str = "",
                 drain_timeout_s: float = 10.0, boot_delay_s: float = 0.0,
                 metrics_port: Optional[int] = 0) -> list[str]:
     """Command line for one ``examples.rheakv_server`` child.
@@ -222,8 +221,6 @@ def server_argv(endpoint: str, stores: list[str], regions: int, data: str,
             "--drain-timeout", str(drain_timeout_s)]
     if pd:
         argv += ["--pd", pd]
-    if apply_lane:
-        argv += ["--apply-lane"]
     if engine:
         argv += ["--engine", engine]
     if boot_delay_s:
@@ -396,8 +393,8 @@ class ProcSupervisor:
 # ---------------------------------------------------------------------------
 
 async def _soak(seconds: float, stores_n: int, regions: int, data: str,
-                transport: str, apply_lane: bool,
-                engine: bool = False, chip_store: int = -1) -> int:
+                transport: str, engine: bool = False,
+                chip_store: int = -1) -> int:
     from examples.rheakv_server import client_for
     from tpuraft.util.linearizability import History, check_history
 
@@ -415,7 +412,7 @@ async def _soak(seconds: float, stores_n: int, regions: int, data: str,
     sup = ProcSupervisor([
         StoreProcess(ep, server_argv(
             ep, endpoints, regions, data, transport=transport,
-            eto_ms=500, apply_lane=apply_lane, engine=backend,
+            eto_ms=500, engine=backend,
             metrics_port=None))
         for ep, backend in zip(endpoints, backends)])
     await sup.start()
@@ -505,7 +502,6 @@ def main() -> None:
     ap.add_argument("--data", default="/tmp/tpuraft-proc-soak")
     ap.add_argument("--transport", choices=["tcp", "native"],
                     default="tcp")
-    ap.add_argument("--apply-lane", action="store_true")
     ap.add_argument("--engine", action="store_true",
                     help="children drive their region nodes from ONE "
                          "MultiRaftEngine each (fused [G] tick) instead "
@@ -522,8 +518,7 @@ def main() -> None:
     import shutil
     shutil.rmtree(args.data, ignore_errors=True)
     rc = asyncio.run(_soak(args.seconds, args.stores, args.regions,
-                           args.data, args.transport, args.apply_lane,
-                           engine=args.engine,
+                           args.data, args.transport, engine=args.engine,
                            chip_store=args.chip_store))
     sys.exit(rc)
 

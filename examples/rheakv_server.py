@@ -50,7 +50,6 @@ async def serve(endpoint: str, stores: list[str], n_regions: int,
                 log_scheme: str = "file",
                 metrics_port: int | None = None,
                 eto_ms: int = 1000,
-                apply_lane: bool = False,
                 engine: str = "",
                 drain_timeout_s: float = 10.0,
                 boot_delay_s: float = 0.0) -> None:
@@ -76,7 +75,6 @@ async def serve(endpoint: str, stores: list[str], n_regions: int,
         election_timeout_ms=eto_ms,
         log_scheme=log_scheme,
         metrics_port=metrics_port,
-        apply_lane=apply_lane,
     )
     if store_kind == "native":
         from tpuraft.rheakv.native_store import NativeRawKVStore
@@ -168,10 +166,6 @@ def main() -> None:
                          "over the admin transport")
     ap.add_argument("--eto-ms", type=int, default=1000,
                     help="election timeout (ms)")
-    ap.add_argument("--apply-lane", action="store_true",
-                    help="run FSM applies + fenced reads on a dedicated "
-                         "worker lane thread (one hot store saturates "
-                         ">1 core)")
     ap.add_argument("--engine", choices=["numpy", "jax"], default="",
                     help="drive all region nodes from ONE MultiRaftEngine "
                          "(fused [G] tick) instead of per-node timers, "
@@ -197,7 +191,6 @@ def main() -> None:
                           log_scheme=args.log_scheme,
                           metrics_port=args.metrics_port,
                           eto_ms=args.eto_ms,
-                          apply_lane=args.apply_lane,
                           engine=args.engine,
                           drain_timeout_s=args.drain_timeout,
                           boot_delay_s=args.boot_delay))

@@ -32,7 +32,7 @@ from tpuraft.rheakv.client import RheaKVStore
 class ProcCluster:
     def __init__(self, tmp_path, stores: int = 3, regions: int = 2,
                  transport: str = "tcp", store_kind: str = "memory",
-                 eto_ms: int = 500, apply_lane: bool = False,
+                 eto_ms: int = 500,
                  drain_timeout_s: float = 10.0,
                  boot_delay_s: dict[int, float] | None = None,
                  metrics: bool = False):
@@ -45,7 +45,7 @@ class ProcCluster:
             StoreProcess(ep, server_argv(
                 ep, self.endpoints, regions, str(tmp_path),
                 transport=transport, store=store_kind, eto_ms=eto_ms,
-                apply_lane=apply_lane, drain_timeout_s=drain_timeout_s,
+                drain_timeout_s=drain_timeout_s,
                 boot_delay_s=delays.get(i, 0.0),
                 metrics_port=0 if metrics else None))
             for i, ep in enumerate(self.endpoints)])

@@ -213,23 +213,83 @@ async def test_failed_round_falls_back_to_one_write_a_region(spy):
 # -- (d) what rides a round and what does not --------------------------------
 
 
-async def test_run_then_compare_put_keeps_log_order_and_results(spy):
+def _record(spy, method: str) -> list:
+    """Record, in the order of the store's calls, each call of
+    ``method``: ``(method, apply_write_batch calls before it)``."""
+    inner, seen = getattr(spy.inner, method), []
+
+    def call(*args):
+        seen.append((method, len(spy.calls)))
+        return inner(*args)
+
+    setattr(spy, method, call)
+    return seen
+
+
+# the non-run op that follows the run on one key: (op, store method, the
+# result its closure carries, the key's value afterwards)
+_AFTER_RUN = {
+    "compare_put": (lambda k: KVOperation.cas(k, b"one", b"two"),
+                    "compare_and_put", True, b"two"),
+    "put_if_absent": (lambda k: KVOperation(KVOp.PUT_IF_ABSENT, k, b"two"),
+                      "put_if_absent", b"one", b"one"),
+    "get_and_put": (lambda k: KVOperation(KVOp.GET_AND_PUT, k, b"two"),
+                    "get_and_put", b"one", b"two"),
+    "delete_range": (lambda k: KVOperation.delete_range(k, k + b"\xff"),
+                     "delete_range", True, None),
+    "merge": (lambda k: KVOperation(KVOp.MERGE, k, b"two"),
+              "merge", True, b"one,two"),
+}
+
+
+@pytest.mark.parametrize("after", sorted(_AFTER_RUN))
+async def test_run_then_compare_put_keeps_log_order_and_results(spy, after):
+    make_op, method, result, value = _AFTER_RUN[after]
+    own_calls = _record(spy, method)
     _round, (g, other) = await _groups(spy, 2, lambda rid: [
         KVOperation(KVOp.PUT, b"r%03d-k" % rid, b"one"),
-        KVOperation.cas(b"r%03d-k" % rid, b"one", b"two"),
+        make_op(b"r%03d-k" % rid),
         KVOperation(KVOp.PUT, b"r%03d-j" % rid, b"three"),
     ] if rid == 1 else [_put(rid, 0)])
     g.commit()
     other.commit()
     (s1, r1), (s2, r2), (s3, r3) = await g.results()
     assert s1.is_ok() and s2.is_ok() and s3.is_ok()
-    # the CAS saw the PUT ahead of it: the run was written first
-    assert (r1, r2, r3) == (True, True, True)
-    assert spy.get(b"r001-k") == b"two" and spy.get(b"r001-j") == b"three"
+    # the op saw the PUT ahead of it: the run was written first
+    assert (r1, r2, r3) == (True, result, True)
+    assert spy.get(b"r001-k") == value and spy.get(b"r001-j") == b"three"
     await other.results()
-    # round 1: both regions' leading runs; round 2: the PUT after the CAS
+    # round 1: both regions' leading runs; round 2: the PUT after the op,
+    # which paid a store call of its own between the two
     assert [sorted(k for k, _ in c) for c in spy.calls] == [
         [b"r001-k", b"r002-k0"], [b"r001-j"]]
+    assert own_calls == [(method, 1)]
+
+
+async def test_mixed_batch_through_on_apply_rides_rounds_in_log_order(spy):
+    """One ``on_apply`` over a put, an all-put MULTI, a CAS on the first
+    put's value and a put: a round of three rows, the CAS's own call
+    (which sees the staged put), a round of one row."""
+    cas_calls = _record(spy, "compare_and_put")
+    apply_round = ApplyRound(spy)
+    fsm = KVStoreStateMachine(Region(id=1, start_key=b"", end_key=b""), spy,
+                              apply_round=apply_round)
+    ops = [_put(1, 0), KVOperation.multi([_put(1, 1), _put(1, 2)]),
+           KVOperation.cas(b"r001-k0", b"v1-0", b"cas"), _put(1, 3)]
+    loop = asyncio.get_running_loop()
+    futs = [loop.create_future() for _ in ops]
+    it = Iterator([LogEntry(type=EntryType.DATA, id=LogId(i + 1, 1),
+                            data=op.encode()) for i, op in enumerate(ops)],
+                  [KVClosure(f) for f in futs])
+    await fsm.on_apply(it)
+    assert [f.result()[0].is_ok() for f in futs] == [True] * 4
+    assert [f.result()[1] for f in futs] == \
+        [True, [(0, "", True)] * 2, True, True]
+    assert [len(c) for c in spy.calls] == [3, 1]
+    assert cas_calls == [("compare_and_put", 1)]
+    assert apply_round.syncs.count == 2
+    assert apply_round.sync_entries.count == 3
+    assert spy.get(b"r001-k0") == b"cas" and spy.get(b"r001-k3") == b"v1-3"
 
 
 async def test_all_put_multi_rides_the_round_with_per_op_outcomes(spy):
@@ -283,39 +343,6 @@ async def test_sealed_region_does_not_ride_the_round(spy):
     assert st.code == RaftError.ESTATEMACHINE and result is None
     assert apply_round.syncs.count == 0 and spy.calls == []
     assert spy.get(b"r001-k0") is None
-
-
-async def test_coalesce_off_keeps_one_store_call_per_op(spy):
-    apply_round = ApplyRound(spy)
-    fsm = KVStoreStateMachine(Region(id=1, start_key=b"", end_key=b""), spy,
-                              coalesce_applies=False, apply_round=apply_round)
-    ops = [_put(1, i) for i in range(3)]
-    it = Iterator([LogEntry(type=EntryType.DATA, id=LogId(i + 1, 1),
-                            data=op.encode()) for i, op in enumerate(ops)],
-                  [None] * 3)
-    await fsm.on_apply(it)
-    assert spy.calls == [] and apply_round.syncs.count == 0
-    assert spy.get(b"r001-k2") == b"v1-2"
-
-
-async def test_lane_path_shares_the_body_and_writes_per_run(spy):
-    """``apply_sync`` (the apply lane's entry) runs the same body with a
-    store call of its own per run and never touches the round."""
-    apply_round = ApplyRound(spy)
-    fsm = KVStoreStateMachine(Region(id=1, start_key=b"", end_key=b""), spy,
-                              apply_round=apply_round)
-    ops = [_put(1, 0), KVOperation.multi([_put(1, 1), _put(1, 2)]),
-           KVOperation.cas(b"r001-k0", b"v1-0", b"cas"), _put(1, 3)]
-    loop = asyncio.get_running_loop()
-    futs = [loop.create_future() for _ in ops]
-    it = Iterator([LogEntry(type=EntryType.DATA, id=LogId(i + 1, 1),
-                            data=op.encode()) for i, op in enumerate(ops)],
-                  [KVClosure(f) for f in futs])
-    assert fsm.apply_sync(it) == 4
-    assert [f.result()[1] for f in futs] == \
-        [True, [(0, "", True)] * 2, True, True]
-    assert [len(c) for c in spy.calls] == [3, 1]
-    assert apply_round.syncs.count == 0
 
 
 async def test_flush_at_shutdown_writes_an_open_round(spy):

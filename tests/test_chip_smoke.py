@@ -121,7 +121,7 @@ def test_chip_smoke_phases_at_tiny_size():
     interpret mode, the mesh and replica phases over four of the eight
     virtual devices."""
     import chip_smoke
-    from bench_multichip import drive_lanes
+    from chip_smoke import drive_lanes
 
     k = chip_smoke.phase_kernels(0, shapes=((256, 4), (200, 8)),
                                  interpret=True)

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import asyncio
 import json
+import time
 
 import numpy as np
 import pytest
@@ -652,8 +653,10 @@ async def test_tick_sections_nest_under_the_one_tracer(backend):
     e = await _engine(backend)
     TRACER.configure(enabled=True)
     try:
+        w0 = time.perf_counter()
         for _ in range(4):
             e.tick_once()
+        wall = time.perf_counter() - w0
         table = TRACER.section_table()
     finally:
         TRACER.configure(enabled=False)
@@ -661,10 +664,13 @@ async def test_tick_sections_nest_under_the_one_tracer(backend):
     assert set(table) == {"tick.build", "tick.call", "tick.fetch",
                           "tick.apply"}
     assert all(calls == 4 for calls, _b, _s in table.values())
-    # the sections tile the tick: their seconds make up tick_total_ms
+    # the sections tile the tick: they open at the clock read
+    # tick_total_ms starts from and close after the one it ends at, one
+    # at a time, so their seconds lie between that total and the wall
+    # time of the four calls (no tolerance: a preempted test thread
+    # stretches all three alike)
     tiled = sum(busy for _c, busy, _s in table.values())
-    assert tiled == pytest.approx(e.tick_hists["tick_total_ms"].total / 1e3,
-                                  rel=0.05)
+    assert e.tick_hists["tick_total_ms"].total / 1e3 - 1e-9 <= tiled <= wall
     # tick.build holds the GroupState build, which tick_build_ms leaves
     # to the device phase
     assert table["tick.build"][1] >= \
@@ -675,8 +681,6 @@ async def test_tick_sections_nest_under_the_one_tracer(backend):
 async def test_tick_late_ms_counts_both_wake_kinds(wake):
     """A wake is late by what the loop took past the time it was due:
     the timeout of a timed wait, the ``mark_dirty`` of a dirty one."""
-    import time
-
     e = _numpy_engine()
     loop = asyncio.get_running_loop()
 

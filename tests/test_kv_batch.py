@@ -10,6 +10,7 @@ amortization with per-op results, and the apply coalescer's semantics.
 
 import asyncio
 import contextlib
+import threading
 
 from tests.kv_cluster import KVTestCluster
 from tpuraft.entity import LogEntry, LogId
@@ -279,18 +280,13 @@ class _BatchSpyStore(MemoryRawKVStore):
     def __init__(self):
         super().__init__()
         self.batch_calls: list[int] = []
-        self.single_calls = 0
 
     def apply_write_batch(self, ops):
         self.batch_calls.append(len(ops))
         super().apply_write_batch(ops)
 
-    def put(self, key, value):
-        self.single_calls += 1
-        super().put(key, value)
 
-
-async def test_fsm_coalesces_consecutive_put_delete_runs():
+async def test_fsm_merges_consecutive_put_delete_runs():
     store = _BatchSpyStore()
     region = Region(id=1, start_key=b"", end_key=b"")
     fsm = KVStoreStateMachine(region, store)
@@ -323,19 +319,6 @@ async def test_fsm_coalesces_consecutive_put_delete_runs():
     # every closure that rode the run reports True
     assert futs[0].result()[1] is True
     assert futs[3].result()[1] is True
-
-
-async def test_fsm_coalescing_off_preserves_per_op_calls():
-    store = _BatchSpyStore()
-    region = Region(id=1, start_key=b"", end_key=b"")
-    fsm = KVStoreStateMachine(region, store, coalesce_applies=False)
-    ops = [KVOperation(KVOp.PUT, b"x%d" % i, b"v") for i in range(4)]
-    it = Iterator([_entry(op, i + 1) for i, op in enumerate(ops)],
-                  [None] * 4)
-    await fsm.on_apply(it)
-    assert store.batch_calls == []
-    assert store.single_calls == 4
-    assert fsm.coalesced_flushes == 0
 
 
 async def test_fsm_multi_entry_per_op_outcomes_and_inner_coalescing():
@@ -429,3 +412,59 @@ async def test_batching_client_history_stays_linearizable():
         assert kv.batch_rpcs > 0   # the load actually rode the batch path
         rep = check_history(h)
         assert rep.ok, str(rep)
+
+
+# -- the loop thread is the only owner of the raw store -----------------------
+
+
+class _ThreadSpyStore:
+    """A raw store that notes, for every call into it, the method and
+    the thread that made the call."""
+
+    def __init__(self, inner, calls: list):
+        self._inner = inner
+        self._calls = calls
+
+    def __getattr__(self, name):
+        attr = getattr(self._inner, name)
+        if not callable(attr):
+            return attr
+
+        def call(*args, **kw):
+            self._calls.append((name, threading.get_ident()))
+            return attr(*args, **kw)
+
+        return call
+
+
+async def test_every_raw_store_call_is_made_on_the_loop_thread(tmp_path):
+    """The ownership rule of ``RawKVStore``: writes, fenced reads, a
+    scan, a CAS, a snapshot save and a split's probes all reach the
+    store from the store engine's loop thread."""
+    calls: list = []
+    async with batch_cluster(
+            tmp_path=tmp_path,
+            raw_store_factory=lambda _ep: _ThreadSpyStore(
+                MemoryRawKVStore(), calls)) as (c, kv, _rpcs):
+        leader = await c.wait_region_leader(1)
+        assert all(await asyncio.gather(
+            *[kv.put(b"key%02d" % i, b"v%d" % i) for i in range(32)]))
+        assert await asyncio.gather(
+            *[kv.get(b"key%02d" % i) for i in range(32)]) == \
+            [b"v%d" % i for i in range(32)]
+        assert len(await kv.scan(b"key00", b"key10")) == 10
+        assert await kv.compare_and_put(b"key00", b"v0", b"cas")
+        st = await leader.node.snapshot()
+        assert st.is_ok(), str(st)
+        st = await leader.store_engine.apply_split(1, 2)
+        assert st.is_ok(), str(st)
+        await c.wait_region_on_all(2)
+        await c.wait_region_leader(2)
+        assert await kv.put(b"key31", b"after")
+        assert await kv.get(b"key31") == b"after"
+    assert {"apply_write_batch", "multi_get", "scan", "compare_and_put",
+            "serialize_range", "approximate_keys_in_range",
+            "jump_over"} <= {name for name, _tid in calls}
+    off_loop = sorted({name for name, tid in calls
+                       if tid != threading.get_ident()})
+    assert off_loop == [], f"raw-store calls off the loop thread: {off_loop}"

@@ -437,7 +437,7 @@ def test_witness_clamp_enumeration_matches_host_and_quorum_math():
             data_best = max((md[p] for p in conf.data_peers()), default=0)
             assert clamped[g] <= data_best
 
-    # deterministic binding case (the bench_multichip clamp probe in
+    # deterministic binding case (chip_smoke.drive_lanes' clamp probe in
     # miniature): 1 data voter at 3, 2 witnesses at 9 -> the unclamped
     # order statistic says 9, the clamp must pin commit to 3
     probe_match = jnp.asarray([[3, 9, 9]], jnp.int32)

@@ -9,15 +9,32 @@ import numpy as np
 import pytest
 
 from tpuraft.entity import Task
+from tpuraft.errors import RaftError
 from tpuraft.parallel.replica_cluster import ReplicaPlaneCluster
 from tpuraft.parallel.replica_plane import ReplicatedClusterPlane
 
 
-async def _apply_ok(node, data, t=10.0):
+async def _apply(node, data, t=10.0):
     fut = asyncio.get_running_loop().create_future()
     await node.apply(Task(data=data, done=fut.set_result))
-    st = await asyncio.wait_for(fut, t)
+    return await asyncio.wait_for(fut, t)
+
+
+async def _apply_ok(node, data, t=10.0):
+    st = await _apply(node, data, t)
     assert st.is_ok(), st
+
+
+async def _apply_through_leader(c, gid, data, t=10.0):
+    """Commit ``data`` in ``gid`` through whoever leads it NOW.  A
+    propose-time rejection (EPERM: not leader, nothing was appended) is
+    offered again to the group's next leader, as a client's route
+    refresh does; any other failure is the test's."""
+    while True:
+        st = await _apply(await c.wait_leader(gid), data, t)
+        if st.raft_error != RaftError.EPERM:
+            assert st.is_ok(), st
+            return
 
 
 def _mesh_2d():
@@ -35,13 +52,19 @@ async def test_multi_step_commits_through_collectives():
     entries through collective.py — 4 replicas x 8 groups, 3 waves of
     writes, every commit decided by the replica-axis all_gather."""
     mesh = _mesh_2d()
-    c = ReplicaPlaneCluster(4, 8, mesh=mesh)
+    # every plane tick blocks the loop thread in a rendezvous of eight
+    # CPU device threads; beside five other busy workers that can hold
+    # the loop past the default 400 ms election timeout, and leaders
+    # then move (the protocol at work, not this test's subject).  So:
+    # a timeout such stalls do not cross, and every write goes through
+    # the group's leader of that moment
+    c = ReplicaPlaneCluster(4, 8, mesh=mesh, election_timeout_ms=2000)
     await c.start_all()
     try:
-        leaders = {g: await c.wait_leader(g) for g in c.groups}
         for wave in range(3):
             await asyncio.gather(*(
-                _apply_ok(leaders[g], b"%s-w%d-%d" % (g.encode(), wave, i))
+                _apply_through_leader(
+                    c, g, b"%s-w%d-%d" % (g.encode(), wave, i))
                 for g in c.groups for i in range(5)))
         # the plane's collective tick drove the commits over many steps
         assert c.plane.ticks >= 3

@@ -3,6 +3,7 @@ LocalSnapshotStorageTest, NodeTest snapshot+install cases — SURVEY.md §5).
 """
 
 import asyncio
+import time
 
 import pytest
 
@@ -339,7 +340,6 @@ async def test_install_under_write_load(tmp_path):
     intervals of writes, then recovers by install DURING sustained
     load — converging to identical logs with every acked entry exactly
     once."""
-    import time
     from collections import Counter
 
     c = TestCluster(3, tmp_path=tmp_path, snapshot=True,
@@ -440,6 +440,21 @@ async def test_add_peer_behind_compacted_log_installs_snapshot(tmp_path):
     await c.stop_all()
 
 
+async def _wait_install_received(node, timeout_s: float = 10.0) -> None:
+    """Until ``node`` has finished a remote InstallSnapshot.  One install
+    loads the FSM first and resets the log after it
+    (``_load_committed_install``), so the applied count says nothing
+    about the log; ``install-snapshot-received`` is counted after both."""
+    deadline = time.monotonic() + timeout_s
+    while not node.metrics.counters_snapshot().get(
+            "install-snapshot-received", 0):
+        if time.monotonic() >= deadline:
+            raise TimeoutError(
+                f"{node} finished no InstallSnapshot in {timeout_s}s "
+                f"(installing={node.snapshot_executor.installing})")
+        await asyncio.sleep(0.02)
+
+
 async def test_install_snapshot_on_multilog_scheme(tmp_path):
     """InstallSnapshot + log reset over the SHARED journal engine: a
     follower crashed past the compaction horizon pulls the snapshot and
@@ -475,7 +490,9 @@ async def test_install_snapshot_on_multilog_scheme(tmp_path):
         await c.wait_applied(25, timeout_s=10)
         assert c.fsms[victim].logs == c.fsms[leader.server_id].logs
         assert c.fsms[victim].snapshots_loaded >= 1  # installed, not replayed
-        # and the recovered node's log lives on the shared engine
+        # and the recovered node's log lives on the shared engine, reset
+        # to the snapshot index once the install has ended
+        await _wait_install_received(node)
         assert node.log_manager.first_log_index() > 1
     finally:
         await c.stop_all()

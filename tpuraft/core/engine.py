@@ -950,7 +950,7 @@ class MultiRaftEngine:
         # floor) with hb/lease scaled proportionally.  The floor grows
         # with registered group count and the measured tick cost, so a
         # 16K-group process lands on a safe operating point without the
-        # hand-tuned 60s timeouts BENCH_SCALE previously required.
+        # hand-tuned 60s timeouts that density used to need.
         # lane: no-conf no-shift — requested durations (register_ctrl
         # writes them; conf changes and the time epoch never do)
         self.req_eto_ms = np.full(g, _DEF_ETO_MS, np.int64)

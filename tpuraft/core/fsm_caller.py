@@ -26,21 +26,12 @@ LOG = logging.getLogger(__name__)
 class FSMCaller:
     def __init__(self, fsm: StateMachine, log_manager, apply_batch: int = 32,
                  on_error: Optional[Callable[[Status], Awaitable[None]]] = None,
-                 health=None, trace_proc: str = "fsm", apply_lane=None):
+                 health=None, trace_proc: str = "fsm"):
         self._fsm = fsm
         self._lm = log_manager
         self._apply_batch = apply_batch
         self._node_on_error = on_error
         self._trace_proc = trace_proc
-        # apply worker lane (compartmentalization): when set AND the FSM
-        # exposes a sync ``apply_sync(it)``, DATA runs execute on the
-        # lane thread — the loop only awaits the hop, so a saturated
-        # store applies on a second core.  Closures the FSM fires on the
-        # lane must be thread-safe (KVClosure hops back via
-        # call_soon_threadsafe); the serialized-queue contract holds
-        # because _drain awaits each lane hop before the next event.
-        self._apply_lane = apply_lane
-        self.lane_batches = 0   # apply batches that rode the lane
         # gray-failure signal: committed-minus-applied depth, reported
         # to the store's HealthTracker on every commit advance — a
         # saturated/slow FSM shows up as a growing backlog long before
@@ -66,7 +57,7 @@ class FSMCaller:
         # demand-spawned drain (r4): a standing task per FSMCaller was
         # O(nodes) standing tasks per process — at 16K groups x 3
         # replicas that alone is 48K idle tasks (the election-starvation
-        # regime BENCH_SCALE r3 measured).  Events queue here and one
+        # regime the round-3 scale runs showed).  Events queue here and one
         # short-lived drain task runs only while events exist.
         self._queue: deque = deque()
         self._task: Optional[asyncio.Task] = None
@@ -294,24 +285,8 @@ class FSMCaller:
                     tids = ([x.trace_id for x in run if x.trace_id]
                             if _TRACE.enabled else [])
                     a0 = time.perf_counter() if tids else 0.0
-                    sync_apply = (getattr(self._fsm, "apply_sync", None)
-                                  if self._apply_lane is not None else None)
                     try:
-                        if sync_apply is not None:
-                            # lane apply: the sync body runs on the
-                            # store's apply thread; per-op closures hop
-                            # back to this loop inside KVClosure, and
-                            # post-apply loop-confined bookkeeping
-                            # (heat) runs here via on_lane_applied
-                            post = await self._apply_lane.submit(
-                                sync_apply, it)
-                            self.lane_batches += 1
-                            post_fn = getattr(self._fsm, "on_lane_applied",
-                                              None)
-                            if post_fn is not None:
-                                post_fn(post)
-                        else:
-                            await self._fsm.on_apply(it)
+                        await self._fsm.on_apply(it)
                     except Exception:
                         LOG.exception("StateMachine.on_apply crashed")
                         await self._set_error(Status.error(

@@ -405,8 +405,7 @@ class Node:
             apply_batch=opts.raft_options.apply_batch,
             on_error=self._on_fsm_error,
             health=opts.health,
-            trace_proc=self._trace_proc,
-            apply_lane=opts.apply_lane)
+            trace_proc=self._trace_proc)
         self.fsm_caller.on_configuration_applied = self._on_configuration_applied
 
         # snapshot subsystem

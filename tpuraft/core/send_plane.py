@@ -7,7 +7,7 @@ applies device outputs (send-plans)"): with thousands of raft groups
 multiplexed on a handful of process endpoints, per-group vote fanouts
 and per-(group, peer) replication tasks cost O(G x P) standing asyncio
 tasks — the measured 16K-group election-starvation wall
-(BENCH_SCALE.json r3).  Here every protocol send targeting one endpoint
+(round 3's scale runs).  Here every protocol send targeting one endpoint
 is enqueued to that endpoint's :class:`EndpointSender`, whose single
 drain task packs everything pending into ONE ``multi_append`` /
 ``multi_vote`` RPC (a :class:`~tpuraft.rpc.messages.BatchRequest`) per

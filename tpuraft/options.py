@@ -236,13 +236,6 @@ class NodeOptions:
     # probes and lease checks consult it to fail closed when the local
     # clock is drift-suspect.  None = no detection.
     clock_sentinel: Optional[object] = None
-    # store-level FSM apply lane (tpuraft.core.lanes.WorkerLane), shared
-    # by every node the hosting store runs: when set AND the FSM exposes
-    # a sync ``apply_sync``, committed DATA runs execute on the lane
-    # thread instead of the event loop (StoreEngineOptions.apply_lane).
-    # The lane then OWNS the state the FSM mutates — all other access
-    # must be submitted through it.  None = apply on the loop.
-    apply_lane: Optional[object] = None
 
 
 @dataclass

@@ -86,13 +86,6 @@ class BatchingOptions:
     # slow region's quorum must not idle the whole store pipe — same
     # reasoning as the send plane's multi-lane vote dispatch
     max_store_inflight: int = 4
-    # optional WorkerLane (tpuraft.core.lanes): batch-item encode moves
-    # off the event loop onto the lane thread, one hop per send window —
-    # the client-side half of the store's apply lane (a hot client loop
-    # spends a measurable slice purely serializing op blobs).  Items
-    # whose encode fails are failed INDIVIDUALLY at send time, same
-    # attribution contract as the inline encode path.
-    encode_lane: Optional[object] = None
 
 
 # graftcheck: loop-confined
@@ -181,19 +174,14 @@ class _StoreSender:
         # encode HERE, not in the send path: a malformed op (bad key
         # type) must fail its OWN caller, never poison the unrelated
         # items sharing its lane (the same invariant RaftRawKVStore.
-        # apply holds one layer down).  With an encode_lane configured
-        # the serialize moves to the lane thread at send time — _send
-        # keeps the same per-item attribution there.
-        if self._client._batch_opts.encode_lane is None:
-            try:
-                blob = encode_batch_item(region.id, region.epoch.conf_ver,
-                                         region.epoch.version, op.encode())
-            except Exception as e:  # noqa: BLE001
-                fut.set_result(RheaKVError(Status.error(
-                    RaftError.EINVAL, f"malformed op: {e!r}")))
-                return fut
-        else:
-            blob = None
+        # apply holds one layer down)
+        try:
+            blob = encode_batch_item(region.id, region.epoch.conf_ver,
+                                     region.epoch.version, op.encode())
+        except Exception as e:  # noqa: BLE001
+            fut.set_result(RheaKVError(Status.error(
+                RaftError.EINVAL, f"malformed op: {e!r}")))
+            return fut
         # trace plane: only a SAMPLED op's context rides the row (and
         # the wire) — unsampled slow-candidates keep the serving path
         # untouched (wire_ctx masks them to 0)
@@ -242,24 +230,6 @@ class _StoreSender:
 
     async def _send(self, batch: list) -> None:
         client = self._client
-        lane = client._batch_opts.encode_lane
-        if lane is not None:
-            # one lane hop serializes the whole window off-loop; a row
-            # whose encode raises fails its OWN future here (same
-            # attribution the inline path gives at submit time) and is
-            # dropped from the RPC
-            blobs = await lane.submit(_encode_rows, batch)
-            keep = []
-            for row, blob in zip(batch, blobs):
-                if isinstance(blob, Exception):
-                    if not row[3].done():
-                        row[3].set_result(RheaKVError(Status.error(
-                            RaftError.EINVAL, f"malformed op: {blob!r}")))
-                    continue
-                keep.append(row[:2] + (blob,) + row[3:])
-            batch = keep
-            if not batch:
-                return
         rpc0 = 0.0
         sec = TRACER.enter("client.send") if TRACER.enabled else None
         req = KVCommandBatchRequest(
@@ -345,21 +315,6 @@ class _StoreSender:
             if not fut.done():
                 fut.set_result(client._decode_outcome(region, peer, blob,
                                                       spread=spread))
-
-
-def _encode_rows(rows: list) -> list:
-    """Serialize a send window's op blobs (runs ON the encode lane
-    thread — touches only the rows' immutable region/op fields).  A
-    failed encode yields its exception in place so the caller can fail
-    that item individually."""
-    out = []
-    for region, _p, _b, _f, _s, _t, _ts, op in rows:
-        try:
-            out.append(encode_batch_item(region.id, region.epoch.conf_ver,
-                                         region.epoch.version, op.encode()))
-        except Exception as e:  # noqa: BLE001 — attributed per item
-            out.append(e)
-    return out
 
 
 # graftcheck: loop-confined — route table, batchers and store senders

@@ -352,11 +352,8 @@ class KVCommandProcessor:
                 result = await rs.apply(op)
             else:
                 # ONE dispatch table for reads: fence here, then the
-                # same local-serve path the batched fast path uses —
-                # on the apply lane when one owns the store
+                # same local-serve path the batched fast path uses
                 await rs.node.read_index()
-                if rs.lane is not None:
-                    return await rs.lane.submit(_serve_read_local, rs, op)
                 return _serve_read_local(rs, op)
         except KVStoreError as e:
             return e.status.code, e.status.error_msg, None
@@ -593,48 +590,26 @@ class KVCommandProcessor:
                         TRACER.span(tid, "srv_read_fence", f0, f1,
                                     proc=self._proc)
                 served = out_bytes = 0
-                lane = rs.lane
-                if lane is not None:
-                    # lane mode: the lane thread owns the store — serve
-                    # the whole fenced sub-batch in ONE lane hop (one
-                    # shared serve-span envelope for traced ops)
-                    s0 = time.perf_counter() if rtids else 0.0
-                    outs = await lane.submit(_serve_reads_sync, rs, reads)
-                    if rtids:
-                        s1 = time.perf_counter()
-                        for tid in rtids:
-                            TRACER.span(tid, "srv_read_serve", s0, s1,
+                sec = TRACER.enter("kv.batch") if TRACER.enabled else None
+                try:
+                    for i, op in reads:
+                        s0 = time.perf_counter() if op.trace_id else 0.0
+                        code, msg, result = _serve_read_local(rs, op)
+                        if op.trace_id:
+                            TRACER.span(op.trace_id, "srv_read_serve",
+                                        s0, time.perf_counter(),
                                         proc=self._proc)
-                    for (i, _op), (code, msg, result) in zip(reads, outs):
                         replies[i] = (
-                            encode_batch_reply(0,
-                                               result=encode_result(result))
-                            if code == 0 else encode_batch_reply(code, msg))
+                            encode_batch_reply(
+                                0, result=encode_result(result))
+                            if code == 0
+                            else encode_batch_reply(code, msg))
                         if code == 0:
                             served += 1
                             out_bytes += len(replies[i])
-                else:
-                    sec = TRACER.enter("kv.batch") if TRACER.enabled \
-                        else None
-                    try:
-                        for i, op in reads:
-                            s0 = time.perf_counter() if op.trace_id else 0.0
-                            code, msg, result = _serve_read_local(rs, op)
-                            if op.trace_id:
-                                TRACER.span(op.trace_id, "srv_read_serve",
-                                            s0, time.perf_counter(),
-                                            proc=self._proc)
-                            replies[i] = (
-                                encode_batch_reply(
-                                    0, result=encode_result(result))
-                                if code == 0
-                                else encode_batch_reply(code, msg))
-                            if code == 0:
-                                served += 1
-                                out_bytes += len(replies[i])
-                    finally:
-                        if sec is not None:
-                            TRACER.leave(sec)
+                finally:
+                    if sec is not None:
+                        TRACER.leave(sec)
                 if served and self._heat is not None:
                     self._heat.note_read(rid, served, out_bytes)
 
@@ -717,11 +692,6 @@ def _serve_read_local(rs, op: KVOperation) -> tuple[int, str, object]:
     except Exception as e:  # noqa: BLE001
         return int(RaftError.EINTERNAL), str(e), None
     return 0, "", result
-
-
-def _serve_reads_sync(rs, reads: list) -> list[tuple[int, str, object]]:
-    """One lane job serving a whole fenced region read sub-batch."""
-    return [_serve_read_local(rs, op) for _, op in reads]
 
 
 _SINGLE_KEY_OPS = {

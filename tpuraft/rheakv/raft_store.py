@@ -42,15 +42,9 @@ _NOT_EAGER = object()
 class RaftRawKVStore:
     def __init__(self, node: Node, store: RawKVStore,
                  apply_batch: int = 32, multi_entries: bool = True,
-                 ack_at_commit: bool = True, lane=None):
+                 ack_at_commit: bool = True):
         self.node = node
         self.store = store
-        # apply worker lane (StoreEngineOptions.apply_lane): when set,
-        # the lane thread owns the raw store — local reads below are
-        # SUBMITTED through it (queue FIFO is the happens-before edge
-        # past the read fence) instead of touching the store from the
-        # loop while another region's apply mutates it
-        self.lane = lane
         # pipelined apply: blind writes ack their proposer at COMMIT
         # (the entry's linearization point — the result is known a
         # priori) and the FSM applies behind in coalesced batches;
@@ -325,11 +319,8 @@ class RaftRawKVStore:
     # -- read path (readIndex barrier + local read) --------------------------
 
     async def _read(self, fn, *args):
-        """Fenced local read: read_index barrier, then the store call —
-        on the apply lane when one owns the store, else inline."""
+        """Fenced local read: read_index barrier, then the store call."""
         await self.node.read_index()
-        if self.lane is not None:
-            return await self.lane.submit(fn, *args)
         return fn(*args)
 
     async def get(self, key: bytes) -> Optional[bytes]:

@@ -44,6 +44,13 @@ class RawKVStore:
     """Synchronous KV storage under one region's state machine.
 
     All ranges are ``[start, end)``; ``b""`` end means +inf.
+
+    Ownership: every data call into a store (reads, writes,
+    ``apply_write_batch``, ``serialize_range``, ``load_serialized``, the
+    key-count and split probes) is made on the store engine's loop
+    thread, so an implementation needs no lock of its own and a call
+    sees every call before it.  No other thread reads or writes one
+    (tests/test_kv_batch.py pins it).
     """
 
     # -- reads ---------------------------------------------------------------

@@ -51,13 +51,7 @@ class RegionEngine:
     async def start(self) -> None:
         se = self.store_engine
         self.fsm = KVStoreStateMachine(
-            self.region, se.raw_store, se,
-            coalesce_applies=se.opts.fsm_coalesce,
-            apply_round=se.apply_round)
-        # apply worker lane (StoreEngineOptions.apply_lane): the lane
-        # owns the shared raw store — the FSM routes snapshot
-        # serialization through it, the raft store its fenced reads
-        self.fsm.lane = se.apply_lane
+            self.region, se.raw_store, se, apply_round=se.apply_round)
         opts = se.make_node_options(self.region, self.fsm)
         self._group_service = RaftGroupService(
             self.group_id, se.server_id, opts, se.node_manager, se.transport,
@@ -74,7 +68,7 @@ class RegionEngine:
             node.append_batcher = se.append_batcher
         self.raft_store = RaftRawKVStore(
             node, se.raw_store, multi_entries=se.opts.multi_op_entries,
-            ack_at_commit=se.opts.ack_at_commit, lane=se.apply_lane)
+            ack_at_commit=se.opts.ack_at_commit)
         LOG.info("region engine started: %s on %s", self.region,
                  se.server_id)
 

@@ -68,34 +68,15 @@ class KVClosure:
     """Proposal completion carrying an op result back to the proposer
     (reference: ``rhea:storage/KVStoreClosure#setData``).
 
-    Thread-safe against worker-lane apply: when the FSM fires it from
-    the store's apply lane, the resolution hops back to the proposer's
-    loop via ``call_soon_threadsafe``.  ``_fired`` (set before the hop)
-    makes the first caller win — the FSMCaller's loop-side
-    auto-complete must not override a lane-fired error status whose
-    delivery is still in flight."""
+    Fired on its future's loop, and the first caller wins: the
+    FSMCaller's auto-complete after ``on_apply`` must not overwrite an
+    error status the FSM already delivered."""
 
     def __init__(self, fut):
         self._fut = fut
         self.result = None
-        self._fired = False
 
     def __call__(self, status: Status) -> None:
-        if self._fired:
-            return
-        self._fired = True
-        fut = self._fut
-        try:
-            running = asyncio.get_running_loop()
-        except RuntimeError:
-            running = None
-        if running is fut.get_loop():
-            if not fut.done():
-                fut.set_result((status, self.result))
-        else:
-            fut.get_loop().call_soon_threadsafe(self._deliver, status)
-
-    def _deliver(self, status: Status) -> None:
         if not self._fut.done():
             self._fut.set_result((status, self.result))
 
@@ -203,7 +184,7 @@ class KVStoreStateMachine(StateMachine):
                             KVOp.GET, KVOp.MULTI_GET, KVOp.CONTAINS_KEY))
 
     def __init__(self, region: Region, store: RawKVStore,
-                 store_engine=None, coalesce_applies: bool = True,
+                 store_engine=None,
                  apply_round: Optional[ApplyRound] = None) -> None:
         self.region = region
         self.store = store
@@ -213,15 +194,8 @@ class KVStoreStateMachine(StateMachine):
         self.apply_round = apply_round if apply_round is not None \
             else ApplyRound(store)
         self.leader_term = -1
-        # apply worker lane (StoreEngineOptions.apply_lane): when set,
-        # the lane thread OWNS the raw store — apply_sync runs there,
-        # and snapshot serialization below is submitted through it
-        # instead of touching the store from the loop
-        self.lane = None
-        # coalesced-apply knob + counters (StoreEngineOptions.fsm_coalesce):
-        # consecutive PUT/DELETE(-list) entries flush as ONE native batch
-        # write instead of one store call per op
-        self.coalesce_applies = coalesce_applies
+        # consecutive PUT/DELETE(-list) entries reach the store as ONE
+        # batch write, not one store call per op
         self.coalesced_flushes = 0   # flushes that merged more than one row
         self.coalesced_ops = 0       # rows that rode a merged flush
         # merge barrier (lifecycle plane): >= 0 once a MERGE_SEAL entry
@@ -274,107 +248,75 @@ class KVStoreStateMachine(StateMachine):
                 done(st)
 
     async def on_apply(self, it: Iterator) -> None:
-        # the apply body on the loop thread; each run's rows ride the
-        # store's apply round, whose one store call is SYNC_SECTION
-        steps = self._apply_steps(it)
-        err = None
-        while True:
-            # a section is a synchronous stretch: one up to each await
-            sec = TRACER.enter("fsm.apply") if TRACER.enabled else None
-            try:
-                rows, entries = steps.send(err)
-                fut = self.apply_round.stage(rows, entries)
-            except StopIteration as stop:
-                self.on_lane_applied(stop.value)
-                return
-            finally:
-                if sec is not None:
-                    TRACER.leave(sec)
-            err = await fut
-
-    def on_lane_applied(self, applied_ops: int) -> None:
-        """Post-apply bookkeeping that must stay on the loop (the heat
-        tracker is loop-confined): the FSMCaller calls this after a
-        lane-submitted apply_sync returns; the loop path above calls it
-        inline."""
-        # per-region heat (fleet observability): the applied lane is the
-        # replication-side load — followers see it for regions they
-        # never serve, giving the store a full local picture; the PD
-        # only ever reads the leaders' serving rates
-        heat = getattr(self.store_engine, "heat", None)
-        if heat is not None and applied_ops:
-            heat.note_applied(self.region.id, applied_ops)
-
-    def apply_sync(self, it: Iterator) -> int:
-        """The apply body, synchronous, for the store's apply worker
-        lane (FSMCaller submits it when StoreEngineOptions.apply_lane is
-        on): the lane thread owns the raw store, so each run is one
-        store call of its own.  Returns the applied op count for
-        on_lane_applied."""
-        steps = self._apply_steps(it)
-        err = None
-        while True:
-            try:
-                rows, _entries = steps.send(err)
-            except StopIteration as stop:
-                return stop.value
-            try:
-                self.store.apply_write_batch(rows)
-                err = None
-            except Exception as e:  # noqa: BLE001 — _finish_run reports it
-                err = e
-
-    def _apply_steps(self, it: Iterator):
-        """The apply body, shared by the loop path and the lane path:
-        decode, collect runs, dispatch the rest, finish closures.  A
-        generator: it yields ``(rows, entries)`` whenever a run has to
-        reach the store before the body may go on (a non-run entry is
-        next, or the batch ended), is sent None or the exception that
-        write raised, and returns the applied op count.
+        """Decode, collect runs, dispatch the rest, finish closures.
 
         A run is consecutive PUT/DELETE(-list) entries and MULTI
         entries made of nothing else (what ``kv_command_batch`` gives a
-        hot region).  Any other entry first has the region's own run
-        written, then dispatches with its own store call."""
+        hot region).  Its rows ride the store's apply round, whose one
+        store call is SYNC_SECTION, and it has to be written before the
+        body may go on: when a non-run entry is next (it dispatches with
+        its own store call and must see the run's rows) or the batch
+        ended."""
         rows: list = []
         dones: list = []   # (done, closure, a MULTI's sub-op count or None)
         applied_ops = 0    # heat telemetry: replication-side rate
-        while it.valid():
-            applied_ops += 1
-            op = KVOperation.decode(it.data())
-            done = it.done()
-            closure = done if isinstance(done, KVClosure) else None
-            subs = None
-            if self.coalesce_applies and self.sealed_into < 0:
-                if op.op == KVOp.MULTI:
-                    subs = KVOperation.unpack_multi(op.value)
-                writes = [op] if subs is None else subs
-                if all(w.op in self._RUN_OPS for w in writes):
-                    for w in writes:
-                        rows.extend(self._run_rows(w))
-                    dones.append((done, closure,
-                                  None if subs is None else len(subs)))
-                    it.next()
-                    continue
-            if dones:
-                self._finish_run((yield rows, len(dones)), len(rows), dones)
-                rows, dones = [], []
-            try:
-                result = self._dispatch_multi(subs) if subs is not None \
-                    else self._dispatch(op)
-                if closure is not None:
-                    closure.result = result
-                if done is not None:
-                    done(Status.OK())
-            except Exception as e:  # noqa: BLE001 — op-level failure, not fatal
-                LOG.exception("region %d apply op %s failed",
-                              self.region.id, op.op)
-                if done is not None:
-                    done(Status.error(RaftError.ESTATEMACHINE, str(e)))
-            it.next()
-        if dones:
-            self._finish_run((yield rows, len(dones)), len(rows), dones)
-        return applied_ops
+        # a section is a synchronous stretch: one up to each await
+        sec = TRACER.enter("fsm.apply") if TRACER.enabled else None
+        try:
+            while True:
+                more = it.valid()
+                if more:
+                    applied_ops += 1
+                    op = KVOperation.decode(it.data())
+                    done = it.done()
+                    closure = done if isinstance(done, KVClosure) else None
+                    subs = None
+                    if self.sealed_into < 0:
+                        if op.op == KVOp.MULTI:
+                            subs = KVOperation.unpack_multi(op.value)
+                        writes = [op] if subs is None else subs
+                        if all(w.op in self._RUN_OPS for w in writes):
+                            for w in writes:
+                                rows.extend(self._run_rows(w))
+                            dones.append((done, closure,
+                                          None if subs is None else len(subs)))
+                            it.next()
+                            continue
+                if dones:
+                    fut = self.apply_round.stage(rows, len(dones))
+                    if sec is not None:
+                        TRACER.leave(sec)
+                        sec = None
+                    err = await fut
+                    sec = TRACER.enter("fsm.apply") if TRACER.enabled \
+                        else None
+                    self._finish_run(err, len(rows), dones)
+                    rows, dones = [], []
+                if not more:
+                    break
+                try:
+                    result = self._dispatch_multi(subs) if subs is not None \
+                        else self._dispatch(op)
+                    if closure is not None:
+                        closure.result = result
+                    if done is not None:
+                        done(Status.OK())
+                except Exception as e:  # noqa: BLE001 — op-level failure, not fatal
+                    LOG.exception("region %d apply op %s failed",
+                                  self.region.id, op.op)
+                    if done is not None:
+                        done(Status.error(RaftError.ESTATEMACHINE, str(e)))
+                it.next()
+            # per-region heat (fleet observability): the applied rate is
+            # the replication-side load — followers see it for regions
+            # they never serve, giving the store a full local picture;
+            # the PD only ever reads the leaders' serving rates
+            heat = getattr(self.store_engine, "heat", None)
+            if heat is not None and applied_ops:
+                heat.note_applied(self.region.id, applied_ops)
+        finally:
+            if sec is not None:
+                TRACER.leave(sec)
 
     def _dispatch(self, op: KVOperation):
         s = self.store
@@ -428,19 +370,6 @@ class KVStoreStateMachine(StateMachine):
             (new_region_id,) = struct.unpack("<q", op.aux)
             if self.store_engine is None:
                 raise RuntimeError("split requires a store engine")
-            try:
-                asyncio.get_running_loop()
-            except RuntimeError:
-                # lane apply: do_split mutates loop-confined StoreEngine
-                # state (region table, heat rows, the new engine's boot
-                # task) — hop it back to the engine's loop.  The range
-                # narrowing lands a beat later; serving-side range
-                # checks re-validate per request, so the window only
-                # delays the client's epoch refresh.
-                self.store_engine.loop_call_threadsafe(
-                    self.store_engine.do_split,
-                    self.region.id, new_region_id, op.key)
-                return True
             self.store_engine.do_split(self.region.id, new_region_id, op.key)
             return True
         if code == KVOp.MERGE_SEAL:
@@ -461,9 +390,8 @@ class KVStoreStateMachine(StateMachine):
             # the data load AND the (no-op) extension.
             if range_covers(self.region, src_start, src_end):
                 return True
-            # data first, in the store-owning context (idempotent
-            # overwrite: on a shared per-store raw store the source's
-            # rows are already physically present)
+            # data first (idempotent overwrite: on a shared per-store
+            # raw store the source's rows are already physically present)
             if op.value:
                 s.load_serialized(op.value)
             self._absorb_meta(src_id, src_start, src_end)
@@ -471,16 +399,6 @@ class KVStoreStateMachine(StateMachine):
         if code == KVOp.MERGE_COMMIT:
             (target_id,) = struct.unpack("<q", op.aux)
             if self.store_engine is not None:
-                try:
-                    asyncio.get_running_loop()
-                except RuntimeError:
-                    # lane apply: retirement mutates loop-confined
-                    # StoreEngine state (region table, heat rows, the
-                    # engine shutdown task) — hop to the engine's loop
-                    self.store_engine.loop_call_threadsafe(
-                        self.store_engine.do_retire,
-                        self.region.id, target_id)
-                    return True
                 self.store_engine.do_retire(self.region.id, target_id)
             return True
         if code == KVOp.GET:  # linearizable-via-log read
@@ -503,8 +421,7 @@ class KVStoreStateMachine(StateMachine):
         outs: list = [None] * len(ops)
         i, n = 0, len(ops)
         while i < n:
-            if self.coalesce_applies and ops[i].op in self._RUN_OPS \
-                    and self.sealed_into < 0:
+            if ops[i].op in self._RUN_OPS and self.sealed_into < 0:
                 j = i
                 rows: list = []
                 while j < n and ops[j].op in self._RUN_OPS:
@@ -536,18 +453,9 @@ class KVStoreStateMachine(StateMachine):
     def _absorb_meta(self, src_id: int, src_start: bytes,
                      src_end: bytes) -> None:
         """Metadata half of a MERGE_ABSORB apply: range extension +
-        epoch bump (+ store-engine bookkeeping), hopped to the engine's
-        loop when applying on the store's worker lane — same contract
-        as the RANGE_SPLIT arm."""
+        epoch bump (+ store-engine bookkeeping)."""
         if self.store_engine is None:
             extend_region_over(self.region, src_start, src_end)
-            return
-        try:
-            asyncio.get_running_loop()
-        except RuntimeError:
-            self.store_engine.loop_call_threadsafe(
-                self.store_engine.do_absorb,
-                self.region.id, src_id, src_start, src_end)
             return
         self.store_engine.do_absorb(self.region.id, src_id,
                                     src_start, src_end)
@@ -588,16 +496,8 @@ class KVStoreStateMachine(StateMachine):
 
     async def on_snapshot_save(self, writer, done) -> None:
         try:
-            # lane mode: the lane thread owns the store — OTHER regions'
-            # applies run there concurrently with this region's save, so
-            # the range serialization must ride the lane queue too
-            if self.lane is not None:
-                blob = await self.lane.submit(
-                    self.store.serialize_range,
-                    self.region.start_key, self.region.end_key)
-            else:
-                blob = self.store.serialize_range(self.region.start_key,
-                                                  self.region.end_key)
+            blob = self.store.serialize_range(self.region.start_key,
+                                              self.region.end_key)
             writer.write_file("kv_data", blob)
             writer.write_file("region_meta", self.region.encode())
             if self.sealed_into >= 0:
@@ -628,15 +528,9 @@ class KVStoreStateMachine(StateMachine):
         # exact state reset of our slice (data + sequences + locks), then
         # load — merging would leave post-snapshot keys behind and make
         # log replay after restart non-deterministic across replicas
-        if self.lane is not None:
-            await self.lane.submit(self._load_sync, blob)
-        else:
-            self._load_sync(blob)
-        return True
-
-    def _load_sync(self, blob: bytes) -> None:
         self.store.reset_range(self.region.start_key, self.region.end_key)
         self.store.load_serialized(blob)
+        return True
 
     async def on_error(self, status: Status) -> None:
         LOG.error("region %d FSM error: %s", self.region.id, status)

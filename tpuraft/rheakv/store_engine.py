@@ -171,11 +171,6 @@ class StoreEngineOptions:
     # interval x 2^fails, clamped here) — a down PD costs one cheap
     # probe per cap interval, not a hot retry loop
     pd_backoff_max_ms: int = 30000
-    # serving-plane apply coalescing: the region FSMs flush consecutive
-    # PUT/DELETE(-list) entries as ONE store batch write (one ctypes
-    # call + one WAL record per run) instead of one call per op — see
-    # KVStoreStateMachine.coalesce_applies
-    fsm_coalesce: bool = True
     # kv_command_batch write sub-batches ride ONE KVOp.MULTI log entry
     # per region (one quorum round amortized).  Set False during a
     # rolling upgrade from a pre-batch build: a MULTI entry replicated
@@ -204,16 +199,6 @@ class StoreEngineOptions:
     # (read_index + wait_applied) keeps reads observing applied state.
     # False = ack after apply (the pre-write-plane behavior).
     ack_at_commit: bool = True
-    # -- apply worker lane (compartmentalization) ----------------------------
-    # run FSM apply on a dedicated store-wide worker thread instead of
-    # the event loop (tpuraft/core/lanes.py): the lane thread OWNS the
-    # raw store — fenced reads, snapshot serialization and split-point
-    # probing are submitted through its FIFO queue, so the loop only
-    # pays an await per batch and a hot store saturates a second core.
-    # False = apply on the loop (the single-core default; the native
-    # store's C calls already release the GIL under the lane, the
-    # memory store still offloads the loop's share).
-    apply_lane: bool = False
     # -- gray-failure survival (fail-slow detection + mitigation) ------------
     # score this store {HEALTHY, DEGRADED, SICK} from hot-path signals
     # (append/fsync latency, peer ack RTTs, apply backlog — see
@@ -824,18 +809,9 @@ class StoreEngine:
                 self.apply_round.syncs
             multi_raft_engine.tick_hists["kv_wal_sync_entries"] = \
                 self.apply_round.sync_entries
-        # apply worker lane: ONE dedicated thread per store owning the
-        # raw store's mutation order (see StoreEngineOptions.apply_lane)
-        self.apply_lane = None
-        if opts.apply_lane:
-            from tpuraft.core.lanes import WorkerLane
-
-            self.apply_lane = WorkerLane(
-                name=f"apply-{self.server_id.endpoint}")
         # SIGTERM drain (process topology): True bounces NEW kv work
         # with a retryable busy while admitted items finish — see drain()
         self.draining = False
-        self._loop: Optional[asyncio.AbstractEventLoop] = None
         self.multi_raft_engine = multi_raft_engine
         self.pd_client = pd_client
         self._regions: dict[int, RegionEngine] = {}
@@ -919,7 +895,6 @@ class StoreEngine:
     # -- lifecycle -----------------------------------------------------------
 
     async def start(self) -> None:
-        self._loop = asyncio.get_running_loop()
         if self.health is not None:
             # beat-plane RPCs double as per-endpoint RTT probes
             self.node_manager.heartbeat_hub.health = self.health
@@ -1054,9 +1029,6 @@ class StoreEngine:
             _gc_store_down()
         if self.multi_raft_engine is not None:
             await self.multi_raft_engine.shutdown()
-        if self.apply_lane is not None:
-            # after the regions: no FSMCaller is left to submit applies
-            await self.apply_lane.aclose()
         # a round left open (its flush callback not yet run) is written
         # before the store goes
         self.apply_round.flush()
@@ -1068,15 +1040,6 @@ class StoreEngine:
 
             _release_journal(self._meta_journal)
             self._meta_journal = None
-
-    def loop_call_threadsafe(self, fn, *args) -> None:
-        """Hop a loop-confined engine call off a worker lane thread
-        (lane-applied RANGE_SPLIT is the one caller today)."""
-        loop = self._loop
-        if loop is None:
-            fn(*args)
-            return
-        loop.call_soon_threadsafe(fn, *args)
 
     async def drain(self, timeout_s: float = 10.0) -> bool:
         """SIGTERM drain: stop admitting NEW kv work (handlers bounce it
@@ -1342,14 +1305,13 @@ class StoreEngine:
         # TTL cache bounds): apply/propose plane totals across every
         # hosted region — entries-per-batch amortization, live
         apply_batches = applied_entries = eager_acked = 0
-        propose_drains = proposed_ops = lane_batches = 0
+        propose_drains = proposed_ops = 0
         for eng in list(self._regions.values()):
             node = eng.node
             if node is not None and node.fsm_caller is not None:
                 apply_batches += node.fsm_caller.apply_batches
                 applied_entries += node.fsm_caller.applied_entries
                 eager_acked += node.fsm_caller.eager_acked
-                lane_batches += node.fsm_caller.lane_batches
             if eng.raft_store is not None:
                 propose_drains += eng.raft_store.propose_drains
                 proposed_ops += eng.raft_store.proposed_ops
@@ -1357,12 +1319,9 @@ class StoreEngine:
             "fsm_apply_batches": apply_batches,
             "fsm_applied_entries": applied_entries,
             "fsm_eager_acked": eager_acked,
-            "fsm_lane_batches": lane_batches,
             "propose_drains": propose_drains,
             "proposed_ops": proposed_ops,
         })
-        if self.apply_lane is not None:
-            counters["lane_jobs"] = self.apply_lane.jobs
         if self.read_batcher is not None:
             counters.update(self.read_batcher.counters())
         if self.append_batcher is not None:
@@ -1392,8 +1351,6 @@ class StoreEngine:
             "draining": int(self.draining),
             **trace_gauges,
         }
-        if self.apply_lane is not None:
-            gauges["lane_depth"] = self.apply_lane.depth()
         if self.health is not None:
             gauges.update(self.health.counters())
         if self.disk_budget is not None:
@@ -1525,14 +1482,6 @@ class StoreEngine:
             # ±10% per-round jitter: phase-locked fleets drift apart
             await asyncio.sleep(backoff * (0.9 + 0.2 * rng.random()))
 
-    async def _approx_keys(self, start: bytes, end: bytes) -> int:
-        """Range key-count probe — through the apply lane when one owns
-        the store (a loop-side index rebuild would race lane applies)."""
-        if self.apply_lane is not None:
-            return await self.apply_lane.submit(
-                self.raw_store.approximate_keys_in_range, start, end)
-        return self.raw_store.approximate_keys_in_range(start, end)
-
     def _pd_fingerprint(self, region: Region) -> tuple:
         return (region.epoch.conf_ver, region.epoch.version,
                 region.start_key, region.end_key, tuple(region.peers))
@@ -1549,7 +1498,8 @@ class StoreEngine:
             if engine is None or not engine.is_leader():
                 continue
             region = engine.region
-            keys = await self._approx_keys(region.start_key, region.end_key)
+            keys = self.raw_store.approximate_keys_in_range(
+                region.start_key, region.end_key)
             fp = self._pd_fingerprint(region)
             last = self._pd_reported.get(rid)
             # a keys move under ~12.5% (and < 64 abs) is noise, not a
@@ -1760,11 +1710,6 @@ class StoreEngine:
         # store-level capacity tracker (LogManager append bytes,
         # snapshot executor commit/prune deltas, ENOSPC observations)
         opts.disk_budget = self.disk_budget
-        # apply worker lane: every region's FSMCaller submits committed
-        # DATA runs to the ONE store-wide lane (total store order
-        # preserved by the lane's FIFO; witness regions have a null FSM
-        # with no apply_sync and stay on the loop)
-        opts.apply_lane = self.apply_lane
         if self.opts.data_path:
             store_base = (f"{self.opts.data_path}/"
                           f"{self.server_id.ip}_{self.server_id.port}")
@@ -1856,18 +1801,14 @@ class StoreEngine:
                                 f"region {new_region_id} exists")
         region = engine.region
         if split_key is None:
-            n = await self._approx_keys(region.start_key, region.end_key)
+            n = self.raw_store.approximate_keys_in_range(
+                region.start_key, region.end_key)
             if n < self.opts.least_keys_on_split:
                 return Status.error(
                     RaftError.EBUSY,
                     f"region {region_id} too small to split ({n} keys)")
-            if self.apply_lane is not None:
-                split_key = await self.apply_lane.submit(
-                    self.raw_store.jump_over,
-                    region.start_key, region.end_key, n // 2)
-            else:
-                split_key = self.raw_store.jump_over(
-                    region.start_key, region.end_key, n // 2)
+            split_key = self.raw_store.jump_over(
+                region.start_key, region.end_key, n // 2)
         if split_key is None or not region.contains_key(split_key):
             return Status.error(RaftError.EINVAL,
                                 f"bad split key {split_key!r}")
@@ -1974,11 +1915,7 @@ class StoreEngine:
             # stores that never hosted the source need it (replicas
             # sharing this raw store re-apply it as an idempotent
             # overwrite)
-            if self.apply_lane is not None:
-                blob = await self.apply_lane.submit(
-                    self.raw_store.serialize_range, src_start, src_end)
-            else:
-                blob = self.raw_store.serialize_range(src_start, src_end)
+            blob = self.raw_store.serialize_range(src_start, src_end)
             st = await self._absorb_into_target(
                 target_region_id, target_peer, region_id,
                 src_start, src_end, blob)

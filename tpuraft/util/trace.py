@@ -206,8 +206,8 @@ class Tracer:
         ``enabled`` first (``sec = T.enter(n) if T.enabled else None``)
         and hands the frame back to :meth:`leave` before any ``await``.
         ``now`` is a ``perf_counter`` reading the caller already took.
-        None from a thread other than the loop's (an apply lane, an
-        executor): sections are loop-confined."""
+        None from a thread other than the loop's (an executor):
+        sections are loop-confined."""
         if _get_ident() != self._sec_tid:
             if self._sec_stack or self._sec_acc:
                 return None
