@@ -23,6 +23,9 @@ int64_t tlm_first(tlm_handle* h, uint32_t gid);
 int64_t tlm_last(tlm_handle* h, uint32_t gid);
 int64_t tlm_append(tlm_handle* h, uint32_t gid, const uint8_t* frames,
                    int64_t total, char* err, int errlen);
+int64_t tlm_append_round(tlm_handle* h, int64_t n, const uint32_t* gids,
+                         const uint8_t* const* frames, const int64_t* lens,
+                         int64_t* results, char* err, int errlen);
 int tlm_sync(tlm_handle* h, char* err, int errlen);
 int64_t tlm_sync_count(tlm_handle* h);
 int64_t tlm_get(tlm_handle* h, uint32_t gid, int64_t index, uint8_t** out);
@@ -101,6 +104,54 @@ int main(int argc, char** argv) {
     });
   }
 
+  // the flush round's staging: one call for four more groups at a time
+  // (two entries each; every 50th round one group offers a gap and must
+  // fail alone), racing the per-group appenders above on the same journal
+  constexpr int kRoundGroups = 4;
+  constexpr int64_t kRounds = 600;
+  uint32_t rgids[kRoundGroups];
+  for (int g = 0; g < kRoundGroups; ++g) {
+    std::string name = "rnd" + std::to_string(g);
+    rgids[g] = tlm_register_group(h, name.c_str(), err, sizeof(err));
+    if (!rgids[g]) return 1;
+  }
+  int64_t next[kRoundGroups];  // read again after the thread is joined
+  std::thread rounder([&] {
+    for (auto& n : next) n = 1;
+    for (int64_t r = 0; r < kRounds; ++r) {
+      std::string f[kRoundGroups];
+      const uint8_t* ptrs[kRoundGroups];
+      int64_t lens[kRoundGroups], results[kRoundGroups];
+      int bad = (r % 50 == 49) ? (int)(r % kRoundGroups) : -1;
+      for (int g = 0; g < kRoundGroups; ++g) {
+        int64_t at = next[g] + (g == bad ? 1 : 0);  // a gap: refused
+        f[g] = make_frame(at, 7, "r" + std::to_string(at)) +
+               make_frame(at + 1, 7, std::string(40, 'x'));
+        ptrs[g] = (const uint8_t*)f[g].data();
+        lens[g] = (int64_t)f[g].size();
+      }
+      char e[256] = {0};
+      int64_t failed = tlm_append_round(h, kRoundGroups, rgids, ptrs, lens,
+                                        results, e, sizeof(e));
+      if (failed != (bad >= 0 ? 1 : 0)) {
+        fprintf(stderr, "round %lld: %lld failed: %s\n", (long long)r,
+                (long long)failed, e);
+        abort();
+      }
+      for (int g = 0; g < kRoundGroups; ++g) {
+        if (results[g] != (g == bad ? -1 : 2)) {
+          fprintf(stderr, "round %lld slot %d: %lld\n", (long long)r, g,
+                  (long long)results[g]);
+          abort();
+        }
+        if (g != bad) next[g] += 2;
+      }
+    }
+    for (int g = 0; g < kRoundGroups; ++g) {
+      if (tlm_last(h, rgids[g]) != next[g] - 1) abort();
+    }
+  });
+
   std::thread syncer([&] {
     while (!stop.load(std::memory_order_acquire)) {
       char e[256];
@@ -152,6 +203,7 @@ int main(int argc, char** argv) {
   });
 
   for (auto& a : appenders) a.join();
+  rounder.join();
   stop.store(true, std::memory_order_release);
   syncer.join();
   for (auto& r : readers) r.join();
@@ -178,6 +230,25 @@ int main(int argc, char** argv) {
     int64_t r = tlm_get(h, gid, tlm_first(h, gid), &blob);
     if (r <= 0) return 1;
     tlm_free(blob);
+  }
+  for (int g = 0; g < kRoundGroups; ++g) {
+    std::string name = "rnd" + std::to_string(g);
+    uint32_t gid = tlm_register_group(h, name.c_str(), err, sizeof(err));
+    int64_t want = next[g] - 1;  // two a round, less its refused rounds
+    if (want < 2 * (kRounds - kRounds / 50)) return 1;
+    if (tlm_last(h, gid) != want) {
+      fprintf(stderr, "rnd%d last %lld != %lld\n", g,
+              (long long)tlm_last(h, gid), (long long)want);
+      return 1;
+    }
+    for (int64_t i = 1; i <= want; ++i) {
+      uint8_t* blob = nullptr;
+      if (tlm_get(h, gid, i, &blob) <= 0) {
+        fprintf(stderr, "rnd%d index %lld unreadable\n", g, (long long)i);
+        return 1;
+      }
+      tlm_free(blob);
+    }
   }
   printf("check_multilog OK (%d groups x %lld entries, %lld fsync rounds, "
          "%lld files)\n",

@@ -452,6 +452,167 @@ struct tlm_handle {
     return true;
   }
 
+  // -- entry staging (tlm_append / tlm_append_round) ----------------------
+  //
+  // Entry records of one or more groups gathered for ONE write() on the
+  // active journal.  The index moves only after the run's bytes hit the
+  // fd (flush_run), so a failed write leaves the in-memory index
+  // consistent with the durable prefix.
+  struct Run {
+    struct Rec {
+      uint32_t gid;
+      Loc loc;
+      const uint8_t* blob;
+      uint32_t blen;
+    };
+    std::string buf;
+    std::vector<Rec> recs;
+    std::set<uint32_t> gids;     // groups with records in buf
+    std::vector<int64_t> slots;  // caller's slots with records in buf
+    int64_t fsize = 0;           // the active file's size once buf is written
+  };
+
+  bool flush_run(Run& run, std::string* err) {
+    if (run.recs.empty()) return true;
+    JournalFile* f = active();
+    bool ok = write_all(f->fd, (const uint8_t*)run.buf.data(), run.buf.size());
+    if (ok) {
+      f->size = run.fsize;
+      active_dirty = true;
+      // the group-commit sync decides by epoch whether the journal has
+      // unsynced bytes: an append that does not bump it is never
+      // fsynced by tlm_sync (found in PR 32: this path never did, so
+      // entries were acknowledged on write() alone unless a control
+      // record rode along)
+      ++write_epoch;
+      for (auto& r : run.recs) {
+        f->groups.insert(r.gid);
+        if (!apply_record(r.gid, kRecEntry, r.blob, r.blen, r.loc, err))
+          ok = false;  // unreachable after the validation pass
+      }
+    } else {
+      *err = std::string("journal write: ") + strerror(errno);
+      run.fsize = f->size;
+    }
+    run.buf.clear();
+    run.recs.clear();
+    run.gids.clear();
+    return ok;
+  }
+
+  // Stage n groups' frames ([u32le blob_len | entry blob]...), in the
+  // caller's order, with ONE write() per touched journal for all of them.
+  // results[i] = entries appended for slot i, or -1: a group that fails
+  // its validation (format, contiguity) fails alone; a write that fails
+  // fails every group with bytes in it.  Returns how many slots failed,
+  // *first_err says why the first one did.
+  int64_t append_round_locked(int64_t n, const uint32_t* gids,
+                              const uint8_t* const* frames,
+                              const int64_t* lens, int64_t* results,
+                              std::string* first_err) {
+    struct Pending {
+      const uint8_t* blob;
+      uint32_t blen;
+    };
+    int64_t failed = 0;
+    auto fail_slot = [&](int64_t i, const std::string& msg) {
+      if (results[i] == -1) return;
+      results[i] = -1;
+      ++failed;
+      if (first_err->empty()) *first_err = msg;
+    };
+    Run run;
+    run.fsize = active() ? active()->size : 0;
+    auto flush = [&]() {
+      std::string err;
+      bool ok = flush_run(run, &err);
+      if (!ok)
+        for (int64_t slot : run.slots) fail_slot(slot, err);
+      run.slots.clear();
+      return ok;
+    };
+    std::vector<Pending> pend;
+    for (int64_t i = 0; i < n; ++i) {
+      results[i] = 0;
+      uint32_t gid = gids[i];
+      // a group's second slot in one round: contiguity is judged against
+      // the index, so its first slot's records must be in it
+      if (run.gids.count(gid)) flush();
+      auto git = groups.find(gid);
+      if (git == groups.end()) { fail_slot(i, "unregistered group"); continue; }
+      GroupLog& gl = git->second;
+
+      // Pass 1: validate frames + contiguity up front.
+      pend.clear();
+      const uint8_t* fr = frames[i];
+      int64_t total = lens[i];
+      int64_t expected = gl.positions.empty() ? -1 : gl.last() + 1;
+      int64_t off = 0;
+      std::string bad;
+      while (off < total) {
+        if (off + 4 > total) { bad = "truncated frame header"; break; }
+        uint32_t blen = load_u32(fr + off);
+        if (off + 4 + (int64_t)blen > total) { bad = "truncated frame"; break; }
+        const uint8_t* blob = fr + off + 4;
+        if (blen < kEntryHdr || blob[0] != kEntryMagic) {
+          bad = "bad entry blob";
+          break;
+        }
+        int64_t idx = load_i64(blob + 12);
+        if (expected == -1) {
+          if (idx < gl.first) { bad = "append below first_log_index"; break; }
+        } else if (idx != expected) {
+          bad = "non-contiguous append: have last=" +
+                std::to_string(expected - 1) + ", got " + std::to_string(idx);
+          break;
+        }
+        expected = idx + 1;
+        pend.push_back({blob, blen});
+        off += 4 + (int64_t)blen;
+      }
+      if (!bad.empty()) { fail_slot(i, bad); continue; }
+      if (pend.empty()) continue;
+
+      // Pass 2: records into the run; the run is written when its
+      // journal is full (then a new one is rotated in) and at the end.
+      bool ok = true;
+      for (const Pending& p : pend) {
+        if (active() == nullptr || run.fsize >= seg_max) {
+          std::string err;
+          if (!flush() || !rotate_locked(&err)) {
+            fail_slot(i, err.empty() ? *first_err : err);
+            ok = false;
+            break;
+          }
+          run.fsize = active()->size;
+        }
+        uint32_t len = (uint32_t)(4 + 4 + 1 + p.blen);
+        size_t base = run.buf.size();
+        run.buf.resize(base + 4 + len);
+        uint8_t* rec = (uint8_t*)run.buf.data() + base;
+        memcpy(rec, &len, 4);
+        memcpy(rec + 8, &gid, 4);
+        rec[12] = kRecEntry;
+        memcpy(rec + 13, p.blob, p.blen);
+        uLong c = crc32(0L, Z_NULL, 0);
+        c = crc32(c, rec + 8, (uInt)(4 + 1 + p.blen));
+        uint32_t crc = (uint32_t)c;
+        memcpy(rec + 4, &crc, 4);
+        run.recs.push_back({gid, Loc{active()->seq, (uint32_t)run.fsize},
+                            p.blob, p.blen});
+        run.fsize += (int64_t)(4 + len);
+        if (run.slots.empty() || run.slots.back() != i) run.slots.push_back(i);
+      }
+      run.gids.insert(gid);
+      if (ok) {
+        results[i] = (int64_t)pend.size();
+        ++appends;
+      }
+    }
+    flush();
+    return failed;
+  }
+
   // The group-commit sync: fsync OUTSIDE mu, so concurrent staging
   // (which runs inline on the host event loop) never blocks behind a
   // flush round.  sync_mu serializes rounds; the epoch check lets a
@@ -686,91 +847,28 @@ int64_t tlm_last(tlm_handle* h, uint32_t gid) {
 // explicitly first); the overwrite rule only serves the recovery scan.
 int64_t tlm_append(tlm_handle* h, uint32_t gid, const uint8_t* frames,
                    int64_t total, char* errbuf, int errlen) {
-  auto fail = [&](const std::string& msg) -> int64_t {
-    if (errbuf && errlen > 0) snprintf(errbuf, (size_t)errlen, "%s", msg.c_str());
-    return -1;
-  };
   std::lock_guard<std::mutex> g(h->mu);
-  auto git = h->groups.find(gid);
-  if (git == h->groups.end()) return fail("unregistered group");
-  GroupLog& gl = git->second;
-
-  // Pass 1: validate frames + contiguity up front.
-  struct Pending {
-    const uint8_t* blob;
-    uint32_t blen;
-  };
-  std::vector<Pending> pend;
-  int64_t expected = gl.positions.empty() ? -1 : gl.last() + 1;
-  int64_t off = 0;
-  while (off < total) {
-    if (off + 4 > total) return fail("truncated frame header");
-    uint32_t blen = load_u32(frames + off);
-    if (off + 4 + (int64_t)blen > total) return fail("truncated frame");
-    const uint8_t* blob = frames + off + 4;
-    if (blen < kEntryHdr || blob[0] != kEntryMagic)
-      return fail("bad entry blob");
-    int64_t idx = load_i64(blob + 12);
-    if (expected == -1) {
-      if (idx < gl.first) return fail("append below first_log_index");
-    } else if (idx != expected) {
-      return fail("non-contiguous append: have last=" +
-                  std::to_string(expected - 1) + ", got " +
-                  std::to_string(idx));
-    }
-    expected = idx + 1;
-    pend.push_back({blob, blen});
-    off += 4 + (int64_t)blen;
-  }
-  if (pend.empty()) return 0;
-
-  // Pass 2: write in segment-sized runs — ONE write() per touched
-  // journal — then index.  Index updates happen only after the run's
-  // bytes hit the fd, so a failed write leaves the in-memory index
-  // consistent with the durable prefix.
+  int64_t result = 0;
   std::string err;
-  size_t i = 0;
-  while (i < pend.size()) {
-    if (h->active() == nullptr || h->active()->size >= h->seg_max) {
-      if (!h->rotate_locked(&err)) return fail(err);
-    }
-    JournalFile* f = h->active();
-    std::string buf;
-    std::vector<std::pair<Loc, size_t>> staged;  // (loc, pend idx)
-    int64_t fsize = f->size;
-    size_t j = i;
-    while (j < pend.size() && (staged.empty() || fsize < h->seg_max)) {
-      const Pending& p = pend[j];
-      uint32_t len = (uint32_t)(4 + 4 + 1 + p.blen);
-      size_t base = buf.size();
-      buf.resize(base + 4 + len);
-      uint8_t* rec = (uint8_t*)buf.data() + base;
-      memcpy(rec, &len, 4);
-      memcpy(rec + 8, &gid, 4);
-      rec[12] = kRecEntry;
-      memcpy(rec + 13, p.blob, p.blen);
-      uLong c = crc32(0L, Z_NULL, 0);
-      c = crc32(c, rec + 8, (uInt)(4 + 1 + p.blen));
-      uint32_t crc = (uint32_t)c;
-      memcpy(rec + 4, &crc, 4);
-      staged.emplace_back(Loc{f->seq, (uint32_t)fsize}, j);
-      fsize += (int64_t)(4 + len);
-      ++j;
-    }
-    if (!write_all(f->fd, (const uint8_t*)buf.data(), buf.size()))
-      return fail(std::string("journal write: ") + strerror(errno));
-    f->size = fsize;
-    f->groups.insert(gid);
-    h->active_dirty = true;
-    for (auto& [loc, pi] : staged) {
-      if (!h->apply_record(gid, kRecEntry, pend[pi].blob, pend[pi].blen,
-                           loc, &err))
-        return fail(err);  // unreachable after pass-1 validation
-    }
-    i = j;
-  }
-  ++h->appends;
-  return (int64_t)pend.size();
+  h->append_round_locked(1, &gid, &frames, &total, &result, &err);
+  if (result < 0 && errbuf && errlen > 0)
+    snprintf(errbuf, (size_t)errlen, "%s", err.c_str());
+  return result;
+}
+
+// The flush round's staging: n groups' frames in ONE call and ONE write()
+// per touched journal (tlm_append is its one-group case).  results[i] is
+// what tlm_append would have returned for slot i; the return value counts
+// the slots that failed and errbuf says why the first one did.
+int64_t tlm_append_round(tlm_handle* h, int64_t n, const uint32_t* gids,
+                         const uint8_t* const* frames, const int64_t* lens,
+                         int64_t* results, char* errbuf, int errlen) {
+  std::lock_guard<std::mutex> g(h->mu);
+  std::string err;
+  int64_t failed = h->append_round_locked(n, gids, frames, lens, results, &err);
+  if (failed && errbuf && errlen > 0)
+    snprintf(errbuf, (size_t)errlen, "%s", err.c_str());
+  return failed;
 }
 
 // ONE fsync covering every group's staged appends since the last sync.

@@ -463,3 +463,50 @@ async def test_store_engine_shares_one_round_and_counts_it(tmp_path, kind):
             assert s.raw_store.get(b"z09") == b"v"
     finally:
         await c.stop_all()
+
+
+@pytest.mark.parametrize("health", [True, False])
+async def test_store_engine_counts_the_logs_flush_rounds(tmp_path, health):
+    """The log's round (ISSUE 32) beside the KV WAL's: the store's shared
+    multilog engine counts its flush rounds in three event histograms that
+    sit with the engine's, so the benchmark's ``summary:`` line carries
+    ``engine<i>.log_rounds.count``, ``.log_round_groups.count`` and
+    ``.log_round_inline.count`` (groups per log fsync is the quotient of
+    the first two), whether or not the store scores its disk's health."""
+    from tests.kv_cluster import KVTestCluster
+    from tpuraft.core.engine import MultiRaftEngine
+    from tpuraft.options import TickOptions
+    from tpuraft.storage.multilog import peek_engine
+
+    def engine():
+        return MultiRaftEngine(TickOptions(
+            max_groups=8, max_peers=4, tick_interval_ms=2, backend="numpy"))
+
+    regions = [Region(id=1, start_key=b"", end_key=b"m"),
+               Region(id=2, start_key=b"m", end_key=b"")]
+    c = KVTestCluster(3, tmp_path=tmp_path, regions=regions,
+                      multi_raft_engine_factory=engine, log_scheme="multilog",
+                      store_opts={"health_scoring": health})
+    await c.start_all()
+    try:
+        leaders = [await c.wait_region_leader(rid) for rid in (1, 2)]
+        oks = await asyncio.gather(*[
+            leader.raft_store.put(b"%s%02d" % (prefix, i), b"v")
+            for leader, prefix in zip(leaders, (b"a", b"z"))
+            for i in range(10)])
+        assert all(oks)
+        for s in c.stores.values():
+            sid = s.server_id
+            mlog = peek_engine(f"{tmp_path}/{sid.ip}_{sid.port}/mlog")
+            rounds = mlog.group_commit
+            assert (rounds.health_probe is not None) is health
+            hists = s.multi_raft_engine.tick_hists
+            assert hists["log_rounds"] is rounds.rounds
+            assert hists["log_round_groups"] is rounds.round_groups
+            assert hists["log_round_inline"] is rounds.round_inline
+            # both regions' logs of this store ride the same rounds
+            assert 0 < rounds.rounds.count <= rounds.round_groups.count
+            assert rounds.round_inline.count <= rounds.rounds.count
+            assert mlog.sync_count <= rounds.rounds.count
+    finally:
+        await c.stop_all()

@@ -142,6 +142,36 @@ def test_thousand_groups_one_engine(tmp_path):
             s.shutdown()
 
 
+def test_every_append_is_covered_by_the_next_sync(tmp_path):
+    """``tlm_sync`` decides by epoch whether the journal holds unsynced
+    bytes.  Until PR 32 ``tlm_append`` never bumped it, so a sync after
+    entry appends alone fsynced nothing and returned at once (it only
+    ran when a registration or a control record rode along): entries
+    were acknowledged on ``write()``.  A sync after an append is a round
+    (``sync_count`` steps); a sync after nothing is not."""
+    a, b = mk_storage(tmp_path, "ea"), mk_storage(tmp_path, "eb")
+    a.init()
+    b.init()
+    try:
+        eng = a.engine
+        a.append_entries(mk_entries(1, 1), sync=True)   # registry too
+        b.append_entries(mk_entries(1, 1), sync=True)
+        for k in range(2, 6):
+            n0 = eng.sync_count
+            (a if k % 2 else b).append_entries(mk_entries(k, 1),
+                                               sync=False)
+            (b if k % 2 else a).append_entries(mk_entries(k, 1),
+                                               sync=False)
+            assert eng.sync_count == n0
+            eng.sync()
+            assert eng.sync_count == n0 + 1, "append left unsynced"
+            eng.sync()
+            assert eng.sync_count == n0 + 1     # nothing new: no round
+    finally:
+        a.shutdown()
+        b.shutdown()
+
+
 async def test_group_fsync_coalescing(tmp_path):
     """The headline property: N groups flushing concurrently cost ~1
     fsync round, not N (RocksDB group commit)."""
@@ -473,13 +503,14 @@ async def test_cluster_on_shared_log_engine(tmp_path):
         await c.stop_all()
 
 
-@pytest.mark.parametrize("path", ["inline", "round"])
+@pytest.mark.parametrize("path", ["inline", "executor"])
 async def test_flush_returns_the_fsync_interval_read_in_its_thread(
         tmp_path, path):
-    """(t0, t1, off_loop) around the fsync itself.  The inline path runs
-    it on the caller's loop; a round runs it in an executor thread and
-    hands every waiter of the round the same interval, which ends before
-    any of them resumes."""
+    """(t0, t1, off_loop) around the fsync itself, the same for every
+    rider of a round.  Where the round is synced follows the measured
+    fsync cost and nothing else: under ``INLINE_MAX_S`` the close runs it
+    on the loop thread, at or over it in an executor thread; either way
+    it ends before any rider resumes."""
     import threading
     import time
 
@@ -498,38 +529,33 @@ async def test_flush_returns_the_fsync_interval_read_in_its_thread(
             real_sync()
 
         eng.sync = spying_sync
-        if path == "inline":
-            # idle, fast disk, nobody waiting: the fsync is taken inline
-            gc._cost_ewma = 0.0
-            gc._last_sync = 0.0
-            before = time.perf_counter()
-            got = [await stores[0].append_entries_async(
-                mk_entries(1, 2, term=1), sync=True)]
-            resumed = [time.perf_counter()]
-            assert sync_threads == [threading.get_ident()]
-        else:
-            gc._cost_ewma = 1.0          # the inline path is banned
-            before = time.perf_counter()
-            resumed = []
+        # the inline close is open, or banned by a slow disk's EWMA
+        gc._cost_ewma = 0.0 if path == "inline" else gc.INLINE_MAX_S
+        before = time.perf_counter()
+        resumed = []
 
-            async def one(k):
-                iv = await stores[k].append_entries_async(
-                    mk_entries(1, 2, term=1), sync=True)
-                resumed.append(time.perf_counter())
-                return iv
+        async def one(k):
+            iv = await stores[k].append_entries_async(
+                mk_entries(1, 2, term=1), sync=True)
+            resumed.append(time.perf_counter())
+            return iv
 
-            got = await asyncio.gather(*(one(k) for k in range(8)))
-            assert threading.get_ident() not in sync_threads
-            # the eight joined far fewer rounds, each round one interval
-            assert len(set(got)) == len(sync_threads) < 8
-        for t0, t1, off_loop in got:
-            assert off_loop is (path == "round")
-            assert before <= t0 and t1 - t0 >= 0.002
-            assert t1 <= min(resumed)
-        # nothing to sync, nothing to time
-        assert await stores[0].append_entries_async([], sync=True) is None
-        assert await stores[0].append_entries_async(
+        got = await asyncio.gather(*(one(k) for k in range(8)))
+        # the eight staged in one turn: one round, one fsync, one interval
+        assert len(sync_threads) == 1 and len(set(got)) == 1
+        assert (sync_threads[0] == threading.get_ident()) \
+            is (path == "inline")
+        t0, t1, off_loop = got[0]
+        assert off_loop is (path == "executor")
+        assert before <= t0 and t1 - t0 >= 0.002
+        assert t1 <= min(resumed)
+        assert (gc.rounds.count, gc.round_groups.count,
+                gc.round_inline.count) == (1, 8, int(path == "inline"))
+        # nothing to sync, nothing to wait for
+        assert stores[0].append_entries_async([], sync=True) is None
+        assert stores[0].append_entries_async(
             mk_entries(3, 1, term=1), sync=False) is None
+        assert len(sync_threads) == 1
     finally:
         for s in stores:
             s.shutdown()
@@ -573,9 +599,9 @@ def test_the_shared_handles_of_a_directory_resolve_its_path_once(
 
 async def test_a_rounds_stall_token_ages_from_the_hand_off_to_the_fsyncs_end(
         tmp_path):
-    """The gray-failure disk probe's stall token of a flush round: taken
-    when the round is handed to the executor, given back in the thread at
-    the fsync's end.  A round queued behind a blocked executor ages it (a
+    """The gray-failure disk probe's stall token of a flush round whose
+    disk is too slow for the loop thread: taken when the round is handed
+    to the executor, given back in the thread at the fsync's end.  A round queued behind a blocked executor ages it (a
     saturated executor IS a gray signal, and a hung fsync never returns
     it); a loop that resumes the waiter late does not (ISSUE 29: held to
     the resumption, a 0.06 ms disk read as stalled whenever the loop ran
@@ -594,7 +620,7 @@ async def test_a_rounds_stall_token_ages_from_the_hand_off_to_the_fsyncs_end(
     try:
         gc = store.engine.group_commit
         gc.health_probe = probe = DiskLatencyProbe()
-        gc._cost_ewma = 1.0              # the inline path is banned
+        gc._cost_ewma = 1.0              # the inline close is banned
         gate = threading.Event()
         pool.submit(gate.wait)           # the one thread is taken
         flush = asyncio.ensure_future(store.append_entries_async(

@@ -938,8 +938,7 @@ class StoreEngine:
         if self.pd_client is not None:
             self._heartbeat_task = asyncio.ensure_future(
                 self._heartbeat_loop())
-        if self.health is not None:
-            self._wire_multilog_probe()
+        self._wire_multilog()
         if self.health is not None or self.disk_budget is not None:
             self._health_task = asyncio.ensure_future(self._health_loop())
         if self.opts.metrics_port is not None:
@@ -966,10 +965,13 @@ class StoreEngine:
         gc.freeze()
         _brake_full_collections()
 
-    def _wire_multilog_probe(self) -> None:
-        """multilog scheme: the shared group commit times every fsync
-        in its executor thread — feed those samples to the disk probe
-        (the LogManager's flush timing covers the file scheme)."""
+    def _wire_multilog(self) -> None:
+        """multilog scheme: the store's shared flush round times every
+        fsync in the thread that runs it — feed those samples to the
+        disk probe (the LogManager's flush timing covers the file
+        scheme) — and counts its rounds: those three event histograms
+        sit with the engine's, beside the KV WAL's (groups per log fsync
+        = log_round_groups.count / log_rounds.count)."""
         if self.opts.log_scheme != "multilog" or not self.opts.data_path:
             return
         from tpuraft.storage.multilog import peek_engine
@@ -977,8 +979,16 @@ class StoreEngine:
         store_base = (f"{self.opts.data_path}/"
                       f"{self.server_id.ip}_{self.server_id.port}")
         eng = peek_engine(f"{store_base}/mlog")
-        if eng is not None:
-            eng.group_commit.health_probe = self.health.disk
+        if eng is None:
+            return
+        rounds = eng.group_commit
+        if self.health is not None:
+            rounds.health_probe = self.health.disk
+        if self.multi_raft_engine is not None:
+            hists = self.multi_raft_engine.tick_hists
+            hists["log_rounds"] = rounds.rounds
+            hists["log_round_groups"] = rounds.round_groups
+            hists["log_round_inline"] = rounds.round_inline
 
     async def shutdown(self) -> None:
         self._started = False

@@ -866,8 +866,9 @@ class NativeJournalTracker:
         self.floors: dict[str, int] = {}
         # -- capacity mirror (ENOSPC) ----------------------------------------
         # the C++ fd writes are unpatachable, so the quota is enforced
-        # one layer up: MultiLogStorage._stage consults the engine's
-        # ``fault_gate`` before tlm_append.  Single-threaded per store
+        # one layer up: MultiLogStorage._stage and the flush round's
+        # MultiLogEngine.append_round consult the engine's ``fault_gate``
+        # before the native append.  Single-threaded per store
         # loop + engine lock upstream — no lock needed here.
         self._quota_limit: Optional[int] = None
         self._quota_used = 0

@@ -1,10 +1,12 @@
 """LogManager: in-memory log window + async batched stable storage.
 
 Reference parity: ``core:storage/impl/LogManagerImpl`` (SURVEY.md §3.1,
-§4.2) — the Disruptor + AppendBatcher pipeline becomes an asyncio flusher
-task that coalesces concurrent appends into one storage write + fsync
-(storage I/O runs in a thread executor so the event loop never blocks);
-wait-listeners wake Replicators when the log grows; follower-side conflict
+§4.2) — the Disruptor + AppendBatcher pipeline becomes, over a shared
+engine (multilog), a staging in the caller's turn that rides the
+store-wide flush round of that turn (one fsync for every group that
+staged in it), and over the other storages an asyncio flusher task that
+coalesces concurrent appends into one storage write + fsync in a thread
+executor, so the event loop never blocks on a slow disk; wait-listeners wake Replicators when the log grows; follower-side conflict
 resolution (``#checkAndResolveConflict``) truncates divergent suffixes;
 ``#setSnapshot`` compacts the prefix after snapshots.
 
@@ -18,7 +20,6 @@ import asyncio
 from collections import deque
 import logging
 import time
-from dataclasses import dataclass
 from typing import Optional
 
 from tpuraft.conf import ConfigurationEntry, ConfigurationManager
@@ -36,10 +37,18 @@ def _is_enospc(exc: BaseException) -> bool:
         or "ENOSPC" in str(exc) or "no space left" in str(exc).lower()
 
 
-@dataclass
-class _FlushReq:
-    entries: list[LogEntry]
-    future: asyncio.Future
+class _Ride:
+    """One group's stake in one store-wide flush round: what it staged
+    into it, and what became of it."""
+
+    __slots__ = ("future", "t0", "entries", "staged", "error")
+
+    def __init__(self, staged, t0: float, entries: list[LogEntry]):
+        self.future = staged.future  # the round's, shared by its riders
+        self.t0 = t0                 # perf_counter before the staging
+        self.entries = entries
+        self.staged = [staged]       # the storage's handles, one a staging
+        self.error: Optional[RaftException] = None
 
 
 # graftcheck: loop-confined — single-writer discipline (see module
@@ -97,15 +106,21 @@ class LogManager:
 
         self._staged: list[LogEntry] = []
         self._stable_waiters: list[tuple[int, asyncio.Future]] = []
-        # demand-spawned flusher (r4): a standing flush task per node is
-        # O(nodes) idle tasks per process (48K at the 16Kx3 ladder rung);
-        # requests queue here and one short-lived drain runs while any
-        # exist.  Single-drainer + FIFO deque keeps flush order, which
-        # _stable_index and the on_stable hook rely on.
-        self._queue: deque = deque()
+        # flushes in flight on either path below; truncation waits for
+        # all of them (_drain_flushes)
         self._inflight_flushes = 0
         self._flush_idle = asyncio.Event()
         self._flush_idle.set()
+        # shared-engine storages (multilog): this group's stake in the
+        # store-wide round that is open now, if it staged into it
+        self._ride: Optional[_Ride] = None
+        # the others, demand-spawned flusher (r4): a standing flush task
+        # per node is O(nodes) idle tasks per process (48K at the 16Kx3
+        # ladder rung); (entries, future) requests queue here and one
+        # short-lived drain runs while any exist.  Single-drainer + FIFO
+        # deque keeps flush order, which _stable_index and the on_stable
+        # hook rely on.
+        self._queue: deque = deque()
         self._flusher: Optional[asyncio.Task] = None
         self._waiters: list[tuple[int, asyncio.Future]] = []
         self._stopped = False
@@ -153,6 +168,7 @@ class LogManager:
         self._stopped = True
         if self._flusher is not None and not self._flusher.done():
             await self._flusher
+        await self._drain_flushes()     # a round this group is riding
         self._wake_waiters(error=True)
         self._storage.shutdown()
 
@@ -371,157 +387,227 @@ class LogManager:
     # -- flush pipeline ------------------------------------------------------
 
     async def _enqueue_flush(self, entries: list[LogEntry]) -> None:
+        if hasattr(self._storage, "append_entries_async"):
+            # a shared engine with a store-wide flush round (multilog)
+            await self._ride_round(entries)
+            return
         fut = asyncio.get_running_loop().create_future()
-        self._inflight_flushes += 1
-        self._flush_idle.clear()
+        self._flush_begins()
         try:
-            self._queue.append(_FlushReq(entries, fut))
+            self._queue.append((entries, fut))
             if self._flusher is None or self._flusher.done():
                 self._flusher = asyncio.ensure_future(self._flush_loop())
             await fut
         finally:
-            self._inflight_flushes -= 1
-            if self._inflight_flushes == 0:
-                self._flush_idle.set()
+            self._flush_ends()
+
+    def _flush_begins(self) -> None:
+        self._inflight_flushes += 1
+        self._flush_idle.clear()
+
+    def _flush_ends(self) -> None:
+        self._inflight_flushes -= 1
+        if self._inflight_flushes == 0:
+            self._flush_idle.set()
+
+    async def _ride_round(self, entries: list[LogEntry]) -> None:
+        """Shared-engine storages: stage in the caller's own turn (calls
+        are in index order and nothing awaits before the staging) and
+        await the store-wide round's one future.  No task and no future
+        of this group's own; what follows the fsync (`_flushed`) is a
+        callback on the round's future, registered before the caller's
+        wake-up so it has run when the caller resumes, and runs even if
+        the caller is cancelled meanwhile (the entries are durable all
+        the same)."""
+        t0 = time.perf_counter()
+        try:
+            staged = self._storage.append_entries_async(entries, self._sync)
+            if staged is None:      # nothing to sync: stable as appended
+                self._flushed(entries, t0, None)
+                return
+        except Exception as exc:
+            raise self._flush_failed(exc) from exc
+        ride = self._ride
+        if ride is not None and ride.future is staged.future:
+            # a second staging of this group in the same round: one
+            # continuation (and one roll-back, should the round fail)
+            ride.entries.extend(entries)
+            ride.staged.append(staged)
+        else:
+            ride = self._ride = _Ride(staged, t0, list(entries))
+            self._flush_begins()
+            ride.future.add_done_callback(
+                lambda _f, ride=ride: self._landed(ride))
+        try:
+            await ride.future
+        except Exception:   # noqa: BLE001 — _landed ran first: ride.error
+            pass
+        if ride.error is not None:
+            raise ride.error
+
+    def _landed(self, ride: _Ride) -> None:
+        """The round this group rode resolved (runs on the loop, ahead
+        of the riders' resumption): stable, or failed: the round for
+        everybody, or this group's own append."""
+        if self._ride is ride:
+            self._ride = None
+        self._flush_ends()
+        if ride.error is not None:
+            return      # withdrawn: an earlier flush of this group failed
+        try:
+            fsync = ride.future.result()
+            for staged in ride.staged:
+                if staged.error is not None:
+                    raise staged.error
+            self._flushed(ride.entries, ride.t0, fsync)
+        except Exception as exc:
+            ride.error = self._flush_failed(exc)
+
+    def _flushed(self, entries: list[LogEntry], t0: float,
+                 fsync: Optional[tuple]) -> None:
+        """A round's riders of this group are in stable storage."""
+        if _TRACE.enabled:
+            # the awaited envelope: staging to this resumption
+            woke = time.perf_counter()
+            self._trace_flush(entries, t0, woke, fsync, woke)
+        self._now_stable(entries)
+        self._wake_stable_waiters()
+
+    def _trace_flush(self, entries: list[LogEntry], t0: float, t1: float,
+                     fsync: Optional[tuple], woke: float) -> None:
+        """Spans of one flush's traced entries: ``log_flush`` t0..t1,
+        then its two parts: the fsync in the thread that ran it (the
+        disk) and from its end to the resumption on the loop (the
+        loop)."""
+        for e in entries:
+            tid = e.trace_id
+            if not tid:
+                continue
+            _TRACE.span(tid, "log_flush", t0, t1, proc=self._trace_proc,
+                        entries=len(entries))
+            if fsync is not None:
+                _TRACE.span(tid, "log_fsync", fsync[0], fsync[1],
+                            proc=self._trace_proc)
+                _TRACE.span(tid, "log_wake", fsync[1], woke,
+                            proc=self._trace_proc)
+
+    def _now_stable(self, entries: list[LogEntry]) -> None:
+        self._stable_index = max(self._stable_index, entries[-1].id.index)
+        if self._disk_budget is not None:
+            # ~32B/entry framing+index overhead on top of payload — an
+            # estimate; the periodic reconcile re-bases on real usage
+            self._disk_budget.note_append(
+                sum(len(e.data) for e in entries) + 32 * len(entries))
+        if self.on_stable is not None:
+            self.on_stable(self._stable_index)
 
     async def _flush_loop(self) -> None:
+        """The flusher of storages with no shared engine (``file://``,
+        ``native://``, memory): one short-lived task per group, storage
+        I/O in an executor thread."""
         loop = asyncio.get_running_loop()
         while self._queue:
             batch = [self._queue.popleft()]
             # coalesce everything already queued (AppendBatcher)
             while self._queue and len(batch) < self._max_flush_batch:
                 batch.append(self._queue.popleft())
-            entries = [e for r in batch for e in r.entries]
+            entries = [e for req, _ in batch for e in req]
             try:
-                if entries:
-                    # shared-engine storages expose an async hook whose
-                    # fsync joins a cross-GROUP commit round (multilog);
-                    # classic storages block an executor thread
-                    append_async = getattr(
-                        self._storage, "append_entries_async", None)
-                    health = self._health
-                    # trace plane: spans for the traced entries of this
-                    # flush round — timed IN the executor thread (the
-                    # PR 11 health-probe discipline: awaited duration
-                    # folds in executor-queue wait and a co-hosted
-                    # neighbor's slow disk would contaminate THIS
-                    # store's attribution exactly like it did the EMA)
-                    tids = ([e.trace_id for e in entries if e.trace_id]
-                            if _TRACE.enabled else [])
-                    if append_async is not None:
-                        # multilog: the group commit times its fsync
-                        # IN the executor thread, feeds the EMA and
-                        # holds the stall token itself (StoreEngine
-                        # wires the probe).  It hands back the round's
-                        # own fsync interval; f0..f1 is the awaited
-                        # envelope.
-                        f0 = time.perf_counter()
-                        fsync = await append_async(entries, self._sync)
-                        f1 = woke = time.perf_counter()
-                    elif health is not None or tids:
-                        # time the append+fsync IN the executor
-                        # thread: end-to-end (awaited) duration
-                        # would fold in executor-queue wait, and a
-                        # co-hosted neighbor's slow disk must not
-                        # score THIS store's disk sick
-                        tok = health.disk.begin() \
-                            if health is not None else None
+                health = self._health
+                traced = _TRACE.enabled and any(e.trace_id for e in entries)
+                if health is not None or traced:
+                    # time the append+fsync IN the executor thread (the
+                    # PR 11 health-probe discipline): end-to-end
+                    # (awaited) duration would fold in executor-queue
+                    # wait, and a co-hosted neighbor's slow disk must
+                    # not score THIS store's disk sick, nor contaminate
+                    # its spans
+                    tok = health.disk.begin() \
+                        if health is not None else None
 
-                        def _timed(entries=entries, tok=tok):
-                            t0 = time.perf_counter()
-                            try:
-                                self._storage.append_entries(entries,
-                                                             self._sync)
-                            finally:
-                                if tok is not None:
-                                    health.disk.end(tok)
-                            return t0, time.perf_counter()
-
+                    def _timed(entries=entries, tok=tok):
+                        t0 = time.perf_counter()
                         try:
-                            f0, f1 = await loop.run_in_executor(None,
-                                                                _timed)
+                            self._storage.append_entries(entries,
+                                                         self._sync)
                         finally:
                             if tok is not None:
-                                health.disk.end(tok)    # never started
-                        woke = time.perf_counter()
-                        fsync = (f0, f1, True)
-                        if health is not None:
-                            health.disk.note(f1 - f0)
-                    else:
-                        await loop.run_in_executor(
-                            None, self._storage.append_entries, entries,
-                            self._sync)
-                    if tids:
-                        # the awaited envelope, then its two parts: the
-                        # fsync in the thread that ran it (the disk)
-                        # and, where that was an executor thread, from
-                        # its end to this resumption (the loop)
-                        for tid in tids:
-                            _TRACE.span(tid, "log_flush", f0, f1,
-                                        proc=self._trace_proc,
-                                        entries=len(entries))
-                            if isinstance(fsync, tuple):
-                                _TRACE.span(tid, "log_fsync", fsync[0],
-                                            fsync[1], proc=self._trace_proc)
-                                if fsync[2]:
-                                    _TRACE.span(tid, "log_wake", fsync[1],
-                                                woke, proc=self._trace_proc)
-                    self._stable_index = max(self._stable_index, entries[-1].id.index)
-                    if self._disk_budget is not None:
-                        # ~32B/entry framing+index overhead on top of
-                        # payload — an estimate; the periodic reconcile
-                        # re-bases on real usage
-                        self._disk_budget.note_append(
-                            sum(len(e.data) for e in entries)
-                            + 32 * len(entries))
-                    if self.on_stable is not None:
-                        self.on_stable(self._stable_index)
-                for r in batch:
-                    if not r.future.done():
-                        r.future.set_result(True)
+                                health.disk.end(tok)
+                        return t0, time.perf_counter()
+
+                    try:
+                        f0, f1 = await loop.run_in_executor(None, _timed)
+                    finally:
+                        if tok is not None:
+                            health.disk.end(tok)    # never started
+                    if health is not None:
+                        health.disk.note(f1 - f0)
+                    if traced:
+                        self._trace_flush(entries, f0, f1, (f0, f1),
+                                          time.perf_counter())
+                else:
+                    await loop.run_in_executor(
+                        None, self._storage.append_entries, entries,
+                        self._sync)
+                self._now_stable(entries)
+                for _, fut in batch:
+                    if not fut.done():
+                        fut.set_result(True)
                 self._wake_stable_waiters()
             except Exception as exc:
-                # storage failure is fatal for the LEADERSHIP, not the
-                # process: every waiter gets a retryable error and the
-                # on_storage_error hook steps the node down — never ack,
-                # never silently drop (ISSUE 17 layer 4)
-                LOG.exception("log flush failed")
-                if self._disk_budget is not None and _is_enospc(exc):
-                    self._disk_budget.note_enospc()
-                err = RaftException(Status.error(RaftError.EIO, str(exc)))
-                # Fail EVERYTHING in flight — this batch, every queued
-                # request, the staged-but-unflushed tail — then roll the
-                # in-memory frontier back to what storage actually
-                # holds.  None of the failed suffix was ever acked, so
-                # dropping it is the follower-conflict-truncate case,
-                # not data loss; KEEPING it permanently desyncs memory
-                # from disk — the next append dies "non-contiguous" in
-                # storage and the node wedges in ERROR state (found by
-                # the --disk-pressure soak's ENOSPC bursts).
-                while self._queue:
-                    batch.append(self._queue.popleft())
-                for r in batch:
-                    if not r.future.done():
-                        r.future.set_exception(err)
-                self._staged.clear()
-                durable = max(self._storage.last_log_index(),
-                              self._first_index - 1)
-                for i in range(durable + 1, self._last_index + 1):
-                    self._mem_pop(i)
-                if durable < self._last_index:
-                    self.conf_manager.truncate_suffix(durable)
-                self._last_index = durable
-                self._stable_index = min(self._stable_index, durable)
-                for _, fut in self._stable_waiters:
+                err = self._flush_failed(exc)
+                for _, fut in batch:
                     if not fut.done():
                         fut.set_exception(err)
-                self._stable_waiters.clear()
-                cb = self.on_storage_error
-                if cb is not None:
-                    try:
-                        cb(exc)
-                    except Exception:
-                        LOG.exception("on_storage_error hook failed")
+
+    def _flush_failed(self, exc: BaseException) -> RaftException:
+        """A flush failed (append or fsync).  Storage failure is fatal
+        for the LEADERSHIP, not the process: every waiter gets the
+        retryable error this returns and the on_storage_error hook steps
+        the node down — never ack, never silently drop (ISSUE 17 layer
+        4)."""
+        LOG.error("log flush failed", exc_info=exc)
+        if self._disk_budget is not None and _is_enospc(exc):
+            self._disk_budget.note_enospc()
+        err = RaftException(Status.error(RaftError.EIO, str(exc)))
+        # Fail EVERYTHING in flight — every queued request, this group's
+        # stake in a round still open (withdrawn: never appended), the
+        # staged-but-unflushed tail — then roll the in-memory frontier
+        # back to what storage actually holds.  None of the failed
+        # suffix was ever acked, so dropping it is the
+        # follower-conflict-truncate case, not data loss; KEEPING it
+        # permanently desyncs memory from disk — the next append dies
+        # "non-contiguous" in storage and the node wedges in ERROR state
+        # (found by the --disk-pressure soak's ENOSPC bursts).
+        while self._queue:
+            _, fut = self._queue.popleft()
+            if not fut.done():
+                fut.set_exception(err)
+        ride, self._ride = self._ride, None
+        if ride is not None:
+            for staged in ride.staged:
+                staged.withdraw()
+            ride.error = err
+        self._staged.clear()
+        durable = max(self._storage.last_log_index(),
+                      self._first_index - 1)
+        for i in range(durable + 1, self._last_index + 1):
+            self._mem_pop(i)
+        if durable < self._last_index:
+            self.conf_manager.truncate_suffix(durable)
+        self._last_index = durable
+        self._stable_index = min(self._stable_index, durable)
+        for _, fut in self._stable_waiters:
+            if not fut.done():
+                fut.set_exception(err)
+        self._stable_waiters.clear()
+        cb = self.on_storage_error
+        if cb is not None:
+            try:
+                cb(exc)
+            except Exception:
+                LOG.exception("on_storage_error hook failed")
+        return err
 
     def _wake_stable_waiters(self) -> None:
         rest = []

@@ -12,11 +12,13 @@ Wiring:
   log_uri = "multilog://<dir>#<group_id>"
 One :class:`MultiLogEngine` per directory per process (registry below);
 each node's :class:`MultiLogStorage` is a per-group view.  Durability:
-``append_entries`` stages bytes; the engine's :class:`_GroupCommit`
-coalesces every concurrently-flushing group into ONE ``tlm_sync``
-(observable via ``sync_count``/``append_count``).  The LogManager uses
-the async ``append_entries_async`` hook when present, so flush waiters
-are futures, not blocked executor threads.
+the engine's :class:`_GroupCommit` gives every group that stages in one
+turn of the event loop ONE ``tlm_append_round`` (one ``write()``) and
+ONE ``tlm_sync`` (one fsync) between them (observable via
+``sync_count``/``append_count`` and the ``rounds`` / ``round_groups``
+histograms).  The LogManager uses the ``append_entries_async`` hook when
+present: it encodes in the caller's turn and awaits the round's one
+future, with no task and no executor thread per group.
 """
 
 from __future__ import annotations
@@ -32,6 +34,7 @@ from typing import Optional
 from tpuraft.entity import LogEntry
 from tpuraft.storage.log_storage import CorruptLogError, LogStorage
 from tpuraft.util.dirkeys import RealPathKeys
+from tpuraft.util.metrics import Histogram
 from tpuraft.util.trace import TRACER as _TRACE
 
 _FRAME = struct.Struct("<I")
@@ -75,6 +78,14 @@ def _load() -> ctypes.CDLL:
             lib.tlm_append.argtypes = [ctypes.c_void_p, ctypes.c_uint32,
                                        ctypes.c_char_p, ctypes.c_int64,
                                        ctypes.c_char_p, ctypes.c_int]
+            lib.tlm_append_round.restype = ctypes.c_int64
+            lib.tlm_append_round.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64,
+                ctypes.POINTER(ctypes.c_uint32),
+                ctypes.POINTER(ctypes.c_char_p),
+                ctypes.POINTER(ctypes.c_int64),
+                ctypes.POINTER(ctypes.c_int64), ctypes.c_char_p,
+                ctypes.c_int]
             lib.tlm_sync.restype = ctypes.c_int
             lib.tlm_sync.argtypes = [ctypes.c_void_p, ctypes.c_char_p,
                                      ctypes.c_int]
@@ -103,201 +114,248 @@ def _load() -> ctypes.CDLL:
         return _lib
 
 
-def _deliver(f: asyncio.Future, exc: Optional[BaseException],
-             interval: Optional[tuple]) -> None:
-    """Resolve one group-commit waiter; must run on f's own loop."""
-    if f.done():
-        return
-    if exc is not None:
-        f.set_exception(exc)
-    else:
-        f.set_result(interval)
+class _RoundFuture(asyncio.Future):
+    """The one future of a flush round, awaited by every stager of it:
+    a stager that is cancelled must not cancel the round under the
+    others, so ``cancel`` declines and the cancelled task is cancelled
+    when the round resolves (``Task.cancel`` falls back to that)."""
+
+    def cancel(self, msg=None) -> bool:
+        return False
+
+
+class _Staged:
+    """One group's encoded entries riding a flush round: appended to the
+    journal with the round's other riders in ONE call when the round
+    closes, then synced with them.  Awaiting it gives the round's fsync
+    interval, or raises what failed: this group's own append (``error``)
+    or the round's sync."""
+
+    __slots__ = ("gid", "frames", "future", "error")
+
+    def __init__(self, gid: int, frames: bytes) -> None:
+        self.gid = gid
+        self.frames: Optional[bytes] = frames
+        self.future: Optional[_RoundFuture] = None   # the round's
+        self.error: Optional[BaseException] = None
+
+    def withdraw(self) -> None:
+        """Leave the round unappended (it has not closed yet): what a
+        LogManager does with its stake when an earlier flush of its
+        group failed."""
+        self.frames = None
+
+    def __await__(self):
+        interval = yield from self.future.__await__()
+        if self.error is not None:
+            raise self.error
+        return interval
+
+
+class _Lane:
+    """One event loop's rounds on a group commit: the open one, which
+    the loop's stagers are joining, and whether an earlier one is with
+    the executor (the open one then waits for it to land)."""
+
+    __slots__ = ("loop", "open", "groups", "items", "in_flight")
+
+    def __init__(self, loop) -> None:
+        self.loop = loop
+        self.open: Optional[_RoundFuture] = None
+        self.groups = 0          # stagings that joined the open round
+        self.items: list = []    # their _Staged, where the engine has any
+        self.in_flight = False
 
 
 class _GroupCommit:
-    """Coalesces concurrent flush() calls into one tlm_sync round
-    (RocksDB group commit): callers that arrive while a round's fsync is
-    in flight wait for the NEXT round, which covers their staged bytes.
+    """The flush round is the loop turn (the shape of the KV WAL's
+    ``ApplyRound``).  The first stager of a turn opens a round and
+    schedules its close with ``call_soon``; every group that stages
+    before that callback runs rides it; the close makes ONE
+    ``engine.append_round()`` for the riders' staged entries (the
+    multilog: one native call, one ``write()``), ONE ``engine.sync()``,
+    and resolves ONE future that all of the round's stagers await.
+    Nothing decides where a round ends but the loop's own order: no
+    linger, no timer.  A lone stager is synced in the turn after it
+    staged.
 
-    The engine is shared process-wide by directory, so flushers may live
-    on DIFFERENT event loops (multi-store processes): the waiter list is
-    lock-guarded and each future resolves on its OWN loop — setting a
-    future from a foreign loop's thread is not thread-safe.
+    The round's sync begins after its append returned, and a rider that
+    staged its bytes itself (``MetaJournal``) calls ``flush()`` after it
+    did.
 
-    ``flush()`` returns the fsync's own interval ``(t0, t1, off_loop)``:
-    ``perf_counter`` at its start and end, read in the thread that ran
-    it, and whether that was an executor thread (a round: every waiter
-    of a round gets the same interval) or the caller's loop (inline).
-    From the interval's end to the waiter's resumption is the loop's
-    share of the awaited time, not the disk's."""
+    Where the fsync runs is decided by what it is measured to cost:
+    while the smoothed cost is under ``INLINE_MAX_S`` the close fsyncs
+    on the loop thread; at or above it the close, having appended,
+    hands the fsync to the executor and its completion resolves the
+    round's future, and what stages in the turns in between waits for it
+    as ONE round (classic group commit: one fsync in flight, the next
+    one covers what arrived meanwhile).
 
-    # An inline fsync blocks the event loop, so the fast path self-bans
-    # the moment a sync exceeds this (slow/contended disk): stalling the
-    # loop stalls heartbeats for EVERY group in the process.
+    The engine is shared process-wide by directory, so stagers may live
+    on DIFFERENT event loops (multi-store processes): a round belongs to
+    the loop that opened it, is closed and resolved on that loop, and
+    ``engine.sync()`` serializes concurrent rounds.
+
+    The round's future resolves to the fsync's own interval
+    ``(t0, t1, off_loop)``: ``perf_counter`` at its start and end, read
+    in the thread that ran it, and whether that was an executor thread.
+    From the interval's end to a stager's resumption is the loop's share
+    of the awaited time, not the disk's."""
+
+    # An inline fsync blocks the event loop, so the loop-thread close
+    # self-bans the moment the smoothed sync cost reaches this (slow or
+    # contended disk): stalling the loop stalls heartbeats for EVERY
+    # group in the process.  One writeback spike doesn't ban it, a
+    # genuinely slow disk does, and while banned there is no inline
+    # re-probe (a probe blocks the loop for the full, unbounded fsync):
+    # the executor rounds feed the same EWMA from their thread, so the
+    # loop-thread close re-enables only after the DISK proves fast again,
+    # off-loop.
     INLINE_MAX_S = 0.001
-    # Gap below which another flush is considered "hot on our heels":
-    # take the coalescing round so N concurrent flushers cost one fsync.
-    INLINE_IDLE_GAP_S = 0.002
 
-    def __init__(self, engine: "MultiLogEngine"):
+    def __init__(self, engine) -> None:
         self._engine = engine
         self._lock = threading.Lock()
-        self._waiters: list[asyncio.Future] = []   # guarded-by: _lock
-        self._task: Optional[asyncio.Task] = None  # guarded-by: _lock
-        self._last_sync = 0.0                      # guarded-by: _lock
-        # smoothed inline-sync cost (seconds)
+        self._lanes: dict = {}                     # guarded-by: _lock
+        # smoothed fsync cost (seconds), fed by every round
         self._cost_ewma = 0.0                      # guarded-by: _lock
         # gray-failure signal sink: a DiskLatencyProbe (util/health.py,
         # itself lock-guarded) fed every measured fsync duration — set
         # by the hosting StoreEngine; None = no health scoring
         self.health_probe = None
+        # events, one sample each, so a window's ``count`` is the
+        # number: a round closed, a staging that rode one (round_groups
+        # / rounds = groups per fsync, 1.0 = nothing merges), a round
+        # synced on the loop thread
+        self.rounds = Histogram()
+        self.round_groups = Histogram()
+        self.round_inline = Histogram()
 
-    async def flush(self) -> tuple:
-        # LOW-LOAD fast path (VERDICT r2 #3): the executor round costs
-        # ~2ms end-to-end on a busy single-core loop (the completion
-        # callback queues behind tick + replicator work) while the fsync
-        # itself is ~0.1ms on this disk class.  When no round is running
-        # and no flush landed within the idle gap, fsync INLINE — the
-        # commit-ack path shortens by the round-trip on both the leader
-        # and the follower.  Sustained load (back-to-back flushes) keeps
-        # the coalescing round: N concurrent flushers -> one fsync.
+    def flush(self, item: Optional[_Staged] = None) -> asyncio.Future:
+        """Join the running loop's open round (opening it, and
+        scheduling its close, if this is the turn's first stager), with
+        ``item`` for the round to append at its close, or with bytes the
+        caller staged already."""
+        loop = asyncio.get_running_loop()
         with self._lock:
-            idle = (self._task is None or self._task.done()) and \
-                (time.monotonic() - self._last_sync
-                 > self.INLINE_IDLE_GAP_S)
-            # NOTE: while banned (ewma >= INLINE_MAX_S) there is no
-            # inline re-probe — a probe blocks the loop for the full,
-            # unbounded fsync (seconds under writeback stalls), for
-            # every group in the process.  The executor round measures
-            # each sync instead (in _run) and the same EWMA recovers
-            # there, so the fast path re-enables only after the DISK
-            # proves fast again, off-loop.
-            if idle and self._cost_ewma < self.INLINE_MAX_S \
-                    and not self._waiters:
-                self._last_sync = time.monotonic()  # claim the window
-                inline = True
-            else:
-                inline = False
-                fut = asyncio.get_running_loop().create_future()
-                self._waiters.append(fut)
-                # done() covers a round task that died without its
-                # locked handoff (its loop closed with the task
-                # pending): the next flusher revives the group commit
-                if self._task is None or self._task.done():
-                    self._task = asyncio.ensure_future(self._run())
-        if inline:
-            # the loop thread blocks here: a stretch of the log layer
-            sec = _TRACE.enter("log.stage") if _TRACE.enabled else None
-            t0 = time.perf_counter()
-            try:
-                self._engine.sync()
-            finally:
-                t1 = time.perf_counter()
-                if sec is not None:
-                    _TRACE.leave(sec, t1)
-                dur = t1 - t0
-                with self._lock:
-                    self._last_sync = time.monotonic()
-                    # smoothed: one writeback spike doesn't ban the fast
-                    # path, a genuinely slow disk does (and keeps it
-                    # banned while the ewma stays above the ceiling)
-                    self._cost_ewma = 0.7 * self._cost_ewma + 0.3 * dur
-                probe = self.health_probe
-                if probe is not None:
-                    probe.note(dur)
-            return t0, t1, False
-        return await fut
+            lane = self._lanes.get(loop)
+            if lane is None:
+                lane = self._lanes[loop] = _Lane(loop)
+            if lane.open is None:
+                lane.open = _RoundFuture(loop=loop)
+                if not lane.in_flight:
+                    loop.call_soon(self._close, lane)
+            lane.groups += 1
+            if item is not None:
+                item.future = lane.open
+                lane.items.append(item)
+            return lane.open
 
-    def _timed_sync(self, probe=None, tok=None) -> tuple:
-        """engine.sync() + its pure in-thread interval.  ``tok`` is the
-        health probe's stall token of this round (taken by ``_run`` when
-        it handed the round to the executor): it is given back HERE, in
-        the thread that does the I/O, the moment the fsync ends."""
+    def _close(self, lane: _Lane) -> None:
+        """End the open round of the lane's loop (on its thread): append
+        what its riders staged, then sync here or hand that to the
+        executor."""
+        with self._lock:
+            fut, groups, items = lane.open, lane.groups, lane.items
+            lane.open, lane.groups, lane.items = None, 0, []
+            lane.in_flight = True       # until this round resolves
+            inline = self._cost_ewma < self.INLINE_MAX_S
+        self.rounds.update(1)
+        self.round_groups.update(1, groups)
+        probe = self.health_probe
+        exc: Optional[BaseException] = None
+        interval: Optional[tuple] = None
+        # the loop thread appends (buffered) and, while the disk is
+        # measured fast, blocks in the fsync: a stretch of the log layer
+        sec = _TRACE.enter("log.stage") if _TRACE.enabled else None
+        try:
+            if items:
+                try:
+                    self._engine.append_round(items)
+                except Exception as e:  # noqa: BLE001 — fails THIS round
+                    exc = e
+            if exc is None and inline:
+                self.round_inline.update(1)
+                exc, interval = self._timed_sync(probe, None, False)
+        finally:
+            if sec is not None:
+                _TRACE.leave(sec)
+        if exc is not None or inline:
+            self._landed(lane, fut, exc, interval)
+            return
+        # the health probe's stall token spans the hand-off to the
+        # executor, the wait for a free thread (a saturated executor IS
+        # a gray signal) and the fsync, and ends in the thread with the
+        # fsync: a hung or never-started fsync ages it.  It does not
+        # span the stagers' resumption: held until then, a store whose
+        # fsyncs take 0.06 ms read as a stalled disk whenever its loop
+        # ran half a second late, and the loop's lateness has a probe of
+        # its own (LoopLagProbe).
+        tok = probe.begin() if probe is not None else None
+        try:
+            lane.loop.run_in_executor(None, self._sync_off_loop, lane, fut,
+                                      probe, tok)
+        except RuntimeError as e:   # the executor is shut down
+            if tok is not None:
+                probe.end(tok)      # a round that never reached a thread
+            self._landed(lane, fut, e, None)
+
+    def _timed_sync(self, probe, tok, off_loop: bool) -> tuple:
+        """``engine.sync()`` and its pure in-thread interval; feeds the
+        EWMA and the health probe from the thread that ran it.  ``tok``
+        is the probe's stall token of an executor round: given back
+        HERE, in the thread that does the I/O, the moment the fsync
+        ends.  Returns ``(exception or None, interval)``."""
+        exc: Optional[BaseException] = None
         t0 = time.perf_counter()
         try:
             self._engine.sync()
+        except Exception as e:  # noqa: BLE001 — fails THIS round only
+            exc = e
         finally:
+            t1 = time.perf_counter()
             if tok is not None:
                 probe.end(tok)
-        return t0, time.perf_counter(), True
-
-    def _revive(self) -> None:
-        """Restart the round on THIS loop — scheduled via
-        call_soon_threadsafe when a foreign host loop died mid-round."""
+        dur = t1 - t0
         with self._lock:
-            if self._waiters and (self._task is None or self._task.done()):
-                self._task = asyncio.ensure_future(self._run())
+            # smoothed: one writeback spike doesn't ban the loop-thread
+            # close, a genuinely slow disk does (and keeps it banned
+            # while the ewma stays above the ceiling); fed from the
+            # executor rounds too, which is how a ban recovers
+            self._cost_ewma = 0.7 * self._cost_ewma + 0.3 * dur
+        if probe is not None:
+            probe.note(dur)
+        return exc, (t0, t1, off_loop)
 
-    async def _run(self) -> None:
-        loop = asyncio.get_running_loop()
-        while True:
-            with self._lock:
-                if not self._waiters:
-                    # hand off INSIDE the lock: a flusher on another loop
-                    # that observed a still-pending task must not strand
-                    # its waiter on a round that already decided to exit
-                    self._task = None
-                    return
-                batch, self._waiters = self._waiters, []
-            exc: Optional[BaseException] = None
-            interval: Optional[tuple] = None
-            # the health probe's stall token spans the hand-off to the
-            # executor, the wait for a free thread (a saturated executor
-            # IS a gray signal) and the fsync, and ends in the thread
-            # with the fsync: a hung or never-started fsync ages it.  It
-            # does not span this task's resumption: held until then, a
-            # store whose fsyncs take 0.06 ms read as a stalled disk
-            # whenever its loop ran half a second late, and the loop's
-            # lateness has a probe of its own (LoopLagProbe).
-            probe = self.health_probe
-            tok = probe.begin() if probe is not None else None
-            try:
-                # time the fsync IN the executor thread: timing around
-                # the await would fold in the loop round-trip (~2ms) and
-                # permanently ban the inline path on any busy process
-                interval = await loop.run_in_executor(
-                    None, self._timed_sync, probe, tok)
-                dur = interval[1] - interval[0]
-                with self._lock:
-                    self._last_sync = time.monotonic()
-                    # keep the inline-ban EWMA fed from the executor
-                    # path too: this is how a banned fast path recovers
-                    # (re-probing inline would block the loop)
-                    self._cost_ewma = 0.7 * self._cost_ewma + 0.3 * dur
-                if probe is not None:
-                    probe.note(dur)
-            except asyncio.CancelledError:
-                # this round's HOST loop is tearing down (asyncio.run
-                # cancels pending tasks at exit) — that is not an fsync
-                # failure, and waiters on OTHER loops must not see it:
-                # requeue the batch, hand the round to every surviving
-                # waiter loop (idempotent under the lock), and let the
-                # cancellation proceed on this loop
-                with self._lock:
-                    self._waiters = batch + self._waiters
-                    self._task = None
-                    for fl in {f.get_loop() for f in self._waiters}:
-                        if fl is loop:
-                            continue
-                        try:
-                            fl.call_soon_threadsafe(self._revive)
-                        except RuntimeError:
-                            pass  # that loop is gone too
-                raise
-            except Exception as e:  # noqa: BLE001 — fail THIS round only
-                exc = e
-            finally:
-                if tok is not None:
-                    probe.end(tok)  # a round that never reached a thread
-            for f in batch:
-                if f.get_loop() is loop:
-                    _deliver(f, exc, interval)
-                else:
-                    try:
-                        f.get_loop().call_soon_threadsafe(
-                            _deliver, f, exc, interval)
-                    except RuntimeError:
-                        pass  # waiter's loop already closed
+    def _sync_off_loop(self, lane: _Lane, fut: _RoundFuture, probe,
+                       tok) -> None:
+        """An executor round: the fsync in this thread, then straight
+        back to the round's loop with its outcome."""
+        exc, interval = self._timed_sync(probe, tok, True)
+        try:
+            lane.loop.call_soon_threadsafe(self._landed, lane, fut, exc,
+                                           interval)
+        except RuntimeError:
+            pass  # the round's loop closed under it: nobody is waiting
+
+    def _landed(self, lane: _Lane, fut: _RoundFuture,
+                exc: Optional[BaseException],
+                interval: Optional[tuple]) -> None:
+        """A round is done, on its loop: resolve it, and close the round
+        that gathered behind it while it was with the executor."""
+        if exc is not None:
+            fut.set_exception(exc)
+        else:
+            fut.set_result(interval)
+        with self._lock:
+            if lane.open is None:
+                lane.in_flight = False
+                del self._lanes[lane.loop]
+            else:
+                # behind the callbacks just scheduled: a rider whose
+                # flush failed withdraws its stake in the next round
+                # before that round appends it
+                lane.loop.call_soon(self._close, lane)
 
 
 class MultiLogEngine:
@@ -340,6 +398,39 @@ class MultiLogEngine:
         if gid == 0:
             raise IOError(f"multilog register failed: {err.value.decode()}")
         return gid
+
+    def append_round(self, items: list) -> None:
+        """A flush round's staging: every rider's frames into the journal
+        in ONE native call and ONE ``write()`` a touched journal
+        (``tlm_append_round``; a withdrawn item is passed over).  A
+        group whose append fails (its frames or their contiguity; a
+        failed write, for every group with bytes in it) has the reason
+        as its item's ``error``; the others are appended."""
+        live = [it for it in items if it.frames is not None]
+        n = len(live)
+        if not n:
+            return
+        h = self._h
+        if not h:
+            # the riders' storages released the engine under their round
+            for it in live:
+                it.error = IOError("multilog engine closed")
+            return
+        gate = self.fault_gate
+        if gate is not None:
+            gate(sum(len(it.frames) for it in live))
+        results = (ctypes.c_int64 * n)()
+        err = ctypes.create_string_buffer(256)
+        failed = self._lib.tlm_append_round(
+            h, n, (ctypes.c_uint32 * n)(*[it.gid for it in live]),
+            (ctypes.c_char_p * n)(*[it.frames for it in live]),
+            (ctypes.c_int64 * n)(*[len(it.frames) for it in live]),
+            results, err, 256)
+        if failed:
+            why = f"multilog append failed: {err.value.decode()}"
+            for it, r in zip(live, results):
+                if r < 0:
+                    it.error = ValueError(why)
 
     def sync(self) -> None:
         with self._sync_lock:
@@ -462,13 +553,17 @@ class MultiLogStorage(LogStorage):
             self._lib.tlm_free(out)
         return LogEntry.decode(blob)
 
-    def _stage(self, entries: list[LogEntry]) -> int:
+    @staticmethod
+    def _frames(entries: list[LogEntry]) -> bytes:
         parts = []
         for e in entries:
             blob = e.encode()
             parts.append(_FRAME.pack(len(blob)))
             parts.append(blob)
-        frames = b"".join(parts)
+        return b"".join(parts)
+
+    def _stage(self, entries: list[LogEntry]) -> int:
+        frames = self._frames(entries)
         gate = self._eng.fault_gate
         if gate is not None:
             gate(len(frames))
@@ -481,7 +576,7 @@ class MultiLogStorage(LogStorage):
 
     def append_entries(self, entries: list[LogEntry], sync: bool = True) -> int:
         """Synchronous path (executor callers): per-call fsync, no
-        cross-group coalescing — prefer append_entries_async."""
+        cross-group round — prefer append_entries_async."""
         if not entries:
             return 0
         n = self._stage(entries)
@@ -489,24 +584,29 @@ class MultiLogStorage(LogStorage):
             self._eng.sync()
         return n
 
-    async def append_entries_async(self, entries: list[LogEntry],
-                                   sync: bool = True) -> Optional[tuple]:
-        """LogManager hook: stage inline (ctypes releases the GIL for
-        the buffered write — no executor hop), then join the engine-wide
-        group commit — N groups flushing concurrently cost ONE fsync.
-        Returns that fsync's interval (``_GroupCommit.flush``), None
-        where nothing was synced."""
+    def append_entries_async(self, entries: list[LogEntry],
+                             sync: bool = True) -> Optional[_Staged]:
+        """LogManager hook: encode NOW, in the caller's own turn (so a
+        group's entries are staged in the caller's order), and join the
+        store-wide flush round of this turn, which appends every
+        rider's frames in ONE native call and syncs them with ONE fsync.
+        Returns the group's stake in that round: awaitable (the fsync's
+        interval, see ``_GroupCommit``, or what failed), uncancellable,
+        with the round's shared ``future``.  None where nothing is to be
+        synced (the entries are appended at once then)."""
         if not entries:
+            return None
+        if not sync:
+            self._stage(entries)
             return None
         sec = _TRACE.enter("log.stage") if _TRACE.enabled else None
         try:
-            self._stage(entries)
+            item = _Staged(self._gid, self._frames(entries))
         finally:
             if sec is not None:
                 _TRACE.leave(sec)
-        if sync:
-            return await self._eng.group_commit.flush()
-        return None
+        self._eng.group_commit.flush(item)
+        return item
 
     def truncate_prefix(self, first_index_kept: int) -> None:
         if self._lib.tlm_truncate_prefix(self._eng._h, self._gid,

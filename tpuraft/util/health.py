@@ -14,8 +14,8 @@ pd_server.py) act on the score.
 
 Signals (no new RPCs, no polling probes):
   - **disk**: append+fsync latency of every log flush round
-    (``LogManager._flush_loop`` times the storage call; the multilog
-    group-commit feeds its in-thread fsync duration) plus the AGE of a
+    (``LogManager._flush_loop`` times the storage call; the multilog's
+    flush round feeds its in-thread fsync duration) plus the AGE of a
     still-in-flight flush — a fully hung fsync produces no completed
     sample, so the EMA alone would never notice it;
   - **peer RTT**: ack round-trip of every beat-plane RPC the
