@@ -8,7 +8,10 @@ here, in a second, before a chip is touched.  It also loads the data:
 :func:`check` gives the harness the manifest with each configuration,
 traffic mix and per-layer metric read from the file of its own that the
 manifest's names lead to (under ``_data``, ``_traffic``, ``_reader``), so adding a cell, a configuration, a mix or a
-metric is adding files and manifest entries, never an edit here.
+metric is adding files and manifest entries, never an edit here.  A mix's
+loop and a configuration's optional ``cluster`` name modules, which have to be
+files beside the data (``loops/<kind>.py``, ``clusters/<name>.py``);
+``benchmark/plugins.py`` loads them from the tree checked here (``_root``).
 """
 
 from __future__ import annotations
@@ -41,6 +44,7 @@ CONFIG_FIELDS = {"name", "source", "why", "regions", "stores", "replicas",
                  "read_mode", "transport", "record_count", "field_count",
                  "field_bytes", "guarantees", "chips", "layout", "assumed",
                  "reduced", "reduced_why"}
+CONFIG_OPTIONAL = {"cluster", "options"}    # the module that builds it; its own
 ENGINE_FIELDS = {"backend", "max_groups", "max_peers", "tick_interval_ms",
                  "mesh_devices"}
 TRAFFIC_FIELDS = {"name", "why", "read_share", "update_share",
@@ -48,7 +52,6 @@ TRAFFIC_FIELDS = {"name", "why", "read_share", "update_share",
                   "request_distribution", "zipfian_constant", "scrambled",
                   "loop", "warm_seconds", "faults"}
 DISTRIBUTIONS = ("zipfian", "uniform", "latest")
-LOOPS = ("closed", "open")
 
 
 class ManifestError(ValueError):
@@ -91,8 +94,24 @@ def _read_json(root: str, rel: str, errs: list):
         return None
 
 
-def check_config_file(cfg: dict, rel: str, errs: list) -> None:
-    _keys(cfg, CONFIG_FIELDS, rel, errs)
+def _module_file(root: str, base: str, kind_dir: str, name, what: str,
+                 errs: list) -> None:
+    """``name`` names a module: a file ``<base>/<kind_dir>/<name>.py``."""
+    _name(name, what, errs)
+    if isinstance(name, str) and NAME_RE.match(name):
+        rel = f"{base}/{kind_dir}/{name}.py"
+        if not os.path.isfile(os.path.join(root, rel)):
+            errs.append(f"{what} {name!r}: {rel}: no such file")
+
+
+def check_config_file(cfg: dict, rel: str, errs: list, root: str = ".",
+                      base: str = "benchmark") -> None:
+    _keys(cfg, CONFIG_FIELDS, rel, errs, optional=CONFIG_OPTIONAL)
+    if "cluster" in cfg:
+        _module_file(root, base, "clusters", cfg["cluster"],
+                     f"{rel}: cluster", errs)
+    if not isinstance(cfg.get("options", {}), dict):
+        errs.append(f"{rel}: options must be an object")
     _line(cfg.get("source"), f"{rel}: source", errs)
     eng = cfg.get("engine")
     if not isinstance(eng, dict):
@@ -107,7 +126,8 @@ def check_config_file(cfg: dict, rel: str, errs: list) -> None:
         _name(key, f"{rel}: reduced key", errs)
 
 
-def check_traffic_file(tr: dict, rel: str, errs: list) -> None:
+def check_traffic_file(tr: dict, rel: str, errs: list, root: str = ".",
+                       base: str = "benchmark") -> None:
     _keys(tr, TRAFFIC_FIELDS, rel, errs)
     shares = [tr.get(k, 0.0) for k in ("read_share", "update_share",
                                        "insert_share", "scan_share",
@@ -119,15 +139,25 @@ def check_traffic_file(tr: dict, rel: str, errs: list) -> None:
         errs.append(f"{rel}: request_distribution must be one of "
                     f"{DISTRIBUTIONS}")
     loop = tr.get("loop")
-    if not isinstance(loop, dict) or loop.get("kind") not in LOOPS:
-        errs.append(f"{rel}: loop.kind must be one of {LOOPS}")
-    elif loop["kind"] == "closed" and (set(loop) != {"kind", "clients"}
+    if not isinstance(loop, dict):
+        errs.append(f"{rel}: loop must be an object with a 'kind'")
+        loop = {}
+    else:
+        # the kind names the module that sends the mix; a kind not known
+        # here has its other keys checked by that module
+        _module_file(root, base, "loops", loop.get("kind"),
+                     f"{rel}: loop.kind", errs)
+    if loop.get("kind") == "closed" and (set(loop) != {"kind", "clients"}
                                        or not isinstance(loop["clients"], int)
                                        or loop["clients"] < 1):
         errs.append(f"{rel}: a closed loop has just 'clients', a positive "
                     f"whole number")
-    elif loop["kind"] == "open" and set(loop) != {"kind", "rate"}:
-        errs.append(f"{rel}: an open loop has just 'rate'")
+    elif loop.get("kind") == "open" and (
+            set(loop) != {"kind", "rate"}
+            or not isinstance(loop["rate"], (int, float))
+            or isinstance(loop["rate"], bool) or loop["rate"] <= 0):
+        errs.append(f"{rel}: an open loop has just 'rate', operations a "
+                    f"second, above 0")
     if not isinstance(tr.get("faults"), list):
         errs.append(f"{rel}: faults must be a list")
 
@@ -185,6 +215,7 @@ def check(root: str = ".") -> dict:
     if not isinstance(paths, list) or not 1 <= len(paths) <= 16:
         errs.append("paths must list 1 to 16 directories")
         paths = []
+    base = paths[0] if paths and isinstance(paths[0], str) else "benchmark"
     for p in paths:
         if (not isinstance(p, str) or not PATH_RE.match(p)
                 or p.startswith("/") or ".." in p.split("/")):
@@ -238,7 +269,7 @@ def check(root: str = ".") -> dict:
         cfg = _read_json(root, rel, errs)
         if cfg is None:
             continue
-        check_config_file(cfg, rel, errs)
+        check_config_file(cfg, rel, errs, root, base)
         if cfg.get("name") != c.get("name"):
             errs.append(f"{rel}: name {cfg.get('name')!r} is not "
                         f"{c.get('name')!r}")
@@ -270,7 +301,7 @@ def check(root: str = ".") -> dict:
     # -- cells --------------------------------------------------------------
     cells: dict = {}
     pairs: set = set()
-    traffic_dir = os.path.join(paths[0], "traffic") if paths else "traffic"
+    traffic_dir = f"{base}/traffic"
     if not 1 <= len(bm["workloads"]) <= 24:
         errs.append("workloads must hold 1 to 24 cells")
     for w in bm["workloads"]:
@@ -294,7 +325,7 @@ def check(root: str = ".") -> dict:
             rel = f"{traffic_dir}/{w['traffic']}.json"
             tr = _read_json(root, rel, errs)
             if tr is not None:
-                check_traffic_file(tr, rel, errs)
+                check_traffic_file(tr, rel, errs, root, base)
                 if tr.get("name") != w["traffic"]:
                     errs.append(f"{rel}: name is not {w['traffic']!r}")
                 w["_traffic"] = tr
@@ -324,8 +355,7 @@ def check(root: str = ".") -> dict:
                         f"setup_s")
 
     # -- per-layer metrics --------------------------------------------------
-    layer_dir = os.path.join(paths[0], "layer_metrics") if paths \
-        else "layer_metrics"
+    layer_dir = f"{base}/layer_metrics"
     names: set = set(e2e)
     covered: set = set()
     if not 1 <= len(bm["per_layer"]) <= 128:
@@ -370,6 +400,7 @@ def check(root: str = ".") -> dict:
 
     if errs:
         raise ManifestError("; ".join(errs))
+    bm["_root"] = os.path.abspath(root)
     return bm
 
 

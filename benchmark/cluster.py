@@ -3,8 +3,13 @@
 The only module of the benchmark that imports ``tpuraft``: it builds the
 deployment the configuration states (the topology ``chip_smoke.py`` proved on
 the chip in PR 21), loads the records, and hands the harness the client, the
-program's counters and the engines' live tick inputs.  Fields that only have
-one value implemented today are validated and the others refused by name.
+program's counters and the engines' live tick inputs.  ``IMPLEMENTS`` says
+which values of the guarded fields this class builds; ``benchmark/plugins.py``
+refuses any other by name.  A deployment built otherwise is a module under
+``benchmark/clusters/`` with a ``Cluster`` of its own (as a rule a subclass
+that overrides one of ``start``'s steps) and its own ``IMPLEMENTS``; its
+configuration file names it under ``cluster`` and may hand it an ``options``
+object, which nothing else reads.
 """
 
 from __future__ import annotations
@@ -24,26 +29,11 @@ class NotImplementedConfig(NotImplementedError):
     pass
 
 
-def refuse_unimplemented(cfg: dict) -> None:
-    want = {"stores": 3, "replicas": 3, "read_mode": "safe",
-            "transport": "inproc", "log_scheme": "multilog",
-            "kv_store": "native"}
-    for key, value in want.items():
-        if cfg[key] != value:
-            raise NotImplementedConfig(
-                f"config {cfg['name']}: {key}={cfg[key]!r} is not "
-                f"implemented, only {value!r} (benchmark/cluster.py: Cluster)")
-    if cfg["engine"]["backend"] != "jax":
-        raise NotImplementedConfig(
-            f"config {cfg['name']}: engine.backend must be 'jax'")
-    if cfg["engine"]["mesh_devices"] not in (0, 1):
-        raise NotImplementedConfig(
-            f"config {cfg['name']}: engine.mesh_devices="
-            f"{cfg['engine']['mesh_devices']} is not implemented: served "
-            f"traffic has never run over the mesh (benchmark/cluster.py)")
-    if cfg["record_count"] % cfg["regions"]:
-        raise NotImplementedConfig(
-            f"config {cfg['name']}: record_count must be a multiple of regions")
+IMPLEMENTS = {"stores": [3], "replicas": [3], "read_mode": ["safe"],
+              "transport": ["inproc"], "log_scheme": ["multilog"],
+              "kv_store": ["native"], "engine.backend": ["jax"],
+              # served traffic has never run over the mesh
+              "engine.mesh_devices": [0, 1]}
 
 
 def region_start(k: int) -> bytes:
@@ -55,8 +45,12 @@ class Cluster:
     with its own ``MultiRaftEngine``, and one batching ``RheaKVStore``."""
 
     def __init__(self, cfg: dict, workdir: str):
-        refuse_unimplemented(cfg)
+        if cfg["record_count"] % cfg["regions"]:
+            raise NotImplementedConfig(
+                f"config {cfg['name']}: record_count must be a multiple of "
+                f"regions")
         self.cfg = cfg
+        self.options = cfg.get("options", {})   # the subclass's own
         self.workdir = workdir
         self.regions = cfg["regions"]
         self.engines: list = []
@@ -71,19 +65,60 @@ class Cluster:
 
     # -- lifecycle ----------------------------------------------------------
 
+    # The steps of ``start`` that a deployment built otherwise overrides.
+
+    def endpoints(self) -> list:
+        return [f"127.0.0.1:{6600 + i}" for i in range(self.cfg["stores"])]
+
+    def tick_options(self, i: int):
+        """Store ``i``'s engine."""
+        from tpuraft.options import TickOptions
+
+        eng = self.cfg["engine"]
+        return TickOptions(
+            max_groups=eng["max_groups"], max_peers=eng["max_peers"],
+            tick_interval_ms=eng["tick_interval_ms"],
+            mesh_devices=eng["mesh_devices"], backend=eng["backend"])
+
+    def store_options(self, i: int, endpoint: str, region_list: list):
+        """Store ``i``: its regions, where it keeps them, what it promises."""
+        from tpuraft.options import ReadOnlyOption
+        from tpuraft.rheakv.native_store import NativeRawKVStore
+        from tpuraft.rheakv.store_engine import StoreEngineOptions
+
+        return StoreEngineOptions(
+            server_id=endpoint,
+            initial_regions=[r.copy() for r in region_list],
+            data_path=f"{self.workdir}/store{i}",
+            election_timeout_ms=self.cfg["election_timeout_ms"],
+            log_scheme=self.cfg["log_scheme"],
+            read_only_option=ReadOnlyOption.SAFE,
+            raw_store_factory=lambda i=i: NativeRawKVStore(
+                f"{self.workdir}/store{i}/kv"))
+
+    def pd_client(self, region_list: list):
+        from tpuraft.rheakv.pd_client import FakePlacementDriverClient
+
+        return FakePlacementDriverClient([r.copy() for r in region_list])
+
+    def make_client(self, net, region_list: list):
+        from tpuraft.rheakv.client import BatchingOptions, RheaKVStore
+        from tpuraft.rpc.transport import InProcTransport
+
+        return RheaKVStore(
+            self.pd_client(region_list), InProcTransport(net, "kvclient:0"),
+            batching=BatchingOptions(enabled=True), timeout_ms=20000)
+
     async def start(self, elect_deadline_s: float = 300.0) -> None:
         from tpuraft.core.engine import MultiRaftEngine
-        from tpuraft.options import RaftOptions, ReadOnlyOption, TickOptions
-        from tpuraft.rheakv.client import BatchingOptions, RheaKVStore
+        from tpuraft.options import RaftOptions
         from tpuraft.rheakv.metadata import Region
         from tpuraft.rheakv.native_store import NativeRawKVStore
-        from tpuraft.rheakv.pd_client import FakePlacementDriverClient
-        from tpuraft.rheakv.store_engine import (StoreEngine,
-                                                 StoreEngineOptions)
+        from tpuraft.rheakv.store_engine import StoreEngine
         from tpuraft.rpc.transport import (InProcNetwork, InProcTransport,
                                            RpcServer)
 
-        cfg, R = self.cfg, self.regions
+        R = self.regions
         # the guarantee is fsync at the program's defaults: see that they
         # still are what the configuration states
         if not (RaftOptions().sync and RaftOptions().sync_meta
@@ -91,33 +126,21 @@ class Cluster:
                 .parameters["sync"].default is True):
             raise RuntimeError("a durability default is no longer fsync-on")
         net = InProcNetwork()
-        endpoints = [f"127.0.0.1:{6600 + i}" for i in range(cfg["stores"])]
+        endpoints = self.endpoints()
         region_list = [Region(id=k + 1,
                               start_key=region_start(k) if k else b"",
                               end_key=region_start(k + 1) if k + 1 < R
                               else b"", peers=list(endpoints))
                        for k in range(R)]
-        eng = cfg["engine"]
         t0 = time.perf_counter()
         for i, ep in enumerate(endpoints):
             os.makedirs(f"{self.workdir}/store{i}", exist_ok=True)
             server = RpcServer(ep)
             net.bind(server)
-            engine = MultiRaftEngine(TickOptions(
-                max_groups=eng["max_groups"], max_peers=eng["max_peers"],
-                tick_interval_ms=eng["tick_interval_ms"],
-                mesh_devices=eng["mesh_devices"], backend=eng["backend"]))
+            engine = MultiRaftEngine(self.tick_options(i))
             self.engines.append(engine)
             store = StoreEngine(
-                StoreEngineOptions(
-                    server_id=ep,
-                    initial_regions=[r.copy() for r in region_list],
-                    data_path=f"{self.workdir}/store{i}",
-                    election_timeout_ms=cfg["election_timeout_ms"],
-                    log_scheme=cfg["log_scheme"],
-                    read_only_option=ReadOnlyOption.SAFE,
-                    raw_store_factory=lambda i=i: NativeRawKVStore(
-                        f"{self.workdir}/store{i}/kv")),
+                self.store_options(i, ep, region_list),
                 server, InProcTransport(net, ep), multi_raft_engine=engine)
             self.stores.append(store)
             await store.start()
@@ -135,10 +158,7 @@ class Cluster:
             await asyncio.sleep(0.1)
         self.timings["elect_s"] = time.perf_counter() - t1
 
-        self.client = RheaKVStore(
-            FakePlacementDriverClient([r.copy() for r in region_list]),
-            InProcTransport(net, "kvclient:0"),
-            batching=BatchingOptions(enabled=True), timeout_ms=20000)
+        self.client = self.make_client(net, region_list)
         await self.client.start()
 
     async def shutdown(self) -> None:
@@ -233,6 +253,13 @@ class Cluster:
                      "commit_advances", "eager_commits"):
             out[f"engine.{name}"] = sum(out[f"engine{i}.{name}"]
                                         for i in range(len(self.engines)))
+        # the two fsync rounds, over the stores: events counted as
+        # histograms that take one sample an event
+        for name in ("kv_wal_syncs", "kv_wal_sync_entries", "log_rounds",
+                     "log_round_groups"):
+            out[f"engine.{name}.count"] = sum(
+                out.get(f"engine{i}.{name}.count", 0)
+                for i in range(len(self.engines)))
         return out
 
     def device_tick_runs(self) -> bool:

@@ -13,118 +13,35 @@ import shutil
 import sys
 import time
 
-from benchmark import check_manifest, layers, trace_reduce
+from benchmark import check_manifest, layers, plugins, trace_reduce
+from benchmark.loops import SETTLE_DEADLINE_S, Window
 from benchmark.reference import (INF, Read, Write, check_history,
                                  tick_mismatches, tick_reference)
 from benchmark.stats import percentile, rate
 from benchmark.traffic import LOADER, READ, OpStream, Values
 
 FAILED_LATENCY_S = 60.0     # a failed operation misses every latency limit
-SETTLE_DEADLINE_S = 60.0    # how long a late answer or replica is waited for
 TRACE_SAMPLE_RATE = 0.05
-
-
-class Window:
-    """What the closed loop recorded: one row per operation issued."""
-
-    def __init__(self) -> None:
-        self.ops: list = []      # (kind, record, invoke, complete, ok, id)
-        self.start = self.end = 0.0
-        self.loop_lag_ms: list = []
-
-
-async def _lag_monitor(win: Window, stop: asyncio.Event,
-                       period_s: float = 0.01) -> None:
-    """How late the event loop that clients and stores share wakes a sleeper:
-    the generator's lateness."""
-    while not stop.is_set():
-        t = time.perf_counter()
-        await asyncio.sleep(period_s)
-        win.loop_lag_ms.append((time.perf_counter() - t - period_s) * 1e3)
-
-
-async def run_window(client, keys: list, stream: OpStream, values: Values,
-                     clients: int, warm_s: float, seconds: float,
-                     on_window_start=None, on_window_end=None) -> Window:
-    """``clients`` callers, each sending its next operation when the last
-    returned (YCSB's own loop), for ``warm_s`` and then ``seconds``.  Ops are
-    taken from one cursor over the seeded stream.  At the close every caller
-    finishes the operation it has in flight: a late answer is late, not lost.
-    """
-    win = Window()
-    cursor = [0]
-    stopping = [False]
-    kinds, records, n = stream.kinds, stream.records, stream.n
-    pc = time.perf_counter
-
-    async def caller(cid: int) -> None:
-        seq = 0
-        while not stopping[0]:
-            i = cursor[0]
-            cursor[0] = i + 1
-            kind, rec = int(kinds[i % n]), int(records[i % n])
-            key = keys[rec]
-            t0 = pc()
-            try:
-                if kind == READ:
-                    got = values.parse(await client.get(key))
-                    ok = True
-                else:
-                    seq += 1
-                    got = (cid, seq, rec)
-                    ok = await client.put(key, values.make(cid, seq, rec)) \
-                        is True
-            except asyncio.CancelledError:
-                raise
-            except Exception:  # noqa: BLE001 — a failed operation is counted
-                ok, got = False, (cid, seq, rec) if kind != READ else None
-            win.ops.append((kind, rec, t0, pc(), ok, got))
-
-    stop_lag = asyncio.Event()
-    tasks = [asyncio.ensure_future(caller(c)) for c in range(clients)]
-    lag_task = asyncio.ensure_future(_lag_monitor(win, stop_lag))
-    try:
-        await asyncio.sleep(warm_s)
-        if on_window_start is not None:
-            on_window_start()
-        win.loop_lag_ms.clear()
-        win.start = pc()
-        await asyncio.sleep(seconds)
-        win.end = pc()
-        if on_window_end is not None:
-            on_window_end()
-        stopping[0] = True
-        stop_lag.set()
-        done, pending = await asyncio.wait(tasks, timeout=SETTLE_DEADLINE_S)
-        for t in pending:
-            t.cancel()
-        for t in done:
-            t.result()
-    finally:
-        stopping[0] = True
-        stop_lag.set()
-        for t in tasks + [lag_task]:
-            if not t.done():
-                t.cancel()
-        await asyncio.gather(*tasks, lag_task, return_exceptions=True)
-    return win
 
 
 def end_to_end(win: Window, seconds: float | None = None) -> dict:
     """The window's end-to-end numbers, over every operation of it (or of its
-    first ``seconds``: what a shorter run would have read)."""
+    first ``seconds``: what a shorter run would have read).  An operation
+    belongs to the window, and its latency counts, from when it was due: on a
+    schedule that is ``win.due``, in a closed loop the call itself."""
     end = win.end if seconds is None else win.start + seconds
-    issued = [o for o in win.ops if win.start <= o[2] < end]
+    since = win.due or [o[2] for o in win.ops]
+    issued = [(o, t) for o, t in zip(win.ops, since) if win.start <= t < end]
     acked = sum(1 for o in win.ops if o[4] and win.start <= o[3] < end)
 
     def lat_ms(kind_is_read: bool) -> list:
-        return [((o[3] - o[2]) if o[4] else FAILED_LATENCY_S) * 1e3
-                for o in issued if (o[0] == READ) == kind_is_read]
+        return [((o[3] - t) if o[4] else FAILED_LATENCY_S) * 1e3
+                for o, t in issued if (o[0] == READ) == kind_is_read]
 
     reads, updates = lat_ms(True), lat_ms(False)
     out = {"ops_per_s": rate(acked, end - win.start),
            "attempted": len(issued),
-           "failed": sum(1 for o in issued if not o[4]),
+           "failed": sum(1 for o, _ in issued if not o[4]),
            "reads": len(reads), "updates": len(updates),
            "_read_ms": reads, "_update_ms": updates}
     if reads:
@@ -206,13 +123,13 @@ async def run_cell(bm: dict, cell_name: str, seed: int, seconds: float,
     3,072 replicas takes seconds that every run of every check would pay."""
     from tpuraft.util.trace import TRACER
 
-    from benchmark.cluster import Cluster
     from benchmark.faults import plant
 
     cell, cfg, traffic = check_manifest.cell(bm, cell_name)
+    loop = plugins.loop_of(bm, traffic)
     values = Values(seed, cfg["field_count"] * cfg["field_bytes"])
     stream = OpStream(traffic, cfg["record_count"], seed)
-    cluster = Cluster(cfg, workdir)
+    cluster = plugins.cluster_of(bm, cfg)(cfg, workdir)
     summary: dict = {"cell": cell_name, "seed": seed, "seconds": seconds,
                      "trace": trace, "fault": fault}
     profile_dir = f"{workdir}/profile"
@@ -259,9 +176,8 @@ async def run_cell(bm: dict, cell_name: str, seed: int, seconds: float,
         if trace:
             TRACER.reset()
         try:
-            win = await run_window(
-                client, cluster.keys, stream, values,
-                traffic["loop"]["clients"], traffic["warm_seconds"], seconds,
+            win = await loop.run_window(
+                client, cluster.keys, stream, values, traffic, seconds,
                 on_window_start, on_window_end)
         finally:
             window_note.close()
@@ -281,6 +197,7 @@ async def run_cell(bm: dict, cell_name: str, seed: int, seconds: float,
         e2e = end_to_end(win)
         e2e["setup_s"] = setup["s"]
         delta = {k: after[k] - before[k] for k in after}
+        delta.update(win.counters)      # what the loop itself counted
         n_eng = len(cluster.engines)
         for i in range(n_eng):     # a gauge, not a count
             delta[f"engine{i}.leaders_now"] = after[f"engine{i}.leaders"]
@@ -321,6 +238,7 @@ async def run_cell(bm: dict, cell_name: str, seed: int, seconds: float,
             "generator_lag_ms": {"p50": percentile(lag, 50),
                                  "p95": percentile(lag, 95),
                                  "max": max(lag)},
+            "loop": win.notes,
             "counters": {k: v for k, v in delta.items()
                          if not k.endswith(".total")},
             "check": counts,

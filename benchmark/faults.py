@@ -32,11 +32,12 @@ class _Faulty:
 
     async def get(self, key: bytes):
         self.n += 1
+        n = self.n      # this call's number: others are made while it waits
         if self.kind == "stale_reads":
             store = self.rng.randrange(len(self.cluster.stores))
             return self.cluster.stores[store].raw_store.get(key)
         value = await self.client.get(key)
-        if self.kind == "alter_answer" and self.n % 64 == 0 and value:
+        if self.kind == "alter_answer" and n % 64 == 0 and value:
             value = value[:-1] + bytes([value[-1] ^ 1])
         return value
 

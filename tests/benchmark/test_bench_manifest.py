@@ -66,6 +66,21 @@ def _edit(root, rel, fn):
      lambda t: t.update(read_share=0.7), "sum to 1"),
     ("benchmark/layer_metrics/srv_propose_ms.json",
      lambda m: m["reader"].update(kind="regex"), "reader.kind"),
+    # a named module has to be a file beside the data
+    ("benchmark/configs/kv3x8.json",
+     lambda c: c.update(cluster="five_stores"),
+     "cluster 'five_stores': benchmark/clusters/five_stores.py: no such file"),
+    ("benchmark/configs/kv3x8.json",
+     lambda c: c.update(cluster="../cluster"), "not a name"),
+    ("benchmark/configs/kv3x8.json",
+     lambda c: c.update(options=[1]), "options must be an object"),
+    ("benchmark/traffic/ycsb_a16.json",
+     lambda t: t.update(loop={"kind": "bursty", "clients": 4}),
+     "loop.kind 'bursty': benchmark/loops/bursty.py: no such file"),
+    ("benchmark/traffic/ycsb_a_open300.json",
+     lambda t: t["loop"].update(rate=0), "above 0"),
+    ("benchmark/traffic/ycsb_a_open300.json",
+     lambda t: t["loop"].update(clients=4), "just 'rate'"),
 ])
 def test_the_check_refuses(tmp_path, rel, edit, says):
     root = extended_copy(str(tmp_path))

@@ -49,8 +49,9 @@ def test_the_manifest_passes_with_the_seventeen_entries():
     bm = check_manifest.check(REPO)
     by_name = {m["name"]: m for m in bm["per_layer"]}
     assert len(NEW_METRICS) == 17 and set(NEW_METRICS) <= set(by_name)
-    # appended after the twelve that were there, none of them moved
-    assert [m["name"] for m in bm["per_layer"]][12:] == list(NEW_METRICS)
+    # appended after the twelve that were there, none of them moved; what
+    # later PRs append comes after the seventeen and is free
+    assert [m["name"] for m in bm["per_layer"]][12:29] == list(NEW_METRICS)
     assert "srv_propose_ms" not in by_name      # the tests' own extra metric
 
 
@@ -121,8 +122,7 @@ def test_the_traced_tiny_run_adds_up(traced):
             "loop.raft.follower", "loop.raft.ack", "loop.raft.heartbeat",
             "loop.log.stage", "loop.fsm.apply", "loop.rpc.inproc",
             "loop.tick.build", "loop.tick.call", "loop.tick.fetch",
-            "loop.tick.apply"} == sections
-    assert len(sections) <= 16
+            "loop.tick.apply"} <= sections      # a later PR may add its own
     layers = sum(got[n] for n in got if n.startswith("loop_pct."))
     assert 0.0 < layers <= 100.0 + 1e-6
 

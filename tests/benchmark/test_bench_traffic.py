@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 from bench_helpers import REPO
 
-from benchmark import peaks, stats
+from benchmark import check_manifest, peaks, plugins, stats
 from benchmark.traffic import (READ, NotImplementedTraffic, OpStream, Values)
 
 
@@ -37,12 +37,25 @@ def test_same_seed_same_operations_with_the_stated_shares_and_skew(name,
 
 
 def test_what_no_cell_uses_yet_is_refused_by_name():
+    """Kinds of operation by the generator; a fault schedule by the lookup,
+    unless the mix's loop module says that it runs it; a loop of a kind that
+    is no module by the manifest's check."""
     for change, word in (({"scan_share": 0.1, "read_share": 0.4}, "scan"),
-                         ({"request_distribution": "latest"}, "latest"),
-                         ({"loop": {"kind": "open", "rate": 100}}, "open"),
-                         ({"faults": [{"kill_store": 1}]}, "faults")):
+                         ({"request_distribution": "latest"}, "latest")):
         with pytest.raises(NotImplementedTraffic, match=word):
             OpStream(dict(_mix("ycsb_a"), **change), 64, 1, n=16)
+    bm = check_manifest.check(REPO)
+    for name in ("ycsb_a", "ycsb_a_open"):
+        assert plugins.loop_of(bm, _mix(name)).IMPLEMENTS == {"faults": [[]]}
+        with pytest.raises(NotImplementedTraffic, match="faults="):
+            plugins.loop_of(bm, dict(_mix(name),
+                                     faults=[{"kill_store": 1}]))
+    errs: list = []
+    check_manifest.check_traffic_file(
+        dict(_mix("ycsb_a"), loop={"kind": "bursty", "rate": 100}),
+        "a_mix.json", errs, REPO)
+    assert any("loop.kind 'bursty'" in e and "no such file" in e
+               for e in errs)
     uniform = OpStream(dict(_mix("ycsb_a"), request_distribution="uniform"),
                        64, 1, n=1 << 14)
     assert np.bincount(uniform.records, minlength=64).min() > 150
