@@ -237,6 +237,9 @@ class _StubCtrl:
         now = e.now_ms() if now_ms is None else now_ms
         e.elect_deadline[self.slot] = now + int(e.eto_ms[self.slot])
 
+    def note_election_due(self) -> None:
+        pass    # no tracer here: nothing to begin a span on
+
     def schedule(self, name: str, handler) -> None:
         self.counts[name] = self.counts.get(name, 0) + 1
 

@@ -292,6 +292,10 @@ class AppendBatcher:
     # -- lifecycle ------------------------------------------------------------
 
     async def shutdown(self) -> None:
+        self.close()
+
+    def close(self) -> None:
+        """Nothing is awaited: a crash (``StoreEngine.crash``) calls it."""
         self._shut = True
         for pend in self._pending.values():
             self._fail_batch(pend)

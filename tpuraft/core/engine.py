@@ -58,6 +58,7 @@ from tpuraft.options import TickOptions
 from tpuraft.util import clock as clockmod
 from tpuraft.util.trace import RECORDER as _RECORDER
 from tpuraft.util.trace import TRACER as _TRACE
+from tpuraft.util.trace import store_proc
 from tpuraft.ops.ballot import NEG_INF_I32 as _NEG_I32
 from tpuraft.ops.tick import (
     ROLE_CANDIDATE,
@@ -234,6 +235,7 @@ class EngineControl:
                                         self._eto_ms))
         self._jitter = random.randrange(self._jitter_range)
         self._scheduled: set = set()
+        self._election_tid = 0      # the open ``election`` span, if sampled
         # quiescence ("hibernate raft") state
         self._quiesce_after = opts.raft_options.quiesce_after_rounds
         self._quiesce_streak = 0
@@ -320,6 +322,8 @@ class EngineControl:
         Reuses the cached jitter — no RNG per append."""
         self.engine.elect_deadline[self.slot] = (
             self.engine.now_ms() + self._eto_ms + self._jitter)
+        if self._election_tid:      # somebody else won
+            self._drop_election_span()
 
     def on_candidate(self) -> None:
         e = self.engine
@@ -332,6 +336,26 @@ class EngineControl:
 
     def stop_vote_wait(self) -> None:
         pass  # deadline is inert once the role leaves CANDIDATE
+
+    def note_vote_round_lost(self) -> None:
+        self.engine.tick_hists["vote_rounds_lost"].update(
+            self.node.current_term)
+
+    def note_election_yielded(self) -> None:
+        self.engine.tick_hists["elections_yielded"].update(
+            self.node.current_term)
+
+    def note_election_due(self) -> None:
+        """The tick fired ``election_due`` for this row: where a sampled
+        group's ``election`` span begins; ``on_leader`` ends it."""
+        if _TRACE.enabled and not self._election_tid:
+            self._election_tid = _TRACE.begin_op(
+                "election", proc=store_proc(self.node.server_id))
+
+    def _drop_election_span(self) -> None:
+        if self._election_tid:
+            _TRACE.abandon_op(self._election_tid)
+            self._election_tid = 0
 
     def start_vote_round(self) -> bool:
         """Clear the vote row, grant self.  Returns True when self alone
@@ -383,6 +407,9 @@ class EngineControl:
         e.stepdown_deadline[s] = now + max(1, self._eto_ms // 2)
         e.granted[s, :] = False
         e.mark_dirty()
+        if self._election_tid:
+            _TRACE.end_op(self._election_tid, term=self.node.current_term)
+            self._election_tid = 0
 
     def on_step_down(self, was_candidate: bool, was_leader: bool,
                      status=None) -> None:
@@ -736,6 +763,7 @@ class EngineControl:
         self.engine.role[self.slot] = ROLE_INACTIVE
 
     def shutdown(self) -> None:
+        self._drop_election_span()
         self.deactivate()
         self.engine.unregister_ctrl(self.slot)
 
@@ -925,6 +953,12 @@ class MultiRaftEngine:
             # hub or sent direct by _flush_heartbeats
             "elections_started": Histogram(),
             "leader_stepdowns": Histogram(),
+            # vote rounds that ended with no winner (the candidate's
+            # round timed out): a split vote, or no quorum reachable
+            "vote_rounds_lost": Histogram(),
+            # pre-vote quorums not acted on: the node had granted a
+            # higher-ranked rival's pre-vote for the same term
+            "elections_yielded": Histogram(),
             "beat_rows": Histogram(),
         }
         # leader step-downs by what fired: "quorum" = dead-quorum check
@@ -1627,6 +1661,17 @@ class MultiRaftEngine:
                 pass
             self._task = None
 
+    def crash(self) -> None:
+        """The tick loop stops where it is; nothing is awaited (the
+        orderly way is ``shutdown``)."""
+        from tpuraft.util import describer
+
+        self._stopped = True
+        describer.unregister(self)
+        if self._task is not None:
+            self._task.cancel()
+            self._task = None
+
     def _next_deadline(self) -> int:
         """Earliest engine-scheduled deadline (election, heartbeat or
         stepdown check) over controlled slots; a huge sentinel when
@@ -2025,6 +2070,7 @@ class MultiRaftEngine:
             # push the deadline NOW: the handler runs async, and a
             # same-deadline refire every tick until it runs would storm
             ctrl.push_election_deadline(now)
+            ctrl.note_election_due()
             ctrl.schedule("election_due", ctrl.node._on_election_due)
         for s in np.nonzero(np.asarray(out.elected) & hc)[0]:
             ctrl = self._ctrls[s]

@@ -16,7 +16,7 @@ from typing import Awaitable, Callable, Optional
 
 from tpuraft.conf import Configuration
 from tpuraft.entity import EntryType, LogEntry, LogId, PeerId
-from tpuraft.errors import RaftError, Status
+from tpuraft.errors import RaftError, RaftException, Status
 from tpuraft.core.state_machine import Iterator, StateMachine
 from tpuraft.util.trace import TRACER as _TRACE
 
@@ -85,6 +85,21 @@ class FSMCaller:
         if self._task is not None:
             await self._task
             self._task = None
+
+    def abandon(self) -> None:
+        """A crash: what is queued is never applied, and whoever waits
+        for an apply is told the node went."""
+        self._shut = True
+        self._queue.clear()
+        if self._task is not None and not self._task.done():
+            self._task.cancel()
+        self._task = None
+        st = Status.error(RaftError.ENODESHUTTING, "node crashed")
+        self.fail_pending_closures(st)
+        for _, fut in self._applied_waiters:
+            if not fut.done():
+                fut.set_exception(RaftException(st))
+        self._applied_waiters.clear()
 
     def _enqueue(self, item) -> None:
         if self._shut:

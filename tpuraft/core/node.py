@@ -142,6 +142,12 @@ class TimerControl:
     def stop_vote_wait(self) -> None:
         self._vote_timer.stop()
 
+    def note_vote_round_lost(self) -> None:
+        pass  # the engine counts them (EngineControl)
+
+    def note_election_yielded(self) -> None:
+        pass
+
     def on_leader(self) -> None:
         self._vote_timer.stop()
         self._acks = {self._node.server_id: self._clock.monotonic()}
@@ -287,6 +293,9 @@ class Node:
         self._note_attested = None
         self._snapshot_timer: Optional[RepeatedTimer] = None
         self._last_leader_timestamp = self._clock.monotonic()  # guarded-by: _lock (writes)
+        # (peer, term, when) of the highest-ranked pre-vote this node has
+        # granted for a term and not yet given way to: see _yields_to_rival
+        self._prevote_granted: Optional[tuple] = None
         # index of the first entry appended in THIS leadership term (the
         # election no-op); reads are unsafe until it commits
         self._term_first_index: int = 0         # guarded-by: _lock (writes)
@@ -514,6 +523,39 @@ class Node:
         # writer, and a shutdown must never queue behind a straggler
         # holding the lock (a wedged holder would wedge join() with it)
         self.state = State.SHUTDOWN  # graftcheck: allow(guarded-by) — terminal write; SHUTTING already excludes all other writers
+        self._shutdown_event.set()
+
+    def crash(self) -> None:
+        """The node goes as its process would in a crash: nothing is
+        flushed, no peer is told, nothing is awaited and no lock is
+        taken (whoever holds it is going too).  What the node shares
+        with others in an in-process cluster is given up so that they
+        are not held: callers parked in its handlers are answered as a
+        reset connection would answer them, and its references to the
+        store's journals are released so a successor opens the files."""
+        if self.state in (State.SHUTTING, State.SHUTDOWN):
+            return
+        self.state = State.SHUTDOWN  # graftcheck: allow(guarded-by) — a crash asks nobody
+        st = Status.error(RaftError.ENODESHUTTING, "node crashed")
+        if self._conf_ctx is not None:
+            self._conf_ctx.fail(st)
+            self._conf_ctx = None  # graftcheck: allow(guarded-by) — a crash asks nobody
+        if self._ctrl is not None:
+            self._ctrl.shutdown()
+        if self._snapshot_timer:
+            self._snapshot_timer.stop()
+        self.replicators.stop_all()
+        if self.read_only_service:
+            self.read_only_service.close()
+        # the snapshot executor keeps no file open between calls (its
+        # shutdown() does nothing): a save or an install under way
+        # leaves a temporary directory, as a crash does, which the
+        # successor's snapshot storage removes when it opens
+        self.fsm_caller.abandon()
+        self.log_manager.abandon()
+        self.ballot_box.close()
+        self._meta.shutdown()
+        describer.unregister(self)
         self._shutdown_event.set()
 
     async def join(self) -> None:
@@ -880,18 +922,22 @@ class Node:
 
     async def _handle_election_timeout(self) -> None:
         async with self._lock:
-            if self.state != State.FOLLOWER:
-                return
-            if not self.conf_entry.contains(self.server_id):
-                return  # not a participant (e.g. learner or removed)
-            if self._leader_lease_valid():
-                return
-            if not self._allow_launch_election():
-                return
-            prev_leader = self.leader_id
-            self.leader_id = EMPTY_PEER
-            if not prev_leader.is_empty():
-                self.fsm_caller.on_stop_following(prev_leader, self.current_term)
+            # the host's election work is the raft layer's on the loop
+            # thread: one section, closed before each await
+            with TRACER.section("raft.election"):
+                if self.state != State.FOLLOWER:
+                    return
+                if not self.conf_entry.contains(self.server_id):
+                    return  # not a participant (e.g. learner or removed)
+                if self._leader_lease_valid():
+                    return
+                if not self._allow_launch_election():
+                    return
+                prev_leader = self.leader_id
+                self.leader_id = EMPTY_PEER
+                if not prev_leader.is_empty():
+                    self.fsm_caller.on_stop_following(prev_leader,
+                                                      self.current_term)
             await self._pre_vote()
 
     async def _persist_meta(self, term: int, voted_for: PeerId) -> None:
@@ -937,6 +983,21 @@ class Node:
         t = asyncio.ensure_future(direct())
         t.add_done_callback(lambda tt: tt.cancelled() or tt.exception())
 
+    def _solicit_votes(self, term: int, last_id: LogId, pre_vote: bool,
+                       on_resp) -> None:  # graftcheck: holds(_lock)
+        """One (pre-)vote request to every other voter of the current
+        and the old configuration."""
+        conf, old_conf = self.conf_entry.conf, self.conf_entry.old_conf
+        with TRACER.section("raft.election"):
+            for p in set(conf.peers) | set(old_conf.peers):
+                if p != self.server_id:
+                    req = RequestVoteRequest(
+                        group_id=self.group_id,
+                        server_id=str(self.server_id), peer_id=str(p),
+                        term=term, last_log_index=last_id.index,
+                        last_log_term=last_id.term, pre_vote=pre_vote)
+                    self._send_vote(p, req, on_resp)
+
     async def _pre_vote(self) -> None:  # graftcheck: holds(_lock)
         """Pre-vote: probe electability WITHOUT bumping term (symmetric-
         partition tolerance — reference: NodeImpl#preVote)."""
@@ -952,11 +1013,14 @@ class Node:
             await self._elect_self()
             return
         req_term = term + 1  # NOT persisted
+        yielded = False
 
         async def on_resp(resp: RequestVoteResponse, peer: PeerId):
+            nonlocal yielded
             async with self._lock:
-                if (self.state != State.FOLLOWER or self.current_term != term):
-                    return  # world moved on
+                if (self.state != State.FOLLOWER or self.current_term != term
+                        or yielded):
+                    return  # world moved on, or this round gave way
                 if resp.term > self.current_term:
                     await self._step_down(resp.term, Status.error(
                         RaftError.EHIGHERTERMRESPONSE, "pre-vote response"))
@@ -964,16 +1028,13 @@ class Node:
                 if resp.granted:
                     ctx.grant(peer)
                     if ctx.is_granted():
+                        if self._yields_to_rival(req_term):
+                            yielded = True
+                            self._ctrl.note_election_yielded()
+                            return
                         await self._elect_self()
 
-        for p in set(conf.peers) | set(old_conf.peers):
-            if p != self.server_id:
-                req = RequestVoteRequest(
-                    group_id=self.group_id, server_id=str(self.server_id),
-                    peer_id=str(p), term=req_term,
-                    last_log_index=last_id.index, last_log_term=last_id.term,
-                    pre_vote=True)
-                self._send_vote(p, req, on_resp)
+        self._solicit_votes(req_term, last_id, True, on_resp)
 
     async def _elect_self(self) -> None:  # graftcheck: holds(_lock)
         """Real election: term+1, vote for self, solicit votes.
@@ -981,15 +1042,17 @@ class Node:
         conf, old_conf = self.conf_entry.conf, self.conf_entry.old_conf
         if not self.conf_entry.contains(self.server_id):
             return
-        LOG.info("%s starting election at term %d", self, self.current_term + 1)
-        RECORDER.record("election_start", self.group_id,
-                        node=str(self.server_id),
-                        term=self.current_term + 1)
-        self.state = State.CANDIDATE
-        self._ctrl.on_candidate()
-        self.current_term += 1
-        self.voted_for = self.server_id
-        self.leader_id = EMPTY_PEER
+        with TRACER.section("raft.election"):
+            LOG.info("%s starting election at term %d", self,
+                     self.current_term + 1)
+            RECORDER.record("election_start", self.group_id,
+                            node=str(self.server_id),
+                            term=self.current_term + 1)
+            self.state = State.CANDIDATE
+            self._ctrl.on_candidate()
+            self.current_term += 1
+            self.voted_for = self.server_id
+            self.leader_id = EMPTY_PEER
         try:
             await self._persist_meta(self.current_term, self.server_id)
         except Exception:
@@ -1023,19 +1086,14 @@ class Node:
                 if resp.granted and self._ctrl.grant_vote(peer):
                     await self._become_leader()
 
-        for p in set(conf.peers) | set(old_conf.peers):
-            if p != self.server_id:
-                req = RequestVoteRequest(
-                    group_id=self.group_id, server_id=str(self.server_id),
-                    peer_id=str(p), term=term,
-                    last_log_index=last_id.index, last_log_term=last_id.term,
-                    pre_vote=False)
-                self._send_vote(p, req, on_resp)
+        self._solicit_votes(term, last_id, False, on_resp)
 
     async def _handle_vote_timeout(self) -> None:
         async with self._lock:
             if self.state != State.CANDIDATE:
                 return
+            # a vote round that ended with no winner
+            self._ctrl.note_vote_round_lost()
             if self.options.raft_options.step_down_when_vote_timedout:
                 self._ctrl.stop_vote_wait()
                 await self._step_down(self.current_term, Status.error(
@@ -1075,6 +1133,10 @@ class Node:
 
     async def _become_leader(self) -> None:  # graftcheck: holds(_lock)
         """Caller holds the lock; we are CANDIDATE with a vote quorum."""
+        with TRACER.section("raft.election"):
+            self._become_leader_locked()
+
+    def _become_leader_locked(self) -> None:  # graftcheck: holds(_lock)
         self.state = State.LEADER
         self.leader_id = self.server_id
         self._ctrl.on_leader()
@@ -1291,7 +1353,8 @@ class Node:
             # a lease-fresh quorum
             self._ctrl.note_activity()
             if req.pre_vote:
-                return self._handle_pre_vote(req, candidate)
+                with TRACER.section("raft.election"):
+                    return self._handle_pre_vote(req, candidate)
             # real vote
             if req.term < self.current_term:
                 return RequestVoteResponse(term=self.current_term, granted=False)
@@ -1358,8 +1421,48 @@ class Node:
         # pre-votes against itself
         if self._believes_leader_alive():
             return RequestVoteResponse(term=self.current_term, granted=False)
+        if (req.term == self.current_term and not self.voted_for.is_empty()
+                and self.voted_for != candidate):
+            # the term the candidate would campaign in is one this node
+            # has voted in already (as a rule for itself: it is a
+            # candidate of that term): the real vote would be refused,
+            # and a granted pre-vote would only make a second candidate
+            # of the term, whose round nobody can win
+            return RequestVoteResponse(term=self.current_term, granted=False)
         granted = self._candidate_log_up_to_date(req)
+        if granted:
+            # of the candidates granted for one term the highest-ranked
+            # is the one a crossing pre-vote of this node's gives way to
+            held = self._prevote_granted
+            if (held is None or held[1] != req.term
+                    or str(held[0]) <= str(candidate)):
+                self._prevote_granted = (candidate, req.term,
+                                         self._clock.monotonic())
         return RequestVoteResponse(term=self.current_term, granted=granted)
+
+    def _yields_to_rival(self, term: int) -> bool:  # graftcheck: holds(_lock)
+        """This node's pre-vote for ``term`` has its quorum; but has it
+        itself just granted a pre-vote for the same term to a peer that
+        ranks above it?  Then both hold each other's grant (their
+        timeouts fell inside one round trip, as in the burst after a
+        store with thousands of leaders dies), both would become
+        candidates of ``term``, vote for themselves, and wait out a
+        whole election timeout for nothing.  The lower one yields: it
+        stays a follower, grants the other's vote request when it
+        comes, and campaigns at its next timeout if nothing came: a
+        grant is yielded to ONCE, so a rival that died, or missed its
+        own quorum (a chain A < B < C of five voters), costs one
+        timeout and not one a grant.  The rank is the order of the
+        peers' strings (":10" < ":9"): any total order does, since all
+        it has to give is that no two nodes yield to each other, so one
+        of a crossing pair always goes on."""
+        rival, self._prevote_granted = self._prevote_granted, None
+        if rival is None:
+            return False
+        peer, rival_term, when = rival
+        eto_s = self.options.election_timeout_ms / 1000.0
+        return (rival_term == term and str(peer) > str(self.server_id)
+                and self._clock.monotonic() - when < eto_s)
 
     def _candidate_log_up_to_date(self, req: RequestVoteRequest) -> bool:
         last = self.log_manager.last_log_id()

@@ -81,6 +81,11 @@ class ReadOnlyService:
         }
 
     async def shutdown(self) -> None:
+        self.close()
+
+    def close(self) -> None:
+        """Fail what waits and cancel the rounds; nothing is awaited, so
+        a crash (``Node.crash``) calls this too."""
         for fut in self._pending + self._fwd_pending:
             if not fut.done():
                 fut.set_exception(

@@ -326,7 +326,8 @@ class RheaKVStore:
                  batching: Optional[BatchingOptions] = None,
                  read_preference: str = "leader",
                  read_from: str = "",
-                 jitter_seed: Optional[int] = None):
+                 jitter_seed: Optional[int] = None,
+                 op_deadline_ms: Optional[float] = None):
         if read_preference not in ("leader", "any"):
             raise ValueError(f"read_preference {read_preference!r} "
                              "(must be 'leader' or 'any')")
@@ -350,6 +351,19 @@ class RheaKVStore:
         self.timeout_ms = timeout_ms
         self.max_retries = max_retries
         self.retry_interval_ms = retry_interval_ms
+        # the retry budget as a time: with a deadline an operation is
+        # retried until it has passed, however many bounces that is (a
+        # count of eight is spent 2 s into an election timeout of 10);
+        # without one, max_retries attempts as ever
+        self.op_deadline_ms = op_deadline_ms
+        # region id -> future of the one batch that is probing a region
+        # whose stores all bounced (no leader yet): every other batch
+        # with items for it waits for that probe instead of sending its
+        # own, so an outage costs one probe a region a backoff, not one
+        # a waiting operation
+        self._probes: dict[int, asyncio.Future] = {}
+        self.probe_waits = 0      # batches that waited on another's probe
+        self.retry_cycles = 0     # attempt cycles after an item's first
         # seeded jitter on every outer retry backoff: a bounced
         # 256-worker batch re-probing in lockstep is a synchronized
         # retry herd that a gray (slow-but-alive) leader turns into a
@@ -483,9 +497,34 @@ class RheaKVStore:
     def _backoff_s(self, attempt: int) -> float:
         """Outer retry backoff: linear schedule x seeded jitter in
         [0.5, 1.5) — bounced herds spread instead of re-probing in
-        lockstep."""
-        return (self.retry_interval_ms * (attempt + 1)
+        lockstep.  It stops growing at what the last of max_retries
+        attempts waits: a client with a deadline makes more attempts
+        than that."""
+        return (self.retry_interval_ms * min(attempt + 1, self.max_retries)
                 * (0.5 + self._backoff_rng.random()) / 1000.0)
+
+    def _budget(self):
+        """``spent(attempts_made)`` for one operation or batch: by the
+        clock where the client has a deadline (then ``spent.left_s()``
+        is what is left of it), else by the count."""
+        if self.op_deadline_ms is None:
+            def spent(attempts: int) -> bool:
+                return attempts >= self.max_retries
+            # a wait on another batch's probe counts as one attempt: at
+            # most the longest backoff
+            spent.left_s = lambda: (self.retry_interval_ms
+                                    * self.max_retries / 1000.0)
+            return spent
+        loop = asyncio.get_running_loop()
+        # graftcheck: allow(raw-clock) — client-side retry budget: the CALLER's real deadline
+        give_up = loop.time() + self.op_deadline_ms / 1000.0
+
+        def spent(attempts: int) -> bool:
+            # graftcheck: allow(raw-clock) — client-side retry budget: the CALLER's real deadline
+            return loop.time() >= give_up
+        # graftcheck: allow(raw-clock) — client-side retry budget: the CALLER's real deadline
+        spent.left_s = lambda: max(0.0, give_up - loop.time())
+        return spent
 
     def _note_ep_latency(self, endpoint: str, dur_s: float) -> None:
         ms = dur_s * 1000.0
@@ -630,65 +669,120 @@ class RheaKVStore:
         round (the round's route cache — invalidated only through the
         retry path on epoch/region errors), group regions by leader
         store into kv_command_batch RPCs, deliver per-item results, and
-        re-shard ONLY the failed/escaped items after a refresh."""
+        re-shard ONLY the failed/escaped items after a refresh.
+
+        A region whose every store bounced has no leader yet.  The
+        first batch to find that out probes it, backing off between
+        attempts; every other batch holds its items for that region and
+        waits for the probe (``_probes``), so the wait for an election
+        costs one probe a region, and the items go out the moment a
+        leader answers."""
         pending = list(chunk)
         last = Status.error(RaftError.EAGAIN, "exhausted retries")
-        for attempt in range(self.max_retries):
-            groups: dict[int, tuple[Region, list]] = {}
-            unroutable: list = []
-            sec = TRACER.enter("client.send") if TRACER.enabled else None
-            try:
-                for item, fut in pending:
-                    try:
-                        r = self.route_table.find_region_by_key(
-                            key_fn(item))
-                    except Exception as e:  # noqa: BLE001 — malformed
-                        # key: fail ITS caller, not the whole chunk
-                        if not fut.done():
-                            fut.set_exception(RheaKVError(Status.error(
-                                RaftError.EINVAL, f"malformed key: {e!r}")))
-                        continue
-                    if r is None:
-                        unroutable.append((item, fut))
-                    else:
-                        groups.setdefault(r.id, (r, []))[1].append(
-                            (item, fut))
-                parts = list(groups.values())
-                region_ops = [(region, op_fn(items))
-                              for region, items in parts]
-            finally:
-                if sec is not None:
-                    TRACER.leave(sec)
-            retry: list = list(unroutable)
-            need_refresh = bool(unroutable)
-            outcomes = await self._dispatch_region_ops(region_ops, attempt)
-            sec = TRACER.enter("client.deliver") if TRACER.enabled else None
-            try:
-                for (region, items), out in zip(parts, outcomes):
-                    if isinstance(out, tuple):
-                        deliver(items, out[1])
-                    elif isinstance(out, _Retry):
-                        need_refresh = need_refresh or out.refresh
-                        if out.status is not None:
-                            last = out.status
-                        retry.extend(items)
-                    else:   # hard error fails ITS region's calls only
-                        for _, fut in items:
+        spent = self._budget()
+        mine: set[int] = set()      # regions this batch probes
+        attempt = 0
+        try:
+            while True:
+                groups: dict[int, tuple[Region, list]] = {}
+                unroutable: list = []
+                held: list = []
+                waits: set = set()
+                sec = TRACER.enter("client.send") if TRACER.enabled else None
+                try:
+                    for item, fut in pending:
+                        try:
+                            r = self.route_table.find_region_by_key(
+                                key_fn(item))
+                        except Exception as e:  # noqa: BLE001 — malformed
+                            # key: fail ITS caller, not the whole chunk
                             if not fut.done():
-                                fut.set_exception(out)
-            finally:
-                if sec is not None:
-                    TRACER.leave(sec)
-            if not retry:
-                return
-            pending = retry
-            if need_refresh:
-                await self._refresh_routes()
-            await asyncio.sleep(self._backoff_s(attempt))
+                                fut.set_exception(RheaKVError(Status.error(
+                                    RaftError.EINVAL,
+                                    f"malformed key: {e!r}")))
+                            continue
+                        if r is None:
+                            unroutable.append((item, fut))
+                            continue
+                        probe = self._probes.get(r.id)
+                        if probe is not None and r.id not in mine:
+                            held.append((item, fut))
+                            waits.add(probe)
+                        else:
+                            groups.setdefault(r.id, (r, []))[1].append(
+                                (item, fut))
+                    parts = list(groups.values())
+                    region_ops = [(region, op_fn(items))
+                                  for region, items in parts]
+                finally:
+                    if sec is not None:
+                        TRACER.leave(sec)
+                retry: list = list(unroutable)
+                need_refresh = bool(unroutable)
+                outcomes = await self._dispatch_region_ops(
+                    region_ops, attempt) if region_ops else []
+                sec = TRACER.enter("client.deliver") if TRACER.enabled \
+                    else None
+                try:
+                    for (region, items), out in zip(parts, outcomes):
+                        if isinstance(out, tuple):
+                            self._end_probe(region.id, mine)
+                            deliver(items, out[1])
+                        elif isinstance(out, _Retry):
+                            need_refresh = need_refresh or out.refresh
+                            if out.status is not None:
+                                last = out.status
+                            retry.extend(items)
+                            if out.refresh:
+                                self._end_probe(region.id, mine)
+                            elif region.id not in self._probes:
+                                self._probes[region.id] = asyncio \
+                                    .get_running_loop().create_future()
+                                mine.add(region.id)
+                        else:   # hard error fails ITS region's calls only
+                            self._end_probe(region.id, mine)
+                            for _, fut in items:
+                                if not fut.done():
+                                    fut.set_exception(out)
+                finally:
+                    if sec is not None:
+                        TRACER.leave(sec)
+                pending = retry + held
+                if not pending:
+                    return
+                attempt += 1
+                if spent(attempt):
+                    break
+                if need_refresh:
+                    await self._refresh_routes()
+                if retry:
+                    self.retry_cycles += 1
+                    await asyncio.sleep(self._backoff_s(attempt - 1))
+                else:
+                    # nothing of its own to ask again: until a probe
+                    # another batch runs has ended, one way or the other
+                    # (a batch that goes ends its probes: it wakes no
+                    # sooner, thousands wait through an election)
+                    self.probe_waits += 1
+                    await asyncio.wait(
+                        waits, timeout=spent.left_s(),
+                        return_when=asyncio.FIRST_COMPLETED)
+        finally:
+            for rid in list(mine):
+                self._end_probe(rid, mine)
         err = RheaKVError(last)
         for _, fut in pending:
             if not fut.done():
                 fut.set_exception(err)
+
+    def _end_probe(self, region_id: int, mine: set) -> None:
+        """The batch that probed ``region_id`` has its answer (or goes):
+        whoever waited for it asks for itself now."""
+        if region_id in mine:
+            mine.discard(region_id)
+            probe = self._probes.pop(region_id, None)
+            if probe is not None and not probe.done():
+                probe.set_result(None)
 
     async def _flush_put_batch(self, chunk) -> None:
         def deliver(items, result):
@@ -951,7 +1045,10 @@ class RheaKVStore:
 
     async def _execute_traced(self, key: bytes, op: KVOperation):
         last = Status.error(RaftError.EAGAIN, "exhausted retries")
-        for attempt in range(self.max_retries):
+        spent = self._budget()
+        attempt = -1
+        while not spent(attempt + 1):
+            attempt += 1
             region = self.route_table.find_region_by_key(key)
             if region is None:
                 await self._refresh_routes()

@@ -472,6 +472,10 @@ class ReadConfirmBatcher:
         return await fut
 
     async def shutdown(self) -> None:
+        self.close()
+
+    def close(self) -> None:
+        """Nothing is awaited: a crash (``StoreEngine.crash``) calls it."""
         for _node, fut in self._pending:
             if not fut.done():
                 fut.set_result(False)
@@ -990,6 +994,28 @@ class StoreEngine:
             hists["log_round_groups"] = rounds.round_groups
             hists["log_round_inline"] = rounds.round_inline
 
+    def _stop_background(self) -> None:
+        """What ``shutdown`` and ``crash`` both stop, none of it
+        awaited: the store's own tasks, its probes' registrations and
+        the two store-wide batchers."""
+        from tpuraft.util import describer
+
+        for name in ("_heartbeat_task", "_health_task", "_gc_settle_task"):
+            task = getattr(self, name)
+            if task is not None:
+                task.cancel()
+                setattr(self, name, None)
+        if self.health is not None:
+            self.health.loop_lag.stop()
+        for probe in (self.heat, self.health, self.disk_budget,
+                      self.read_batcher, self.append_batcher):
+            if probe is not None:
+                describer.unregister(probe)
+        if self.read_batcher is not None:
+            self.read_batcher.close()
+        if self.append_batcher is not None:
+            self.append_batcher.close()
+
     async def shutdown(self) -> None:
         self._started = False
         if self._metrics_httpd is not None:
@@ -999,38 +1025,7 @@ class StoreEngine:
             # poll interval, so hop off the event loop for it
             await asyncio.get_running_loop().run_in_executor(
                 None, httpd.shutdown_blocking)
-        if self.heat is not None:
-            from tpuraft.util import describer
-
-            describer.unregister(self.heat)
-        if self._heartbeat_task is not None:
-            self._heartbeat_task.cancel()
-            self._heartbeat_task = None
-        if self._health_task is not None:
-            self._health_task.cancel()
-            self._health_task = None
-        if self._gc_settle_task is not None:
-            self._gc_settle_task.cancel()
-            self._gc_settle_task = None
-        if self.health is not None:
-            from tpuraft.util import describer
-
-            self.health.loop_lag.stop()
-            describer.unregister(self.health)
-        if self.disk_budget is not None:
-            from tpuraft.util import describer
-
-            describer.unregister(self.disk_budget)
-        if self.read_batcher is not None:
-            from tpuraft.util import describer
-
-            describer.unregister(self.read_batcher)
-            await self.read_batcher.shutdown()
-        if self.append_batcher is not None:
-            from tpuraft.util import describer
-
-            describer.unregister(self.append_batcher)
-            await self.append_batcher.shutdown()
+        self._stop_background()
         for engine in list(self._regions.values()):
             await engine.shutdown()
         self._regions.clear()
@@ -1045,6 +1040,49 @@ class StoreEngine:
         close = getattr(self.raw_store, "close", None)
         if close is not None:
             close()  # native engine: flush + release the WAL fd
+        if self._meta_journal is not None:
+            from tpuraft.storage.meta_multilog import _release_journal
+
+            _release_journal(self._meta_journal)
+            self._meta_journal = None
+
+    def crash(self) -> None:
+        """Lose the store as a crash of its process would lose it: no
+        region is shut down, nothing staged is flushed, no peer and no
+        client is told, and nothing is awaited, so the loop the
+        survivors share is held for this call and no longer.  The caller
+        takes the endpoint off the network FIRST (a dead process
+        answers nothing).  What an in-process cluster shares with the
+        store is given up: whoever is parked in one of its handlers is
+        answered as a reset connection would answer, its tasks and
+        timers stop, and its files are closed unflushed (what was
+        ``write()``n stays with the operating system, as after a kill)
+        so that a successor can open them.  ``shutdown()`` is the
+        orderly way."""
+        self._started = False
+        if self._metrics_httpd is not None:
+            # its own thread: told to stop, not waited for
+            threading.Thread(target=self._metrics_httpd.shutdown_blocking,
+                             daemon=True).start()
+            self._metrics_httpd = None
+        self._stop_background()
+        engine = self.multi_raft_engine
+        if engine is not None:
+            engine.crash()
+        for region in self._regions.values():
+            if region.node is not None:
+                region.node.crash()
+                self.node_manager.remove(region.node)
+        self._regions.clear()
+        self.node_manager.send_plane.shutdown()
+        if self._gc_counted:
+            self._gc_counted = False
+            _gc_store_down()
+        # the open apply round is NOT written: its entries were never
+        # acknowledged as applied, and the log has them
+        close = getattr(self.raw_store, "close", None)
+        if close is not None:
+            close()
         if self._meta_journal is not None:
             from tpuraft.storage.meta_multilog import _release_journal
 

@@ -216,10 +216,22 @@ class InProcNetwork:
             raise RpcError(
                 Status.error(RaftError.EHOSTDOWN, f"{dst} unreachable from {src}"))
         try:
-            return await asyncio.wait_for(
+            response = await asyncio.wait_for(
                 server.dispatch(method, request), timeout_ms / 1000.0)
         except asyncio.TimeoutError:
             raise RpcError(Status.error(RaftError.ETIMEDOUT, f"{method} to {dst}"))
+        except Exception:
+            if server.running and dst not in self._down:
+                raise
+            response = None
+        if not server.running or dst in self._down:
+            # the endpoint went down under the call: whatever its
+            # handler made of it (an answer, or an error from a store
+            # that crashed around it) never left the dead process; the
+            # caller sees the connection go
+            raise RpcError(Status.error(
+                RaftError.EHOSTDOWN, f"{dst} went down during {method}"))
+        return response
 
 
     def _route(self, src: str, dst: str, method: str, request: Any,

@@ -172,6 +172,16 @@ class LogManager:
         self._wake_waiters(error=True)
         self._storage.shutdown()
 
+    def abandon(self) -> None:
+        """A crash: nothing staged is flushed and no round is waited
+        for; the group's reference to the store's journal goes, so that
+        a successor can open the files."""
+        self._stopped = True
+        if self._flusher is not None and not self._flusher.done():
+            self._flusher.cancel()
+        self._wake_waiters(error=True)
+        self._storage.shutdown()
+
     # -- queries ------------------------------------------------------------
 
     def first_log_index(self) -> int:

@@ -55,6 +55,7 @@ up to* the incident survives ring churn.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import random
 import threading
@@ -243,6 +244,18 @@ class Tracer:
         if t1 - self._bucket_t0 >= 1.0 and self.enabled:
             self._roll(t1)
 
+    @contextlib.contextmanager
+    def section(self, name: str):
+        """``with TRACER.section(name):`` around a stretch with no
+        ``await`` in it, for paths too cold to spell enter and leave
+        out (elections); nothing happens while tracing is off."""
+        frame = self.enter(name) if self.enabled else None
+        try:
+            yield
+        finally:
+            if frame is not None:
+                self.leave(frame)
+
     def switch(self, frame: list, name: str, now: float = 0.0) -> list:
         """Leave ``frame`` and enter ``name`` at one instant."""
         now = now or _pc()
@@ -379,6 +392,11 @@ class Tracer:
         else:
             self.ops_dropped += 1
         return dur
+
+    def abandon_op(self, tid: int) -> None:
+        """An op that will never end (an election somebody else won):
+        its staging goes, nothing is recorded."""
+        self._staged.pop(tid, None)
 
     def span(self, tid: int, name: str, t0: float, t1: float,
              proc: str = "", **args) -> None:
