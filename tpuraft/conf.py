@@ -191,3 +191,11 @@ class ConfigurationManager:
         if self._configurations:
             return self._configurations[-1].copy()
         return self._snapshot.copy()
+
+    def last_id(self) -> LogId:
+        """The id of :meth:`last`, without the copy: for a caller that
+        only asks whether the last configuration is still the one it
+        holds (a follower, once an append)."""
+        if self._configurations:
+            return self._configurations[-1].id
+        return self._snapshot.id

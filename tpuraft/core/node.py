@@ -60,6 +60,26 @@ from tpuraft.util.timer import RepeatedTimer
 LOG = logging.getLogger(__name__)
 
 
+# what Node._begin_append answers where the node has to step down
+# before the append can go on
+_STEP_DOWN = "step_down"
+_LEADER_CONFLICT = "leader_conflict"
+
+
+class _Appending:
+    """A follower's append between its begin and its finish: the
+    entries it journals, the log round they ride (None: the log has to
+    wait before it stages) and the log's verdict once it is known."""
+
+    __slots__ = ("entries", "tr0", "ride", "ok")
+
+    def __init__(self, entries: list, tr0: float, ride, ok: Optional[bool]):
+        self.entries = entries
+        self.tr0 = tr0      # perf_counter at the begin, traced appends only
+        self.ride = ride
+        self.ok = ok
+
+
 class State(enum.Enum):
     UNINITIALIZED = "uninitialized"
     FOLLOWER = "follower"
@@ -1470,130 +1490,203 @@ class Node:
 
     async def handle_append_entries(self, req: AppendEntriesRequest
                                     ) -> AppendEntriesResponse:
+        """An AppendEntries, with the waits it may need: the begin, a
+        step-down or the log's own waits where the begin cannot go on
+        without one, the log round the entries ride, the finish.
+        ``NodeManager._handle_store_append`` runs the same begin and
+        finish for every row of a store-wide round in its own turn."""
         server = PeerId.parse(req.server_id)
         # capability advertisement (VERDICT r2 #6): this endpoint serves
         # multi_heartbeat iff it runs a NodeManager
         mh = self.node_manager is not None
         async with self._lock:
-            if self.state in (State.SHUTTING, State.SHUTDOWN, State.ERROR,
-                              State.UNINITIALIZED):
-                # NOT a protocol response: a success=False/last=0 reply
-                # here reads as "my log is empty" and drives the leader
-                # into a full-speed probe livelock at next_index=1.  An
-                # RPC error takes the leader's paced-retry path instead.
-                raise RpcError(Status.error(
-                    RaftError.EHOSTDOWN, f"node not serviceable: "
-                    f"{self.state.value}"))
-            if req.term < self.current_term:
-                return AppendEntriesResponse(
-                    multi_hb=mh,
-                    term=self.current_term, success=False,
-                    last_log_index=self.log_manager.last_log_index())
-            if req.term > self.current_term or self.state != State.FOLLOWER:
+            sec = TRACER.enter("raft.follower") if TRACER.enabled else None
+            try:
+                began = self._begin_append(req, server, mh)
+            finally:
+                if sec is not None:
+                    TRACER.leave(sec)
+            if began is _STEP_DOWN:
                 await self._step_down(req.term, Status.error(
                     RaftError.EHIGHERTERMREQUEST,
                     f"append_entries from {server}"), new_leader=server)
-            if self.leader_id.is_empty():
-                self.leader_id = server
-                self.fsm_caller.on_start_following(server, req.term)
-            elif self.leader_id != server:
+                began = self._begin_append(req, server, mh)
+            if began is _LEADER_CONFLICT:
                 # two leaders in one term: protocol violation
                 LOG.error("%s: leader conflict %s vs %s at term %d", self,
                           self.leader_id, server, req.term)
                 await self._step_down(req.term + 1, Status.error(
                     RaftError.ELEADERCONFLICT, "two leaders in one term"))
-                return AppendEntriesResponse(
-                    multi_hb=mh,
-                    term=self.current_term, success=False,
-                    last_log_index=self.log_manager.last_log_index())
-            lm = self.log_manager
+                return self._append_response(mh, False)
+            if began.__class__ is not _Appending:
+                return began
+            ride = began.ride
+            if ride is None:
+                # the log has to wait before it can stage: a suffix to
+                # truncate, or a storage with no shared round
+                try:
+                    began.ok = await self.log_manager.append_entries_follower(
+                        req.prev_log_index, req.prev_log_term, began.entries)
+                except RaftException as e:
+                    return self._append_failed(mh, e)
+            else:
+                try:
+                    await ride.future
+                except Exception:   # noqa: BLE001 — ride.error has it
+                    pass
             sec = TRACER.enter("raft.follower") if TRACER.enabled else None
             try:
-                self._last_leader_timestamp = self._clock.monotonic()
-                self._ctrl.note_leader_contact()
-                # an incoming full-semantics append (entries, probe, or
-                # classic beat) means the leader is ACTIVE: a quiescent
-                # follower wakes — heals the asymmetric state left by
-                # an aborted quiesce handshake within one beat instead
-                # of one store-lease expiry
-                self._ctrl.note_activity()
-                if not req.entries:
-                    return self._answer_probe(req, mh)
-                if self._note_append_start is not None:
-                    self._note_append_start(req.term)
-                entries = list(req.entries)
-                if self.options.witness:
-                    # metadata-only journal: strip any payload that
-                    # still arrived full (a mixed-fleet leader that
-                    # predates witness-aware stripping) — CRC-verify the
-                    # wire blob FIRST so a corrupt frame can't journal
-                    # bad metadata
-                    from tpuraft.entity import strip_entry_payload
-
-                    entries = [strip_entry_payload(e) for e in entries]
-                # trace plane: wire-borne contexts join the
-                # follower-side append (incl. its fsync wait) to the
-                # originating trace
-                tr0 = 0.0
-                if TRACER.enabled and req.trace_ctx:
-                    adopt_entry_ctx(entries, req.trace_ctx)
-                    tr0 = time.perf_counter()
+                return self._finish_append(req, mh, began)
             finally:
                 if sec is not None:
                     TRACER.leave(sec)
+
+    def _try_lock(self) -> bool:
+        """Take the node's lock as an uncontended ``async with
+        self._lock`` does, without a coroutine: False where somebody
+        holds it or waits for it (a waiter just woken included: the
+        lock is its).  Reads ``asyncio.Lock``'s two fields, which
+        ``tests/test_follower_round.py`` pins."""
+        lock = self._lock
+        if lock._locked or lock._waiters:
+            return False
+        lock._locked = True
+        return True
+
+    def _append_response(self, mh: bool, success: bool
+                         ) -> AppendEntriesResponse:
+        return AppendEntriesResponse(
+            multi_hb=mh, term=self.current_term, success=success,
+            last_log_index=self.log_manager.last_log_index())
+
+    # graftcheck: holds(_lock)
+    def _begin_append(self, req: AppendEntriesRequest, server: PeerId,
+                      mh: bool, now: Optional[float] = None):
+        """An AppendEntries up to its first wait, in the caller's turn.
+        Returns the response where none is needed; an :class:`_Appending`
+        where there are entries to journal (``ride``: the log round they
+        are staged in, to be followed by :meth:`_finish_append` once its
+        future is done; None: the log has to wait before it can stage
+        and nothing of it has changed); ``_STEP_DOWN`` or
+        ``_LEADER_CONFLICT`` where the node has to step down first, with
+        nothing changed at all.  ``now`` is a reading of this node's
+        clock the caller already took."""
+        if self.state in (State.SHUTTING, State.SHUTDOWN, State.ERROR,
+                          State.UNINITIALIZED):
+            # NOT a protocol response: a success=False/last=0 reply
+            # here reads as "my log is empty" and drives the leader
+            # into a full-speed probe livelock at next_index=1.  An
+            # RPC error takes the leader's paced-retry path instead.
+            raise RpcError(Status.error(
+                RaftError.EHOSTDOWN, f"node not serviceable: "
+                f"{self.state.value}"))
+        if req.term < self.current_term:
+            return self._append_response(mh, False)
+        if req.term > self.current_term or self.state != State.FOLLOWER:
+            return _STEP_DOWN
+        if self.leader_id.is_empty():
+            self.leader_id = server
+            self.fsm_caller.on_start_following(server, req.term)
+        elif self.leader_id != server:
+            return _LEADER_CONFLICT
+        self._last_leader_timestamp = (
+            self._clock.monotonic() if now is None else now)
+        self._ctrl.note_leader_contact()
+        # an incoming full-semantics append (entries, probe, or
+        # classic beat) means the leader is ACTIVE: a quiescent
+        # follower wakes — heals the asymmetric state left by
+        # an aborted quiesce handshake within one beat instead
+        # of one store-lease expiry
+        self._ctrl.note_activity()
+        if not req.entries:
+            return self._answer_probe(req, mh)
+        if self._note_append_start is not None:
+            self._note_append_start(req.term)
+        entries = list(req.entries)
+        if self.options.witness:
+            # metadata-only journal: strip any payload that
+            # still arrived full (a mixed-fleet leader that
+            # predates witness-aware stripping) — CRC-verify the
+            # wire blob FIRST so a corrupt frame can't journal
+            # bad metadata
+            from tpuraft.entity import strip_entry_payload
+
+            entries = [strip_entry_payload(e) for e in entries]
+        # trace plane: wire-borne contexts join the
+        # follower-side append (incl. its fsync wait) to the
+        # originating trace
+        tr0 = 0.0
+        if TRACER.enabled and req.trace_ctx:
+            adopt_entry_ctx(entries, req.trace_ctx)
+            tr0 = time.perf_counter()
+        try:
+            began = self.log_manager.begin_follower_append(
+                req.prev_log_index, req.prev_log_term, entries)
+        except RaftException as e:
+            return self._append_failed(mh, e)
+        if began is True or began is False:
+            return self._finish_append(
+                req, mh, _Appending(entries, tr0, None, began))
+        return _Appending(entries, tr0, began, None)
+
+    # graftcheck: holds(_lock)
+    def _append_failed(self, mh: bool, e: RaftException
+                       ) -> AppendEntriesResponse:
+        """The log refused or lost a follower's append."""
+        if e.status.code == RaftError.EIO:
+            # transient storage failure (ENOSPC/EIO flush): the
+            # entries were NOT journaled and NOT acked — reject
+            # the round so the leader backs off and retries.
+            # Once pressure clears (reclaim freed disk, burst
+            # healed) the retry lands; the replica must NOT be
+            # condemned to ERROR for a full volume.
+            return self._append_response(mh, False)
+        # conflict below the applied index: this replica's state
+        # machine has diverged from the leader's committed log —
+        # unrecoverable (only reachable through storage loss /
+        # amnesiac restart, which Raft does not tolerate).  Fail
+        # the node loudly (reference: NodeImpl#onError) instead
+        # of rejecting this RPC forever.  The FSM hears about it
+        # too (StateMachine#onError) via the caller queue; the
+        # ERROR transition itself happens now, under the lock,
+        # so no further RPC is served meanwhile.
+        self._enter_error_locked(e.status)
+        self.fsm_caller.poison(e.status)
+        raise RpcError(Status.error(
+            RaftError.EHOSTDOWN, f"node failed: {e.status}")) from e
+
+    # graftcheck: holds(_lock)
+    def _finish_append(self, req: AppendEntriesRequest, mh: bool,
+                       ap: _Appending) -> AppendEntriesResponse:
+        """An AppendEntries after its wait, if it had one: the log's
+        verdict (of the round the entries rode, which has landed, where
+        ``ap.ok`` is not there yet), and with it the follower's
+        membership, commit index and answer."""
+        lm = self.log_manager
+        ok = ap.ok
+        if ok is None:
             try:
-                ok = await lm.append_entries_follower(
-                    req.prev_log_index, req.prev_log_term, entries)
+                ok = lm.end_follower_append(ap.ride)
             except RaftException as e:
-                if e.status.code == RaftError.EIO:
-                    # transient storage failure (ENOSPC/EIO flush): the
-                    # entries were NOT journaled and NOT acked — reject
-                    # the round so the leader backs off and retries.
-                    # Once pressure clears (reclaim freed disk, burst
-                    # healed) the retry lands; the replica must NOT be
-                    # condemned to ERROR for a full volume.
-                    return AppendEntriesResponse(
-                        multi_hb=mh,
-                        term=self.current_term, success=False,
-                        last_log_index=lm.last_log_index())
-                # conflict below the applied index: this replica's state
-                # machine has diverged from the leader's committed log —
-                # unrecoverable (only reachable through storage loss /
-                # amnesiac restart, which Raft does not tolerate).  Fail
-                # the node loudly (reference: NodeImpl#onError) instead
-                # of rejecting this RPC forever.  The FSM hears about it
-                # too (StateMachine#onError) via the caller queue; the
-                # ERROR transition itself happens now, under the lock,
-                # so no further RPC is served meanwhile.
-                self._enter_error_locked(e.status)
-                self.fsm_caller.poison(e.status)
-                raise RpcError(Status.error(
-                    RaftError.EHOSTDOWN,
-                    f"node failed: {e.status}")) from e
-            if tr0:
-                t1 = time.perf_counter()
-                for e in entries:
-                    if e.trace_id:
-                        TRACER.span(e.trace_id, "follower_append", tr0, t1,
-                                    proc=self._trace_proc, ok=ok)
-            if not ok:
-                return AppendEntriesResponse(
-                    multi_hb=mh,
-                    term=self.current_term, success=False,
-                    last_log_index=lm.last_log_index())
-            self._refresh_conf_from_log()
-            self.ballot_box.set_last_committed_index(
-                min(req.committed_index,
-                    req.prev_log_index + len(req.entries)))
-            if self._note_attested is not None and \
-                    lm.last_log_index() == req.prev_log_index + len(req.entries):
-                # the append covered our tail: log is a verified prefix
-                # of the leader's (replica-plane attestation)
-                self._note_attested(req.term)
-            return AppendEntriesResponse(
-                multi_hb=mh,
-                term=self.current_term, success=True,
-                last_log_index=lm.last_log_index())
+                return self._append_failed(mh, e)
+        if ap.tr0:
+            t1 = time.perf_counter()
+            for e in ap.entries:
+                if e.trace_id:
+                    TRACER.span(e.trace_id, "follower_append", ap.tr0, t1,
+                                proc=self._trace_proc, ok=ok)
+        if not ok:
+            return self._append_response(mh, False)
+        self._refresh_conf_from_log()
+        self.ballot_box.set_last_committed_index(
+            min(req.committed_index,
+                req.prev_log_index + len(req.entries)))
+        if self._note_attested is not None and \
+                lm.last_log_index() == req.prev_log_index + len(req.entries):
+            # the append covered our tail: log is a verified prefix
+            # of the leader's (replica-plane attestation)
+            self._note_attested(req.term)
+        return self._append_response(mh, True)
 
     def _answer_probe(self, req: AppendEntriesRequest, mh: bool) -> AppendEntriesResponse:  # graftcheck: holds(_lock)
         """An AppendEntries with no entries: heartbeat or probe."""
@@ -1628,6 +1721,13 @@ class Node:
             last_log_index=lm.last_log_index())
 
     def _refresh_conf_from_log(self) -> None:  # graftcheck: holds(_lock)
+        held, last_id = self.conf_entry.id, \
+            self.log_manager.conf_manager.last_id()
+        if last_id.index == held.index and last_id.term == held.term:
+            # a follower asks once an append, and but for the few that
+            # carried a configuration the answer is "the one we hold"
+            # (whose index lies inside the log: nothing to roll back)
+            return
         last = self.log_manager.conf_manager.last()
         if last.conf.is_empty():
             # no conf anywhere in log/snapshot: if ours came from a log

@@ -11,14 +11,36 @@ import asyncio
 import logging
 from typing import Optional
 
-from tpuraft.core.node import Node, State
+from tpuraft.core.node import (_LEADER_CONFLICT, _STEP_DOWN, Node, State,
+                               _Appending)
 from tpuraft.entity import PeerId
-from tpuraft.rpc.messages import BatchResponse, BeatAck
+from tpuraft.rpc.messages import (BatchResponse, BeatAck, ErrorResponse,
+                                  StoreAppendResponse)
 from tpuraft.errors import RaftError, Status
 from tpuraft.rpc.transport import RpcError, RpcServer
+from tpuraft.util.metrics import Histogram
 from tpuraft.util.trace import TRACER as _TRACE
 
 LOG = logging.getLogger(__name__)
+
+
+def _row_error(exc: Exception) -> ErrorResponse:
+    """What one row of a store_append round answers when serving it
+    raised: the RPC error's own status, else EINTERNAL (one bad row
+    only: the round's other rows are served)."""
+    if isinstance(exc, RpcError):
+        return ErrorResponse(exc.status.code, exc.status.error_msg)
+    LOG.error("store_append row failed", exc_info=exc)
+    return ErrorResponse(int(RaftError.EINTERNAL), repr(exc))
+
+
+def _repeated(keys: list) -> set:
+    """The keys that occur more than once."""
+    seen: set = set()
+    twice: set = set()
+    for key in keys:
+        (twice if key in seen else seen).add(key)
+    return twice
 
 
 class NodeManager:
@@ -63,6 +85,12 @@ class NodeManager:
         # snapshot load) must not accumulate one shielded handler —
         # each carrying a full entry window — per leader retry cycle
         self._append_inflight: set[tuple[str, str]] = set()
+        # events, one sample each, so a window's ``count`` is the
+        # number: rows of store_append RPCs served, and of them the rows
+        # begun and finished in the handler's own turns (the rest
+        # waited: the per-node coroutine, or a deadline that passed)
+        self.follower_rows = Histogram()
+        self.follower_rows_inline = Histogram()
 
     @property
     def heartbeat_hub(self):
@@ -196,94 +224,171 @@ class NodeManager:
             items=await self._serve_append_items(request.items))
 
     async def _handle_store_append(self, request):
-        """AppendBatcher's store-wide append round: per-node in-order
-        execution like ``multi_append``, but LEAN — one task per node
-        run and direct awaits per row instead of the per-item
-        shield/wait_for pair.  The per-item EBUSY budget moves to the
-        node run: a node that cannot finish its rows within half an
-        election timeout answers EBUSY for the unserved tail (the
-        handler itself keeps running shielded — cancelling a
-        mid-flush append would tear durability ordering).  At region
-        density the per-item timer+task machinery was a measurable
-        slice of the loop's saturated write path; rounds are already
-        windowed sender-side, so the receiver doesn't need a second
-        layer of per-item pacing."""
-        from tpuraft.rpc.messages import ErrorResponse, StoreAppendResponse
+        """AppendBatcher's store-wide append round, served in this
+        coroutine's own turns: BEGIN every row (its node's lock taken
+        without waiting, ``Node._begin_append``: checks, leader contact,
+        the probe's answer, the entries staged into the log's flush
+        round of this turn), AWAIT the round once under one deadline for
+        the RPC, FINISH every row (``Node._finish_append``, the lock
+        released).  No task, future, shield or timer a group: all the
+        rows of one turn ride one log round, so there is one future to
+        wait for.
 
+        A row that cannot be begun without waiting takes the per-node
+        coroutine (``_run_node_rows``: ``handle_append_entries`` row by
+        row, in batch order) beside the others, and is awaited with
+        them: a node whose lock is held or waited for, a node that has
+        to step down first (a higher term, not a follower, a leader
+        conflict), a log that has to truncate a suffix or has no shared
+        round, a node with more than one row in this RPC.
+
+        The deadline is half the shortest election timeout among the
+        RPC's nodes.  When it passes, every row not finished answers
+        EBUSY and the reply leaves; such a row's finish still runs when
+        its round lands, as a callback on the round's future (the
+        entries are staged: cancelling would tear durability ordering),
+        releases the lock and the ``_append_inflight`` claim, and no
+        longer touches the reply.  A node with a claim outstanding
+        answers EBUSY at once, as it did."""
         rows = request.rows
         out: list = [None] * len(rows)
-        by_node: dict[tuple[str, str], list[int]] = {}
-        for i, req in enumerate(rows):
-            by_node.setdefault((req.group_id, req.peer_id), []).append(i)
-
-        async def run_node(key, idxs):
-            node = self._nodes.get(key)
-            if node is None:
-                err = ErrorResponse(int(RaftError.ENOENT),
-                                    f"no node for {key[0]}")
-                for i in idxs:
-                    out[i] = err
-                return
-            if key in self._append_inflight:
-                busy = ErrorResponse(int(RaftError.EBUSY), f"{key[0]} busy")
-                for i in idxs:
-                    out[i] = busy
-                return
-            answered = [False]   # round replied: drop any late writes
-            # claim the lane SYNCHRONOUSLY, before the task is even
-            # scheduled: deferring the add into run_rows opens a
-            # window where two concurrent rounds for the same node
-            # both pass the busy-check above and interleave the
-            # group's log writes (the in-order contract the guard
-            # exists for)
-            self._append_inflight.add(key)
-
-            async def run_rows():
+        keys = [(req.group_id, req.peer_id) for req in rows]
+        # nodes with more than one row here (none, as a rule)
+        repeated = _repeated(keys) if len(set(keys)) != len(keys) else ()
+        inflight = self._append_inflight
+        nodes = self._nodes
+        riding: list = []       # (row, node, its _Appending): begun here
+        slow: dict[tuple[str, str], list[int]] = {}
+        eto_ms = 0
+        sender = server = clock = None
+        now = 0.0
+        inline = 0
+        sec = _TRACE.enter("raft.follower") if _TRACE.enabled else None
+        try:
+            for i, req in enumerate(rows):
+                key = keys[i]
+                node = nodes.get(key)
+                if node is None:
+                    out[i] = ErrorResponse(int(RaftError.ENOENT),
+                                           f"no node for {key[0]}")
+                    continue
+                if key in slow:
+                    slow[key].append(i)     # in batch order, behind its first
+                    continue
+                if key in inflight:
+                    out[i] = ErrorResponse(int(RaftError.EBUSY),
+                                           f"{key[0]} busy")
+                    continue
+                # claim the lane SYNCHRONOUSLY, before anything is staged
+                # or scheduled: two concurrent rounds for the same node
+                # must not both pass the busy-check above and interleave
+                # the group's log writes (the in-order contract the guard
+                # exists for)
+                inflight.add(key)
+                if not eto_ms or node.options.election_timeout_ms < eto_ms:
+                    eto_ms = node.options.election_timeout_ms
+                if key in repeated or not node._try_lock():
+                    slow[key] = [i]
+                    continue
+                # the node's lock is ours until the row's finish
+                began = None
                 try:
-                    for i in idxs:
-                        try:
-                            r = await node.handle_append_entries(rows[i])
-                        except RpcError as e:
-                            r = ErrorResponse(e.status.code,
-                                              e.status.error_msg)
-                        except asyncio.CancelledError:
-                            raise
-                        except Exception as e:  # noqa: BLE001
-                            LOG.exception("store_append row failed")
-                            r = ErrorResponse(int(RaftError.EINTERNAL),
-                                              repr(e))
-                        if answered[0]:
-                            return  # reply already serialized: too late
-                        out[i] = r
-                finally:
-                    self._append_inflight.discard(key)
-
-            budget = node.options.election_timeout_ms / 1000.0 / 2
-            task = asyncio.ensure_future(run_rows())
+                    if req.server_id != sender:
+                        sender = req.server_id
+                        server = PeerId.parse(sender)
+                    if node._clock is not clock:
+                        clock = node._clock
+                        now = clock.monotonic()
+                    began = node._begin_append(
+                        req, server, node.node_manager is not None, now)
+                    if began.__class__ is _Appending and \
+                            began.ride is not None:
+                        riding.append((i, node, began))
+                        continue
+                except Exception as e:  # noqa: BLE001 — one bad row only
+                    began = _row_error(e)
+                node._lock.release()
+                if began.__class__ is _Appending or began is _STEP_DOWN \
+                        or began is _LEADER_CONFLICT:
+                    slow[key] = [i]     # it has to wait first; keeps its claim
+                    continue
+                inflight.discard(key)
+                out[i] = began
+                inline += 1
+        finally:
+            if sec is not None:
+                _TRACE.leave(sec)
+        waits: set = {ap.ride.future for _i, _n, ap in riding}
+        tasks = []
+        sent = [False]      # the reply left: a late row writes nothing
+        for key, idxs in slow.items():
+            tasks.append(asyncio.ensure_future(self._run_node_rows(
+                nodes[key], key, idxs, rows, out, sent)))
+        waits.update(tasks)
+        try:
+            if waits:
+                await asyncio.wait(waits, timeout=eto_ms / 1000.0 / 2)
+        finally:
+            # also when this handler is cancelled under its wait: what
+            # is staged is finished when its round lands
+            sent[0] = True
+            sec = _TRACE.enter("raft.follower") if _TRACE.enabled else None
             try:
-                await asyncio.wait_for(asyncio.shield(task), budget)
-            except asyncio.TimeoutError:
-                # the node is stuck (long fsync / snapshot load): EBUSY
-                # its unserved tail NOW; the shielded run keeps going
-                # (cancelling a mid-flush append tears durability
-                # ordering) but may no longer touch this reply
-                answered[0] = True
-                task.add_done_callback(
-                    lambda t: t.cancelled() or t.exception())
-                busy = ErrorResponse(int(RaftError.EBUSY),
-                                     f"{key[0]} busy")
-                for i in idxs:
-                    if out[i] is None:
-                        out[i] = busy
-
-        if len(by_node) == 1:
-            # the common round shape: no gather layer
-            key, idxs = next(iter(by_node.items()))
-            await run_node(key, idxs)
-        else:
-            await asyncio.gather(*(run_node(k, v)
-                                   for k, v in by_node.items()))
+                for i, node, ap in riding:
+                    if ap.ride.future.done():
+                        out[i] = self._finish_row(node, keys[i], rows[i], ap)
+                        inline += 1
+                    else:
+                        ap.ride.future.add_done_callback(
+                            lambda _f, node=node, key=keys[i], req=rows[i],
+                            ap=ap: self._finish_row(node, key, req, ap))
+            finally:
+                if sec is not None:
+                    _TRACE.leave(sec)
+            for task in tasks:
+                if not task.done():
+                    # stuck (long fsync / snapshot load / a lock held):
+                    # it keeps going, uncancelled, and is heard out
+                    task.add_done_callback(
+                        lambda t: t.cancelled() or t.exception())
+        self.follower_rows.update(1, len(rows))
+        if inline:
+            self.follower_rows_inline.update(1, inline)
+        for i, ack in enumerate(out):
+            if ack is None:     # the deadline passed over it
+                out[i] = ErrorResponse(int(RaftError.EBUSY),
+                                       f"{keys[i][0]} busy")
         return StoreAppendResponse(acks=out)
+
+    def _finish_row(self, node: Node, key: tuple[str, str], req, ap):
+        """The finish of a row begun in ``_handle_store_append``, its
+        round landed: the answer; the node's lock and the lane's claim
+        are given back whatever it makes of it."""
+        try:
+            return node._finish_append(
+                req, node.node_manager is not None, ap)
+        except Exception as e:  # noqa: BLE001 — one bad row only
+            return _row_error(e)
+        finally:
+            node._lock.release()
+            self._append_inflight.discard(key)
+
+    async def _run_node_rows(self, node: Node, key: tuple[str, str],
+                             idxs: list[int], rows: list, out: list,
+                             sent: list) -> None:
+        """One node's rows of a store_append RPC that have to wait,
+        in batch order, under the claim its handler made."""
+        try:
+            for i in idxs:
+                try:
+                    r = await node.handle_append_entries(rows[i])
+                except Exception as e:  # noqa: BLE001 — one bad row only
+                    r = _row_error(e)
+                if sent[0]:
+                    return  # reply already serialized: too late
+                out[i] = r
+        finally:
+            self._append_inflight.discard(key)
 
     async def _serve_append_items(self, items) -> list:
         from tpuraft.rpc.messages import ErrorResponse

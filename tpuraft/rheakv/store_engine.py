@@ -813,6 +813,11 @@ class StoreEngine:
                 self.apply_round.syncs
             multi_raft_engine.tick_hists["kv_wal_sync_entries"] = \
                 self.apply_round.sync_entries
+            # the follower's side of the store-wide append rounds
+            multi_raft_engine.tick_hists["follower_rows"] = \
+                self.node_manager.follower_rows
+            multi_raft_engine.tick_hists["follower_rows_inline"] = \
+                self.node_manager.follower_rows_inline
         # SIGTERM drain (process topology): True bounces NEW kv work
         # with a retryable busy while admitted items finish — see drain()
         self.draining = False

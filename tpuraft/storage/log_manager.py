@@ -305,26 +305,28 @@ class LogManager:
         await self.flush_staged(last_id.index)
         return last_id
 
-    async def append_entries_follower(self, prev_log_index: int, prev_log_term: int,
-                                      entries: list[LogEntry]) -> bool:
-        """Conflict-checked follower append (#checkAndResolveConflict).
-
-        Returns False when prev_log does not match (leader must back off).
-        """
+    def _check_follower_append(self, prev_log_index: int, prev_log_term: int,
+                               entries: list[LogEntry]
+                               ) -> tuple[bool, list[LogEntry], Optional[int]]:
+        """The conflict check of ``#checkAndResolveConflict``, changing
+        nothing: ``(False, [], None)`` when prev_log does not match
+        (leader must back off), else ``(True, new, cut)``: the entries
+        not held yet, and the index to keep the log up to before they
+        are staged where the first of them conflicts with what is held
+        (None: no conflict)."""
         if prev_log_index > self._last_index:
-            return False  # gap: we don't have prev yet
+            return False, [], None  # gap: we don't have prev yet
         if prev_log_index >= self._first_index or (
             prev_log_index == self._last_snapshot_id.index
         ):
             if self.get_term(prev_log_index) != prev_log_term:
-                return False
+                return False, [], None
         # else: prev lies in the compacted region (its term is unknowable
         # unless it is the snapshot index) — those entries were committed,
         # so Raft's Log Matching property guarantees agreement.
-        if not entries:
-            return True
         # skip entries we already have with matching terms
         keep_from = 0
+        cut = None
         for i, e in enumerate(entries):
             if (e.id.index < self._first_index
                     or e.id.index <= self._last_snapshot_id.index):
@@ -340,21 +342,65 @@ class LogManager:
                     raise RaftException(Status.error(
                         RaftError.EINTERNAL,
                         f"conflict at applied index {e.id.index}"))
-                await self._truncate_suffix(e.id.index - 1)
+                cut = e.id.index - 1
                 keep_from = i
                 break
             keep_from = i + 1
-        new_entries = entries[keep_from:]
+        return True, entries[keep_from:], cut
+
+    async def append_entries_follower(self, prev_log_index: int, prev_log_term: int,
+                                      entries: list[LogEntry]) -> bool:
+        """Conflict-checked follower append (#checkAndResolveConflict).
+
+        Returns False when prev_log does not match (leader must back off).
+        """
+        ok, new_entries, cut = self._check_follower_append(
+            prev_log_index, prev_log_term, entries)
+        if not ok:
+            return False
+        if cut is not None:
+            await self._truncate_suffix(cut)
         if not new_entries:
             return True
-        sec = _TRACE.enter("log.stage") if _TRACE.enabled else None
-        try:
-            if not self._stage_follower_entries(new_entries):
-                return False
-        finally:
-            if sec is not None:
-                _TRACE.leave(sec)
+        if not self._stage_follower_entries(new_entries):
+            return False
         await self._enqueue_flush(new_entries)
+        self._wake_waiters()
+        return True
+
+    def begin_follower_append(self, prev_log_index: int, prev_log_term: int,
+                              entries: list[LogEntry]):
+        """:meth:`append_entries_follower` up to its first wait, for a
+        caller that serves many groups in one turn.  True or False where
+        no wait is needed (the verdict); the group's :class:`_Ride` where
+        the new entries are staged and ride the store-wide flush round
+        of this turn (:meth:`end_follower_append` gives the verdict once
+        its ``future`` is done); None, with nothing changed, where the
+        append has to wait before it can stage: a conflicting suffix to
+        truncate, or a storage with no shared round."""
+        ok, new_entries, cut = self._check_follower_append(
+            prev_log_index, prev_log_term, entries)
+        if not ok:
+            return False
+        if cut is not None:
+            return None
+        if not new_entries:
+            return True
+        if not hasattr(self._storage, "append_entries_async"):
+            return None
+        if not self._stage_follower_entries(new_entries):
+            return False
+        ride = self._join_round(new_entries)
+        if ride is None:
+            self._wake_waiters()
+            return True
+        return ride
+
+    def end_follower_append(self, ride: _Ride) -> bool:
+        """The round a :meth:`begin_follower_append` rode has resolved
+        (``_landed`` ran as its callback): the verdict, or what failed."""
+        if ride.error is not None:
+            raise ride.error
         self._wake_waiters()
         return True
 
@@ -367,19 +413,24 @@ class LogManager:
         # would later mistake it for a torn tail and silently truncate
         # acked suffix entries.  Rejecting here makes the leader back
         # off and retransmit, turning corruption into a transient.
+        sec = _TRACE.enter("log.stage") if _TRACE.enabled else None
         try:
+            try:
+                for e in new_entries:
+                    e.verify_crc()
+            except ValueError:
+                LOG.warning("rejecting AppendEntries batch: wire CRC "
+                            "mismatch at index %d", e.id.index)
+                return False
             for e in new_entries:
-                e.verify_crc()
-        except ValueError:
-            LOG.warning("rejecting AppendEntries batch: wire CRC mismatch "
-                        "at index %d", e.id.index)
-            return False
-        for e in new_entries:
-            self._mem_put(e)
-            self._last_index = e.id.index
-            if e.type == EntryType.CONFIGURATION:
-                self._track_conf(e)
-        return True
+                self._mem_put(e)
+                self._last_index = e.id.index
+                if e.type == EntryType.CONFIGURATION:
+                    self._track_conf(e)
+            return True
+        finally:
+            if sec is not None:
+                _TRACE.leave(sec)
 
     def _track_conf(self, e: LogEntry) -> None:
         from tpuraft.conf import Configuration
@@ -421,20 +472,34 @@ class LogManager:
             self._flush_idle.set()
 
     async def _ride_round(self, entries: list[LogEntry]) -> None:
-        """Shared-engine storages: stage in the caller's own turn (calls
-        are in index order and nothing awaits before the staging) and
-        await the store-wide round's one future.  No task and no future
-        of this group's own; what follows the fsync (`_flushed`) is a
-        callback on the round's future, registered before the caller's
-        wake-up so it has run when the caller resumes, and runs even if
-        the caller is cancelled meanwhile (the entries are durable all
-        the same)."""
+        """Shared-engine storages: stage in the caller's own turn and
+        await the store-wide round's one future."""
+        ride = self._join_round(entries)
+        if ride is None:
+            return
+        try:
+            await ride.future
+        except Exception:   # noqa: BLE001 — _landed ran first: ride.error
+            pass
+        if ride.error is not None:
+            raise ride.error
+
+    def _join_round(self, entries: list[LogEntry]) -> Optional[_Ride]:
+        """Stage into the store-wide round of this turn (calls are in
+        index order and nothing awaits before the staging): the group's
+        stake in it, whose ``future`` is the round's, or None where
+        nothing is to be synced (stable as appended).  No task and no
+        future of this group's own; what follows the fsync (`_flushed`)
+        is a callback on the round's future, registered before any
+        waiter's wake-up so it has run when a waiter resumes, and runs
+        even if nobody waits any more (the entries are durable all the
+        same)."""
         t0 = time.perf_counter()
         try:
             staged = self._storage.append_entries_async(entries, self._sync)
             if staged is None:      # nothing to sync: stable as appended
                 self._flushed(entries, t0, None)
-                return
+                return None
         except Exception as exc:
             raise self._flush_failed(exc) from exc
         ride = self._ride
@@ -448,12 +513,7 @@ class LogManager:
             self._flush_begins()
             ride.future.add_done_callback(
                 lambda _f, ride=ride: self._landed(ride))
-        try:
-            await ride.future
-        except Exception:   # noqa: BLE001 — _landed ran first: ride.error
-            pass
-        if ride.error is not None:
-            raise ride.error
+        return ride
 
     def _landed(self, ride: _Ride) -> None:
         """The round this group rode resolved (runs on the loop, ahead
