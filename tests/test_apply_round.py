@@ -510,3 +510,35 @@ async def test_store_engine_counts_the_logs_flush_rounds(tmp_path, health):
             assert mlog.sync_count <= rounds.rounds.count
     finally:
         await c.stop_all()
+
+
+# -- a store's two roles in one round (ISSUE 36) -----------------------------
+
+
+async def test_a_kv_round_counts_as_mixed_only_when_both_roles_applied(spy):
+    """``syncs_mixed`` takes one sample for a round in which a region this
+    store leads and one it follows applied together; a round of one role
+    has none."""
+    apply_round, groups = await _groups(
+        spy, 4, lambda rid: [_put(rid, 0), _put(rid, 1)])
+    lead, follow = groups[:2], groups[2:]
+    for g in lead:
+        await g.fsm.on_leader_start(1)
+
+    async def turn(commits) -> None:
+        for g, upto in commits:     # one turn: no await between the commits
+            g.caller.on_committed(upto)
+        for g, upto in commits:
+            await asyncio.wait_for(g.futs[upto - 1], 10)
+
+    await turn([(g, 1) for g in lead])
+    assert (apply_round.syncs.count, apply_round.syncs_mixed.count) == (1, 0)
+    await turn([(g, 1) for g in follow])
+    assert (apply_round.syncs.count, apply_round.syncs_mixed.count) == (2, 0)
+    await turn([(lead[0], 2), (follow[0], 2)])
+    assert (apply_round.syncs.count, apply_round.syncs_mixed.count) == (3, 1)
+    # a leader that stepped down applies as a follower
+    await lead[1].fsm.on_leader_stop(None)
+    await turn([(lead[1], 2), (follow[1], 2)])
+    assert (apply_round.syncs.count, apply_round.syncs_mixed.count) == (4, 1)
+    assert apply_round.sync_entries.count == 8

@@ -564,7 +564,7 @@ async def test_tick_phase_histograms_count_ticks(backend):
         e.tick_once()
     hists = e.tick_histograms()
     events = {"elections_started", "leader_stepdowns", "beat_rows",
-              "vote_rounds_lost", "elections_yielded"}
+              "vote_rounds_lost", "elections_yielded", "leader_transfers"}
     assert set(hists) == PER_TICK_HISTS | events | {
         "tick_late_ms", "tick_transfers", "fence_resolve_ms"}
     # one sample an event, not a tick: no node, no leader, none of them

@@ -468,3 +468,76 @@ async def test_every_raw_store_call_is_made_on_the_loop_thread(tmp_path):
     off_loop = sorted({name for name, tid in calls
                        if tid != threading.get_ident()})
     assert off_loop == [], f"raw-store calls off the loop thread: {off_loop}"
+
+
+# -- a round cut into chunks is one round (ISSUE 36) -------------------------
+
+
+async def test_a_chunked_round_answers_all_its_callers_together():
+    """Three chunks of one loop turn's items, finishing at three times:
+    no caller is answered before the last chunk has its answers, then all
+    are, in one turn, each with its own result or its own error."""
+    from tpuraft.rheakv.client import _Batcher
+
+    gates = [asyncio.Event() for _ in range(3)]
+    started: list = []
+
+    async def flush(chunk):
+        k = len(started)
+        started.append([item for item, _ in chunk])
+        await gates[k].wait()
+        for item, fut in chunk:
+            if item == 7:
+                fut.set_exception(ValueError("seven"))
+            else:
+                fut.set_result(item * 10)
+
+    b = _Batcher(4, flush)
+    futs = [b.add(i) for i in range(10)]            # one turn: one round
+    for _ in range(4):
+        await asyncio.sleep(0)
+    assert started == [[0, 1, 2, 3], [4, 5, 6, 7], [8, 9]]
+    gates[2].set()                                  # the small last chunk
+    gates[0].set()
+    for _ in range(4):
+        await asyncio.sleep(0)
+    assert not any(f.done() for f in futs)          # chunk 1 is still out
+    futs[5].cancel()                                # a caller goes away
+    gates[1].set()
+    for _ in range(4):
+        await asyncio.sleep(0)
+    assert all(f.done() for f in futs)
+    assert [f.result() for i, f in enumerate(futs) if i not in (5, 7)] == \
+        [0, 10, 20, 30, 40, 60, 80, 90]
+    assert futs[5].cancelled()
+    assert isinstance(futs[7].exception(), ValueError)
+
+
+async def test_a_round_of_one_chunk_and_rounds_of_later_turns_stay_apart():
+    """What fits one chunk is flushed as before, with the callers' own
+    futures, and a later turn's items make a later round that waits for
+    nobody."""
+    from tpuraft.rheakv.client import _Batcher
+
+    seen: list = []
+    hold = asyncio.Event()
+
+    async def flush(chunk):
+        seen.append([item for item, _ in chunk])
+        if len(seen) == 1:
+            await hold.wait()
+        for item, fut in chunk:
+            fut.set_result(item)
+
+    b = _Batcher(4, flush)
+    first = [b.add(i) for i in range(3)]
+    for _ in range(3):
+        await asyncio.sleep(0)
+    second = [b.add(i) for i in (10, 11)]
+    for _ in range(3):
+        await asyncio.sleep(0)
+    assert seen == [[0, 1, 2], [10, 11]]
+    assert [f.result() for f in second] == [10, 11]     # not held up
+    assert not any(f.done() for f in first)
+    hold.set()
+    assert await asyncio.gather(*first) == [0, 1, 2]

@@ -685,3 +685,47 @@ async def test_a_round_that_crosses_a_journal_boundary(tmp_path):
                 [300 + k] * 9
         finally:
             s.shutdown()
+
+
+# -- a store's two roles in one round (ISSUE 36) -----------------------------
+
+
+async def test_a_round_counts_as_mixed_only_when_both_roles_rode_it(
+        tmp_path):
+    """``rounds_mixed`` takes one sample for a round that carried a
+    leader's staging AND a follower's: a store that only leads, or only
+    follows, has none; the inline follower round (``begin_follower_append``)
+    counts as the waiting path does."""
+    lms = await mk_managers(tmp_path, 4)
+    gc = lms[0]._storage.engine.group_commit
+    try:
+        # two leaders' stagings in one turn: one round, not mixed
+        await asyncio.gather(*(lm.append_entries_leader(mk_entries(1, 1), 1)
+                               for lm in lms[:2]))
+        assert (gc.rounds.count, gc.rounds_mixed.count) == (1, 0)
+        # two followers' appends in one turn: one round, not mixed
+        assert await asyncio.gather(*(
+            lm.append_entries_follower(0, 0, mk_entries(1, 1))
+            for lm in lms[2:])) == [True, True]
+        assert (gc.rounds.count, gc.rounds_mixed.count) == (2, 0)
+        # a leader's and a follower's in one turn: one round, mixed
+        await asyncio.gather(
+            lms[0].append_entries_leader(mk_entries(2, 1), 1),
+            lms[2].append_entries_follower(1, 1, mk_entries(2, 1)))
+        assert (gc.rounds.count, gc.rounds_mixed.count) == (3, 1)
+        # the same through the handler's inline path: staged without a wait
+        lead = asyncio.ensure_future(
+            lms[1].append_entries_leader(mk_entries(2, 1), 1))
+        await asyncio.sleep(0)      # the leader has staged: its round is open
+        ride = lms[3].begin_follower_append(1, 1, mk_entries(2, 1))
+        await ride.future
+        assert lms[3].end_follower_append(ride) is True
+        await lead
+        assert (gc.rounds.count, gc.rounds_mixed.count) == (4, 2)
+        # and the next round starts clean: a lone leader is not mixed
+        await lms[0].append_entries_leader(mk_entries(3, 1), 1)
+        assert (gc.rounds.count, gc.rounds_mixed.count) == (5, 2)
+        assert gc.round_groups.count == 9
+    finally:
+        for lm in lms:
+            await lm.shutdown()

@@ -345,6 +345,10 @@ class EngineControl:
         self.engine.tick_hists["elections_yielded"].update(
             self.node.current_term)
 
+    def note_leader_transfer(self) -> None:
+        self.engine.tick_hists["leader_transfers"].update(
+            self.node.current_term)
+
     def note_election_due(self) -> None:
         """The tick fired ``election_due`` for this row: where a sampled
         group's ``election`` span begins; ``on_leader`` ends it."""
@@ -959,6 +963,10 @@ class MultiRaftEngine:
             # pre-vote quorums not acted on: the node had granted a
             # higher-ranked rival's pre-vote for the same term
             "elections_yielded": Histogram(),
+            # leaderships this engine's nodes GAINED through TimeoutNow
+            # (a transfer, not a timeout; each is one of
+            # elections_started too; sample = the term)
+            "leader_transfers": Histogram(),
             "beat_rows": Histogram(),
         }
         # leader step-downs by what fired: "quorum" = dead-quorum check

@@ -168,6 +168,9 @@ class TimerControl:
     def note_election_yielded(self) -> None:
         pass
 
+    def note_leader_transfer(self) -> None:
+        pass  # leaderships gained through TimeoutNow: the engine counts
+
     def on_leader(self) -> None:
         self._vote_timer.stop()
         self._acks = {self._node.server_id: self._clock.monotonic()}
@@ -325,6 +328,13 @@ class Node:
         # aborted) — lets a nemesis land a seeded crash mid-stage
         self.conf_stage_listener: Optional[Callable[["Node", str], None]] = None
         self._transfer_deadline: float = 0.0    # guarded-by: _lock (writes)
+        # the watchdog of the transfer this leader has in flight: the
+        # step-down that completes the transfer cancels it
+        self._transfer_watchdog_task: Optional[asyncio.Task] = None
+        # the transferee's side: (term of the election a TimeoutNow
+        # started here, the old leader's trace context), until that
+        # election is won or another term begins
+        self._transfer_gain: Optional[tuple] = None  # guarded-by: _lock (writes)
         self._shutdown_event = asyncio.Event()
         self._wakeup_candidate: Optional[PeerId] = None
         # priority election [1.3+] (reference: NodeImpl targetPriority /
@@ -777,18 +787,30 @@ class Node:
             r = self.replicators.get(peer)
             if r is None:
                 return Status.error(RaftError.EINVAL, f"no replicator for {peer}")
-            self.state = State.TRANSFERRING
-            self._transfer_deadline = (
-                self._clock.monotonic()
-                + self.options.election_timeout_ms / 1000.0)
-            r.transfer_leadership(self.log_manager.last_log_index())
-            r.wake()
-            LOG.info("%s transferring leadership to %s", self, peer)
-            asyncio.ensure_future(
-                self._transfer_watchdog(peer, self.current_term))
+            with TRACER.section("raft.election"):
+                self.state = State.TRANSFERRING
+                self._transfer_deadline = (
+                    self._clock.monotonic()
+                    + self.options.election_timeout_ms / 1000.0)
+                # the ``leader_transfer`` span of a sampled group: accepted
+                # here; the transferee's become-leader ends it (the
+                # context rides the TimeoutNow)
+                tid = TRACER.begin_op("leader_transfer",
+                                      proc=self._trace_proc)
+                r.transfer_leadership(self.log_manager.last_log_index(), tid)
+                r.wake()
+                LOG.info("%s transferring leadership to %s", self, peer)
+                self._transfer_watchdog_task = asyncio.ensure_future(
+                    self._transfer_watchdog(peer, self.current_term, tid))
             return Status.OK()
 
-    async def _transfer_watchdog(self, peer: PeerId, term: int) -> None:
+    async def _transfer_watchdog(self, peer: PeerId, term: int,
+                                 tid: int = 0) -> None:
+        """Resume leading if the transfer has not deposed this leader
+        within one election timeout (its trace ``tid`` then never ends).
+        The step-down that completes a transfer cancels it
+        (``_step_down``), so a store that hands over hundreds of
+        leaderships keeps no task for each of them."""
         await asyncio.sleep(self.options.election_timeout_ms / 1000.0)
         async with self._lock:
             # the term pins the watchdog to ITS transfer: deposed and
@@ -796,13 +818,16 @@ class Node:
             # flight — a stale watchdog resuming LEADER for it would arm
             # change_peers while the new target's TimeoutNow is pending
             if self.state == State.TRANSFERRING and self.current_term == term:
-                LOG.info("%s leadership transfer timed out; resuming", self)
-                self.state = State.LEADER
-                # cancel the pending TimeoutNow trigger: the target
-                # catching up later must not depose the resumed leader
-                r = self.replicators.get(peer)
-                if r is not None:
-                    r.stop_transfer_leadership()
+                with TRACER.section("raft.election"):
+                    LOG.info("%s leadership transfer timed out; resuming",
+                             self)
+                    self.state = State.LEADER
+                    TRACER.abandon_op(tid)
+                    # cancel the pending TimeoutNow trigger: the target
+                    # catching up later must not depose the resumed leader
+                    r = self.replicators.get(peer)
+                    if r is not None:
+                        r.stop_transfer_leadership()
 
     # ======================================================================
     # apply-side commit plumbing
@@ -1160,6 +1185,12 @@ class Node:
         self.state = State.LEADER
         self.leader_id = self.server_id
         self._ctrl.on_leader()
+        gain, self._transfer_gain = self._transfer_gain, None
+        if gain is not None and gain[0] == self.current_term:
+            # a leadership gained through TimeoutNow; where the old
+            # leader's trace began in this process, it ends here
+            self._ctrl.note_leader_transfer()
+            TRACER.end_op(gain[1], term=self.current_term)
         LOG.info("%s became LEADER at term %d", self, self.current_term)
         RECORDER.record("leader_elected", self.group_id,
                         node=str(self.server_id), term=self.current_term)
@@ -1261,6 +1292,10 @@ class Node:
         was_leader = self.state in (State.LEADER, State.TRANSFERRING)
         self._ctrl.on_step_down(self.state == State.CANDIDATE, was_leader,
                                 status)
+        watchdog, self._transfer_watchdog_task = \
+            self._transfer_watchdog_task, None
+        if watchdog is not None:
+            watchdog.cancel()   # no transfer outlives the leadership
         if was_leader:
             self.replicators.stop_all()
             self.ballot_box.clear_pending()
@@ -1803,6 +1838,7 @@ class Node:
                          self)
                 return TimeoutNowResponse(term=self.current_term,
                                           success=False)
+            self._transfer_gain = (self.current_term + 1, req.trace_ctx)
             await self._elect_self()
             return TimeoutNowResponse(term=self.current_term, success=True)
 

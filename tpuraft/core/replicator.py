@@ -84,6 +84,7 @@ class Replicator:
         # timeout) — cleared if the peer is re-added meanwhile
         self.retiring = False
         self._transfer_target_index: Optional[int] = None
+        self._transfer_trace_ctx: int = 0
         self._catchup_waiters: list[tuple[int, asyncio.Future]] = []
         self.inflight_peak = 0  # high-water mark of the batch window
         # send-plane state
@@ -550,9 +551,11 @@ class Replicator:
 
     # -- leadership transfer -------------------------------------------------
 
-    def transfer_leadership(self, log_index: int) -> None:
-        """Send TimeoutNow once this peer's match reaches log_index."""
+    def transfer_leadership(self, log_index: int, trace_ctx: int = 0) -> None:
+        """Send TimeoutNow once this peer's match reaches log_index
+        (``trace_ctx``: the transfer's trace, for the transferee)."""
         self._transfer_target_index = log_index
+        self._transfer_trace_ctx = trace_ctx
         if self.match_index >= log_index:
             t = asyncio.ensure_future(self._maybe_timeout_now())
             t.add_done_callback(_consume)
@@ -572,12 +575,14 @@ class Replicator:
                 and self.match_index >= self._transfer_target_index):
             self._transfer_target_index = None
             node = self._node
-            req = TimeoutNowRequest(
-                group_id=node.group_id,
-                server_id=str(node.server_id),
-                peer_id=str(self.peer),
-                term=node.current_term,
-            )
+            with _TRACE.section("raft.election"):
+                req = TimeoutNowRequest(
+                    group_id=node.group_id,
+                    server_id=str(node.server_id),
+                    peer_id=str(self.peer),
+                    term=node.current_term,
+                    trace_ctx=self._transfer_trace_ctx,
+                )
             try:
                 await node.transport.timeout_now(self.peer.endpoint, req)
             except RpcError:

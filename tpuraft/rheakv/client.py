@@ -132,10 +132,38 @@ class _Batcher:
         if len(batch) <= self._max:
             await flush(batch)
             return
-        # chunks are independent: flush them concurrently
+        # A round cut into chunks is still ONE round: the chunks go out
+        # together and their callers are answered together, by the chunk
+        # that finishes last (as one chunk answers all of its callers
+        # when its last region has answered).  Answered apart, a small
+        # last chunk that met only the fastest of several leading stores
+        # releases its callers a loop turn ahead of the others; their
+        # next operations then start the batchers a turn apart, reads
+        # and writes miss each other's RPC, and callers that had
+        # travelled as one round travel as two from then on, each
+        # paying every store-wide round's fixed cost (with one leading
+        # store every chunk rides the one RPC and nothing can part them)
+        loop = asyncio.get_running_loop()
+        held = [(item, loop.create_future()) for item, _ in batch]
+        left = -(-len(batch) // self._max)
+
+        async def flush_part(i: int) -> None:
+            nonlocal left
+            await flush(held[i:i + self._max])
+            left -= 1
+            if left:
+                return
+            for (_, fut), (_, got) in zip(batch, held):
+                exc = got.exception()
+                if fut.done():      # its caller went away
+                    continue
+                if exc is not None:
+                    fut.set_exception(exc)
+                else:
+                    fut.set_result(got.result())
+
         await asyncio.gather(*[
-            flush(batch[i:i + self._max])
-            for i in range(0, len(batch), self._max)])
+            flush_part(i) for i in range(0, len(batch), self._max)])
 
 
 # graftcheck: loop-confined

@@ -90,6 +90,9 @@ SYNC_SECTION = "fsm.sync.wal"
 
 # graftcheck: loop-confined — one round per raw store, staged into and
 # flushed from the store's event loop only
+_LEADS, _FOLLOWS = 1, 2      # a stager's role here, as a bit of a round's
+
+
 class ApplyRound:
     """The store-wide apply round: the KV WAL's group commit.
 
@@ -111,21 +114,28 @@ class ApplyRound:
     def __init__(self, store: RawKVStore) -> None:
         self.store = store
         self._staged: list = []   # (rows, entries, future) of the open round
+        self._roles = 0           # _LEADS | _FOLLOWS: its stagers' roles
         # events, one sample each, so a window's ``count`` is the
-        # number: a round's store call, and a log entry that rode one
-        # (sync_entries.count / syncs.count = entries per fsync)
+        # number: a round's store call, a log entry that rode one
+        # (sync_entries.count / syncs.count = entries per fsync), and a
+        # round in which a region this store leads and one it follows
+        # applied together (none on a store with one role)
         self.syncs = Histogram()
         self.sync_entries = Histogram()
+        self.syncs_mixed = Histogram()
 
-    def stage(self, rows: list, entries: int) -> asyncio.Future:
+    def stage(self, rows: list, entries: int,
+              leader: bool = False) -> asyncio.Future:
         """Put one run's rows (``entries`` log entries) into the open
         round; the future resolves to None once they are written, or to
-        the exception their write raised."""
+        the exception their write raised.  ``leader``: the region that
+        applies them leads its group here."""
         loop = asyncio.get_running_loop()
         fut = loop.create_future()
         if not self._staged:
             loop.call_soon(self.flush)
         self._staged.append((rows, entries, fut))
+        self._roles |= _LEADS if leader else _FOLLOWS
         return fut
 
     def flush(self) -> None:
@@ -134,7 +144,7 @@ class ApplyRound:
         staged = self._staged
         if not staged:
             return
-        self._staged = []
+        roles, self._staged, self._roles = self._roles, [], 0
         sec = TRACER.enter(SYNC_SECTION) if TRACER.enabled else None
         try:
             errors = self._write(staged)
@@ -142,6 +152,8 @@ class ApplyRound:
             if sec is not None:
                 TRACER.leave(sec)
         self.syncs.update(1)
+        if roles == _LEADS | _FOLLOWS:
+            self.syncs_mixed.update(1)
         self.sync_entries.update(1, sum(n for _rows, n, _fut in staged))
         for (_rows, _n, fut), err in zip(staged, errors):
             if not fut.done():
@@ -283,7 +295,8 @@ class KVStoreStateMachine(StateMachine):
                             it.next()
                             continue
                 if dones:
-                    fut = self.apply_round.stage(rows, len(dones))
+                    fut = self.apply_round.stage(rows, len(dones),
+                                                 self.leader_term >= 0)
                     if sec is not None:
                         TRACER.leave(sec)
                         sec = None

@@ -813,6 +813,8 @@ class StoreEngine:
                 self.apply_round.syncs
             multi_raft_engine.tick_hists["kv_wal_sync_entries"] = \
                 self.apply_round.sync_entries
+            multi_raft_engine.tick_hists["kv_wal_syncs_mixed"] = \
+                self.apply_round.syncs_mixed
             # the follower's side of the store-wide append rounds
             multi_raft_engine.tick_hists["follower_rows"] = \
                 self.node_manager.follower_rows
@@ -978,9 +980,10 @@ class StoreEngine:
         """multilog scheme: the store's shared flush round times every
         fsync in the thread that runs it — feed those samples to the
         disk probe (the LogManager's flush timing covers the file
-        scheme) — and counts its rounds: those three event histograms
+        scheme) — and counts its rounds: those four event histograms
         sit with the engine's, beside the KV WAL's (groups per log fsync
-        = log_round_groups.count / log_rounds.count)."""
+        = log_round_groups.count / log_rounds.count; log_rounds_mixed:
+        the rounds that carried a leader's staging and a follower's)."""
         if self.opts.log_scheme != "multilog" or not self.opts.data_path:
             return
         from tpuraft.storage.multilog import peek_engine
@@ -998,6 +1001,7 @@ class StoreEngine:
             hists["log_rounds"] = rounds.rounds
             hists["log_round_groups"] = rounds.round_groups
             hists["log_round_inline"] = rounds.round_inline
+            hists["log_rounds_mixed"] = rounds.rounds_mixed
 
     def _stop_background(self) -> None:
         """What ``shutdown`` and ``crash`` both stop, none of it

@@ -170,6 +170,10 @@ class TimeoutNowRequest:
     server_id: str
     peer_id: str
     term: int
+    # trailing, wire-compatible: the old leader's ``leader_transfer``
+    # trace on a sampled group (0 = not traced); the transferee ends it
+    # when it becomes leader
+    trace_ctx: int = 0
 
 
 @dataclass

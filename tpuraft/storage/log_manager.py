@@ -364,7 +364,7 @@ class LogManager:
             return True
         if not self._stage_follower_entries(new_entries):
             return False
-        await self._enqueue_flush(new_entries)
+        await self._enqueue_flush(new_entries, follower=True)
         self._wake_waiters()
         return True
 
@@ -390,7 +390,7 @@ class LogManager:
             return None
         if not self._stage_follower_entries(new_entries):
             return False
-        ride = self._join_round(new_entries)
+        ride = self._join_round(new_entries, follower=True)
         if ride is None:
             self._wake_waiters()
             return True
@@ -447,10 +447,11 @@ class LogManager:
 
     # -- flush pipeline ------------------------------------------------------
 
-    async def _enqueue_flush(self, entries: list[LogEntry]) -> None:
+    async def _enqueue_flush(self, entries: list[LogEntry],
+                             follower: bool = False) -> None:
         if hasattr(self._storage, "append_entries_async"):
             # a shared engine with a store-wide flush round (multilog)
-            await self._ride_round(entries)
+            await self._ride_round(entries, follower)
             return
         fut = asyncio.get_running_loop().create_future()
         self._flush_begins()
@@ -471,10 +472,11 @@ class LogManager:
         if self._inflight_flushes == 0:
             self._flush_idle.set()
 
-    async def _ride_round(self, entries: list[LogEntry]) -> None:
+    async def _ride_round(self, entries: list[LogEntry],
+                          follower: bool = False) -> None:
         """Shared-engine storages: stage in the caller's own turn and
         await the store-wide round's one future."""
-        ride = self._join_round(entries)
+        ride = self._join_round(entries, follower)
         if ride is None:
             return
         try:
@@ -484,7 +486,8 @@ class LogManager:
         if ride.error is not None:
             raise ride.error
 
-    def _join_round(self, entries: list[LogEntry]) -> Optional[_Ride]:
+    def _join_round(self, entries: list[LogEntry],
+                    follower: bool = False) -> Optional[_Ride]:
         """Stage into the store-wide round of this turn (calls are in
         index order and nothing awaits before the staging): the group's
         stake in it, whose ``future`` is the round's, or None where
@@ -493,10 +496,12 @@ class LogManager:
         is a callback on the round's future, registered before any
         waiter's wake-up so it has run when a waiter resumes, and runs
         even if nobody waits any more (the entries are durable all the
-        same)."""
+        same).  ``follower``: a follower's append, not a leader's own
+        entries (the round counts those that carried both)."""
         t0 = time.perf_counter()
         try:
-            staged = self._storage.append_entries_async(entries, self._sync)
+            staged = self._storage.append_entries_async(
+                entries, self._sync, follower)
             if staged is None:      # nothing to sync: stable as appended
                 self._flushed(entries, t0, None)
                 return None

@@ -131,11 +131,13 @@ class _Staged:
     interval, or raises what failed: this group's own append (``error``)
     or the round's sync."""
 
-    __slots__ = ("gid", "frames", "future", "error")
+    __slots__ = ("gid", "frames", "follower", "future", "error")
 
-    def __init__(self, gid: int, frames: bytes) -> None:
+    def __init__(self, gid: int, frames: bytes,
+                 follower: bool = False) -> None:
         self.gid = gid
         self.frames: Optional[bytes] = frames
+        self.follower = follower    # staged by a follower's append
         self.future: Optional[_RoundFuture] = None   # the round's
         self.error: Optional[BaseException] = None
 
@@ -152,17 +154,21 @@ class _Staged:
         return interval
 
 
+_LEADER, _FOLLOWER = 1, 2    # a staging's role, as a bit of _Lane.roles
+
+
 class _Lane:
     """One event loop's rounds on a group commit: the open one, which
     the loop's stagers are joining, and whether an earlier one is with
     the executor (the open one then waits for it to land)."""
 
-    __slots__ = ("loop", "open", "groups", "items", "in_flight")
+    __slots__ = ("loop", "open", "groups", "roles", "items", "in_flight")
 
     def __init__(self, loop) -> None:
         self.loop = loop
         self.open: Optional[_RoundFuture] = None
         self.groups = 0          # stagings that joined the open round
+        self.roles = 0           # _LEADER | _FOLLOWER: who staged them
         self.items: list = []    # their _Staged, where the engine has any
         self.in_flight = False
 
@@ -226,10 +232,13 @@ class _GroupCommit:
         # events, one sample each, so a window's ``count`` is the
         # number: a round closed, a staging that rode one (round_groups
         # / rounds = groups per fsync, 1.0 = nothing merges), a round
-        # synced on the loop thread
+        # synced on the loop thread, a round that carried a leader's
+        # staging and a follower's (the store's two roles rode one
+        # fsync; a store that only leads or only follows has none)
         self.rounds = Histogram()
         self.round_groups = Histogram()
         self.round_inline = Histogram()
+        self.rounds_mixed = Histogram()
 
     def flush(self, item: Optional[_Staged] = None) -> asyncio.Future:
         """Join the running loop's open round (opening it, and
@@ -249,6 +258,7 @@ class _GroupCommit:
             if item is not None:
                 item.future = lane.open
                 lane.items.append(item)
+                lane.roles |= _FOLLOWER if item.follower else _LEADER
             return lane.open
 
     def _close(self, lane: _Lane) -> None:
@@ -257,11 +267,14 @@ class _GroupCommit:
         executor."""
         with self._lock:
             fut, groups, items = lane.open, lane.groups, lane.items
-            lane.open, lane.groups, lane.items = None, 0, []
+            roles = lane.roles
+            lane.open, lane.groups, lane.items, lane.roles = None, 0, [], 0
             lane.in_flight = True       # until this round resolves
             inline = self._cost_ewma < self.INLINE_MAX_S
         self.rounds.update(1)
         self.round_groups.update(1, groups)
+        if roles == _LEADER | _FOLLOWER:
+            self.rounds_mixed.update(1)
         probe = self.health_probe
         exc: Optional[BaseException] = None
         interval: Optional[tuple] = None
@@ -585,7 +598,8 @@ class MultiLogStorage(LogStorage):
         return n
 
     def append_entries_async(self, entries: list[LogEntry],
-                             sync: bool = True) -> Optional[_Staged]:
+                             sync: bool = True,
+                             follower: bool = False) -> Optional[_Staged]:
         """LogManager hook: encode NOW, in the caller's own turn (so a
         group's entries are staged in the caller's order), and join the
         store-wide flush round of this turn, which appends every
@@ -593,7 +607,9 @@ class MultiLogStorage(LogStorage):
         Returns the group's stake in that round: awaitable (the fsync's
         interval, see ``_GroupCommit``, or what failed), uncancellable,
         with the round's shared ``future``.  None where nothing is to be
-        synced (the entries are appended at once then)."""
+        synced (the entries are appended at once then).  ``follower``
+        says whose staging this is, a follower's append or a leader's
+        own entries, for the round's count of mixed rounds."""
         if not entries:
             return None
         if not sync:
@@ -601,7 +617,7 @@ class MultiLogStorage(LogStorage):
             return None
         sec = _TRACE.enter("log.stage") if _TRACE.enabled else None
         try:
-            item = _Staged(self._gid, self._frames(entries))
+            item = _Staged(self._gid, self._frames(entries), follower)
         finally:
             if sec is not None:
                 _TRACE.leave(sec)
