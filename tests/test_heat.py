@@ -565,8 +565,12 @@ async def test_tick_phase_histograms_count_ticks(backend):
     hists = e.tick_histograms()
     events = {"elections_started", "leader_stepdowns", "beat_rows",
               "vote_rounds_lost", "elections_yielded", "leader_transfers"}
-    assert set(hists) == PER_TICK_HISTS | events | {
+    overlap = {"tick_overlapped", "tick_ready", "tick_inflight_ms"}
+    assert set(hists) == PER_TICK_HISTS | events | overlap | {
         "tick_late_ms", "tick_transfers", "fence_resolve_ms"}
+    # tick_once is both halves in one go: nothing was collected a turn
+    # after its enqueue (tests/test_tick_overlap.py drives tick())
+    assert all(hists[k]["count"] == 0 for k in overlap)
     # one sample an event, not a tick: no node, no leader, none of them
     assert all(hists[k]["count"] == 0 for k in events)
     assert all(hists[k]["count"] == 5 for k in PER_TICK_HISTS)

@@ -492,6 +492,83 @@ async def test_batcher_window_bounds_concurrent_stalled_rounds():
         assert await asyncio.wait_for(fut, 2.0) is True
 
 
+@pytest.mark.parametrize("loop_tick", [None, "began_before_the_acks"])
+async def test_rounds_closing_in_one_turn_share_one_forced_tick(loop_tick):
+    """Three rounds over one engine, their RPCs answered in one turn:
+    TWO device calls, where every round used to force its own.  The
+    first destination's acks begin a tick at once (``tick_soon``), or
+    find the engine loop's own in flight; whatever lands after that
+    snapshot, the other rounds' acks, rides ONE tick after it, which the
+    three rounds' closes share."""
+    from tests.test_tick_overlap import device_fence_node, overlap_engine
+    from tpuraft.rheakv.store_engine import ReadConfirmBatcher
+
+    eng = overlap_engine()
+    voters = _voters(8200)
+    transport = _StallTransport({p.endpoint for p in voters[1:]})
+    b = ReadConfirmBatcher()
+    futs = []
+    for i in range(3):
+        node = device_fence_node(eng, f"g{i}", transport, voters)
+        futs.append(asyncio.ensure_future(b.confirm(node)))
+        await asyncio.sleep(0.01)       # a round each, all waiting
+    assert b.rounds == 3 and len(b._rounds_inflight) == 3
+    assert eng.ticks == 0
+    loops = asyncio.ensure_future(eng.tick()) if loop_tick else None
+    transport.release.set()
+    assert await asyncio.wait_for(asyncio.gather(*futs), 5) == [True] * 3
+    if loops is not None:
+        assert await loops == 0
+    assert eng.ticks == 2       # the first snapshot's, and one for the rest
+    assert eng.log == ["call", "fetch"] * eng.ticks
+    assert b.failed == 0 and b.device_fences == 3
+    assert eng.fence_lane_resolves == 3 and eng._fence_waiters == {}
+
+
+async def test_a_destinations_acks_begin_the_fences_tick_at_once():
+    """The tick that confirms a round's device fences is begun in the
+    turn its first destination answered, not when the engine loop has
+    woken up or the round has closed, and the close then asks for
+    none."""
+    from tests.test_tick_overlap import device_fence_node, overlap_engine
+    from tpuraft.rheakv.store_engine import ReadConfirmBatcher
+
+    eng = overlap_engine()
+    calls = []
+    tick_soon = eng.tick_soon
+    eng.tick_soon = lambda: calls.append(len(eng.log)) or tick_soon()
+    nodes = [device_fence_node(eng, f"g{i}", _BatchTransport(), _voters(8400))
+             for i in range(4)]
+    b = ReadConfirmBatcher()
+    outs = await asyncio.wait_for(
+        asyncio.gather(*(b.confirm(n) for n in nodes)), 5)
+    assert outs == [True] * 4 and b.rounds == 1 and b.failed == 0
+    assert calls[0] == 0                # asked before any device call
+    assert eng.ticks == 1 and eng.log == ["call", "fetch"]
+    assert eng.fence_lane_resolves == 4 and eng._fence_waiters == {}
+
+
+async def test_a_round_over_three_engines_enqueues_all_before_collecting():
+    from tests.test_tick_overlap import device_fence_node, overlap_engine
+    from tpuraft.rheakv.store_engine import ReadConfirmBatcher
+
+    log: list = []
+    engines = [overlap_engine(ready=False) for _ in range(3)]
+    transport = _BatchTransport()
+    nodes = []
+    for i, eng in enumerate(engines):
+        eng.log = log                   # one order over the three
+        nodes.append(device_fence_node(eng, f"g{i}", transport,
+                                       _voters(8300 + 10 * i)))
+    b = ReadConfirmBatcher()
+    outs = await asyncio.wait_for(
+        asyncio.gather(*(b.confirm(n) for n in nodes)), 5)
+    assert outs == [True] * 3 and b.rounds == 1
+    assert log == ["call"] * 3 + ["fetch"] * 3
+    assert [e.ticks for e in engines] == [1, 1, 1]
+    assert all(e.tick_hists["tick_overlapped"].count == 1 for e in engines)
+
+
 # ---------------------------------------------------------------------------
 # integration: fence dedupe + batcher through the KV stack
 # ---------------------------------------------------------------------------

@@ -444,12 +444,17 @@ class EngineControl:
             ms = e.to_ms(when)
             if ms > e.last_ack[self.slot, col]:
                 e.last_ack[self.slot, col] = ms
+                e._input_seq += 1
                 # acks deliberately don't wake the tick (eager_commit
                 # note in TpuBallotBox.commit_at) — EXCEPT while a read
                 # fence is pending: its resolution IS this tick's q_ack
                 # reduction, so the ack that completes the fence quorum
-                # must drive a tick instead of waiting out a deadline
-                if e.fence_start[self.slot] > _NEG_I32:
+                # must drive a tick instead of waiting out a deadline.
+                # With a tick in flight the question waits for its end
+                # (_tick_end asks it for every ack it has not seen): it
+                # may confirm the fence without this ack
+                if e.fence_start[self.slot] > _NEG_I32 \
+                        and e._flight is None:
                     e.mark_dirty()
 
     # -- device read-fence plane (ReadConfirmBatcher rounds) -----------------
@@ -785,6 +790,30 @@ class _NpOutputs:
             setattr(self, k, v)
 
 
+class _Flight:
+    """A tick between its halves: ``_tick_begin`` enqueued the program
+    and ``_tick_end`` has not collected it yet.  ``out`` is the call's
+    result (on a device: maybe not computed yet), ``now`` the snapshot's
+    time, ``gen`` the layout generation it was taken under, ``base``
+    the log bases its relative indexes hang on, ``seq`` the engine's
+    input count at the snapshot, ``t0`` / ``t1`` / ``ts`` / ``tc``
+    begin's clock reads (entry, views built, state built, call
+    returned).  ``done`` and ``advanced`` are end's; ``fut``, if
+    anybody but the caller that began it waits for its end, takes
+    ``advanced`` there."""
+
+    __slots__ = ("out", "now", "gen", "base", "seq", "t0", "t1", "ts", "tc",
+                 "done", "advanced", "fut")
+
+    def __init__(self, out, now, gen, base, seq, t0, t1, ts, tc):
+        self.out, self.now, self.gen, self.base = out, now, gen, base
+        self.seq = seq
+        self.t0, self.t1, self.ts, self.tc = t0, t1, ts, tc
+        self.done = False
+        self.advanced = 0
+        self.fut: Optional[asyncio.Future] = None
+
+
 class MultiRaftEngine:
     """Per-process batched consensus plane.  Start once, register each
     node's ballot box through :meth:`ballot_box_factory`."""
@@ -894,10 +923,33 @@ class MultiRaftEngine:
         # single-device jax path: the ONE host array a tick uploads
         # (ops/tick.py pack_state), allocated on first use and again
         # after _grow.  Reused from tick to tick only because _fetch has
-        # waited for the program before tick_once returns: on the CPU
-        # backend JAX may alias host memory instead of copying it.
+        # waited for the program before the next tick begins (ONE tick
+        # in flight an engine: _flight): on the CPU backend JAX may
+        # alias host memory instead of copying it.
         self._packed = False
         self._tick_buf: Optional[np.ndarray] = None
+        # the tick between its halves (tick(): begun in one loop turn,
+        # collected in the next), and the future of the tick AFTER it,
+        # which every caller shares that asks during the flight for
+        # something its snapshot lacks
+        self._flight: Optional[_Flight] = None
+        self._next_tick: Optional[asyncio.Future] = None
+        # what a snapshot can lack: counts every dirty mark and every
+        # ack that moved a last_ack row.  A flight whose count is still
+        # the engine's has seen all a caller could be asking for, and
+        # once it has landed (_seq_served) so has the loop's dirty mark
+        self._input_seq = 0
+        self._seq_served = -1
+        # what a flight's snapshot was taken under: bumped wherever a
+        # row changes its owner or its columns (_grow, alloc_slot,
+        # release, unregister_ctrl, set_conf, a _rebase that moves a
+        # base).  An output that comes back under another generation is
+        # dropped whole: its row may belong to another group now
+        self._layout_gen = 0
+        self.ticks_dropped = 0   # outputs dropped for that (or at a stop)
+        # the last tick's own seconds (begin + end, not the loop turn
+        # between them): what _loop paces by and feeds the density floor
+        self._tick_own_s = 0.0
         # arrays the last tick moved across the host/device boundary
         self._tick_transfers = 0
         self._deadline_fold = None  # mesh mode: sharded earliest-deadline min
@@ -932,6 +984,14 @@ class MultiRaftEngine:
             "tick_state_ms": Histogram(),
             "tick_call_ms": Histogram(),
             "tick_fetch_ms": Histogram(),
+            # ticks collected a loop turn after they were enqueued
+            # (tick(): one sample each, so ``count`` is the number), of
+            # them those whose output was there before the fetch asked
+            # (``is_ready()``), and per such tick the time from the
+            # enqueue to the start of the collecting half
+            "tick_overlapped": Histogram(),
+            "tick_ready": Histogram(),
+            "tick_inflight_ms": Histogram(),
             # NOT per tick but per ARRAY that crosses the host/device
             # boundary (host arrays handed to the call + arrays
             # downloaded), sampled with its bytes: count / ticks is 2
@@ -1191,6 +1251,7 @@ class MultiRaftEngine:
         # the REAL controlled density grew past the safe operating point
         if self.has_ctrl[slot]:
             self._n_ctrls -= 1
+        self._layout_gen += 1
         self._ctrls[slot] = None
         self._ctrl_server[slot] = None
         self.has_ctrl[slot] = False
@@ -1199,6 +1260,7 @@ class MultiRaftEngine:
     def alloc_slot(self) -> int:
         if not self._free:
             self._grow()
+        self._layout_gen += 1
         return self._free.pop()
 
     def _grow(self) -> None:
@@ -1209,6 +1271,7 @@ class MultiRaftEngine:
         divisibility by mesh_devices for the sharded path."""
         old_g = self.G
         new_g = old_g * 2
+        self._layout_gen += 1
 
         def pad(a: np.ndarray, fill=0) -> np.ndarray:
             extra = np.full((old_g,) + a.shape[1:], fill, a.dtype)
@@ -1252,6 +1315,7 @@ class MultiRaftEngine:
 
     def release(self, box: TpuBallotBox) -> None:
         s = box.slot
+        self._layout_gen += 1
         self._boxes[s] = None
         self.unregister_ctrl(s)
         self.voter_mask[s] = False
@@ -1289,6 +1353,7 @@ class MultiRaftEngine:
     def set_conf(self, slot: int, conf: Configuration,
                  old_conf: Configuration) -> None:
         """Map peers to columns and set voter masks for a group."""
+        self._layout_gen += 1
         cols = self._peer_cols[slot]
         all_peers = list(dict.fromkeys(
             conf.peers + old_conf.peers + conf.learners + old_conf.learners))
@@ -1370,6 +1435,7 @@ class MultiRaftEngine:
 
     def mark_dirty(self) -> None:
         self._dirty = True
+        self._input_seq += 1
         if not self._dirty_event.is_set():
             self._dirty_at = time.perf_counter()
             self._dirty_event.set()
@@ -1475,6 +1541,7 @@ class MultiRaftEngine:
         ms = self.now_ms() if when_ms is None else when_ms
         sl, co = arrs
         self.last_ack[sl, co] = np.maximum(self.last_ack[sl, co], ms)
+        self._input_seq += 1
 
     def describe(self) -> str:
         """Live engine state for operators (the device-plane counterpart
@@ -1533,6 +1600,7 @@ class MultiRaftEngine:
             "witness_groups": self._n_witness_slots,
             "stepdown_ticks": self.stepdown_ticks,
             "tick_failures": self.tick_failures,
+            "ticks_dropped": self.ticks_dropped,
             "tick_transfers": self._tick_transfers,
             "fence_lane_armed": self.fence_lane_armed,
             "fence_lane_resolves": self.fence_lane_resolves,
@@ -1723,6 +1791,11 @@ class MultiRaftEngine:
             due = self._next_deadline() <= now
             if sec is not None:
                 _TRACE.leave(sec)
+            if self._dirty and self._seq_served == self._input_seq:
+                # a tick somebody else began after the mark (a confirm
+                # round, tick_soon) has landed: the mark is served
+                self._dirty = False
+                self._dirty_event.clear()
             if self._dirty or due:
                 self._dirty_event.clear()
                 self._dirty = False
@@ -1731,7 +1804,10 @@ class MultiRaftEngine:
                 t0 = time.perf_counter()
                 advanced = 0
                 try:
-                    advanced = self.tick_once()
+                    advanced = await self.tick()
+                    # the two halves' own seconds: the loop turn between
+                    # them is other tasks' work, not the tick's cost
+                    dur = self._tick_own_s
                 except Exception:
                     # the loop must outlive one bad tick, but a tick
                     # that raises (lost device, OOM, a refused compile
@@ -1740,7 +1816,7 @@ class MultiRaftEngine:
                     self.tick_failures += 1
                     LOG.exception("engine tick failed")
                     self._dirty = True  # re-process pending acks next tick
-                dur = time.perf_counter() - t0
+                    dur = time.perf_counter() - t0
                 # measured tick dispatch cost: one input to the density-
                 # aware election-timeout floor (_density_floor_ms)
                 self._tick_cost_ema_s = (
@@ -1790,6 +1866,7 @@ class MultiRaftEngine:
     def _rebase(self) -> None:
         hot = (self.match_abs.max(axis=1) - self.base) > _REBASE_LIMIT
         if hot.any():
+            self._layout_gen += 1
             for s in np.nonzero(hot)[0]:
                 new_base = self.commit_abs[s]
                 self.pending_rel[s] = max(
@@ -1799,13 +1876,114 @@ class MultiRaftEngine:
     def tick_once(self) -> int:
         """One batched device tick for all groups: commit advancement,
         election/heartbeat scheduling, lease & step-down.  Returns the
-        number of groups whose commit advanced."""
+        number of groups whose commit advanced.  Synchronous: both
+        halves in one go, the wait for the device between them (the
+        warm-up at start, the numpy twin, the mesh path, a round that
+        closes while it is being cancelled).  A tick that ``tick()``
+        left in flight is collected first: one at a time."""
+        if self._flight is not None:
+            self._tick_end(self._flight, time.perf_counter())
+        flight = self._tick_begin()
+        return self._tick_end(flight, flight.tc)
+
+    async def tick(self) -> int:
+        """``tick_once`` with the loop free while the device has the
+        program: the first half enqueues it in this loop turn, the
+        second collects it in the next (a turn under load lasts many
+        times what the program and the copy need; on an idle loop the
+        fetch waits out the rest, as ``tick_once`` does).  Taken where
+        there is a device call to overlap (single-device jax); the numpy
+        twin and the mesh path tick synchronously, and so does an engine
+        whose ``tick_once`` is not this class's (a subclass, a spy around
+        it: whoever replaced it expects every tick to pass through it).
+
+        ONE tick in flight an engine.  A caller that asks during a
+        flight gets the NEXT tick, begun right after the flight's end:
+        its snapshot is taken after whatever the caller recorded before
+        asking (what a read fence needs), and every caller that asks
+        meanwhile shares it.  Unless nothing was recorded since the
+        flight's own snapshot (no dirty mark, no ack: ``_input_seq``):
+        then the flight IS the tick the caller asks for, and rounds
+        that close in one turn cost one device call."""
+        if not self._overlaps():
+            return self.tick_once()
+        flight = self._flight
+        if flight is not None:
+            loop = asyncio.get_running_loop()
+            if flight.seq == self._input_seq:
+                fut = flight.fut = flight.fut or loop.create_future()
+            else:
+                fut = self._next_tick = \
+                    self._next_tick or loop.create_future()
+            # shielded: one caller's cancellation is not the others'
+            return await asyncio.shield(fut)
+        flight = self._tick_begin()
+        try:
+            await asyncio.sleep(0)
+        except asyncio.CancelledError:
+            # collected all the same (by the loop, or by the caller's
+            # own synchronous close before that)
+            asyncio.get_running_loop().call_soon(self._land, flight)
+            raise
+        return self._land(flight)
+
+    def _overlaps(self) -> bool:
+        """Is there a device call to overlap (single-device jax), and
+        is ``tick_once`` this class's own?"""
+        return (self._tick_fn is not None and self._packed
+                and getattr(self.tick_once, "__func__", None)
+                is MultiRaftEngine.tick_once)
+
+    def tick_soon(self) -> None:
+        """For a caller that has just recorded what a waiting read
+        fence needs and awaits nothing (ReadConfirmBatcher, after a
+        destination's acks): the tick begins NOW, in the caller's turn,
+        and the loop collects it in its next, the turn in which the
+        engine loop would only have woken up to begin it.  With a tick
+        in flight, or nothing to overlap, the caller's dirty mark does
+        what it always did."""
+        if self._flight is None and not self._stopped and self._overlaps():
+            asyncio.get_running_loop().call_soon(
+                self._land, self._tick_begin())
+
+    def _land(self, flight: _Flight) -> int:
+        """The collecting half of an overlapped tick, one loop turn
+        after its begin (the caller that began it calls, or the loop
+        for a tick begun here), then the begin of the tick its flight
+        made others wait for, which the loop collects in its next
+        turn."""
+        try:
+            if not flight.done:     # else a tick_once collected it
+                self._tick_end(flight, time.perf_counter(), yielded=True)
+            return flight.advanced
+        finally:
+            nxt = self._next_tick
+            if nxt is not None and self._flight is None:
+                # (a flight begun since, after a tick_once collected
+                # this one, begins theirs when it lands)
+                self._next_tick = None
+                if self._stopped:
+                    nxt.set_result(0)
+                else:
+                    try:
+                        self._tick_begin().fut = nxt
+                    except Exception as e:
+                        nxt.set_exception(e)
+                    else:
+                        asyncio.get_running_loop().call_soon(
+                            self._land, self._flight)
+
+    def _tick_begin(self) -> _Flight:
+        """A tick's first half: snapshot the mirrors at ``now``, enqueue
+        the program, start the output's copy to the host.  Nothing is
+        waited for.  (The numpy twin computes here: all "call".)"""
         pc = time.perf_counter
         t0 = pc()
         # one loop section open at a time, switched at the clock reads
-        # the histograms take: tick.build | tick.call | tick.fetch |
-        # tick.apply (None while tracing is off)
+        # the histograms take: tick.build | tick.call here, tick.fetch |
+        # tick.apply in _tick_end (None while tracing is off)
         sec = _TRACE.enter("tick.build", t0) if _TRACE.enabled else None
+        tc = 0.0
         try:
             now = self.now_ms()
             self._maybe_time_rebase(now)
@@ -1826,45 +2004,96 @@ class MultiRaftEngine:
                 if sec is not None:
                     sec = _TRACE.switch(sec, "tick.call", ts)
                 out = self._call_tick(state, now)
-                tc = pc()
-                if sec is not None:
-                    sec = _TRACE.switch(sec, "tick.fetch", tc)
-                out = self._fetch(out)
-            else:  # numpy twin (tiny deployments / no jax): all "call"
+                if self._packed:
+                    out.copy_to_host_async()
+            else:  # numpy twin (tiny deployments / no jax)
                 self._tick_transfers = 0
                 ts = t1
                 if sec is not None:
                     sec = _TRACE.switch(sec, "tick.call", ts)
                 out = self._np_tick(rel, commit_rel_now, now)
-                tc = pc()
-                if sec is not None:
-                    sec = _TRACE.switch(sec, "tick.fetch", tc)
-            t2 = pc()
-
-            self.ticks += 1
-            # publish the read-plane lane: the fused q_ack reduce is
-            # exactly what per-read lease checks need, and the row it
-            # replaces is a per-read [P] copy+sort on the hot GET path
-            np.copyto(self.tick_q_ack, np.asarray(out.q_ack))
+            tc = pc()
+        finally:
             if sec is not None:
-                sec = _TRACE.switch(sec, "tick.apply")
+                _TRACE.leave(sec, tc)
+        flight = self._flight = _Flight(
+            out, now, self._layout_gen, self.base.copy(), self._input_seq,
+            t0, t1, ts, tc)
+        return flight
+
+    def _tick_end(self, flight: _Flight, te: float,
+                  yielded: bool = False) -> int:
+        """A tick's second half, begun at clock read ``te``: wait for
+        what is left of the program, download, publish ``tick_q_ack``,
+        apply.  The outputs are hints about the mirrors as they were at
+        begin, a loop turn ago where the tick was overlapped: commits
+        only ever rise, a fence is confirmed by its own ``start <=
+        q_ack``, a timer mask is held against its mirror row again
+        (_apply_protocol), the handlers re-verify under the node lock.
+        An output taken under another layout generation, or collected
+        after a stop, is dropped whole."""
+        pc = time.perf_counter
+        hists = self.tick_hists
+        sec = _TRACE.enter("tick.fetch", te) if _TRACE.enabled else None
+        # over whatever happens below: the next tick may begin
+        self._flight = None
+        flight.done = True
+        try:
+            out = flight.out
+            if yielded:
+                hists["tick_overlapped"].update(1)
+                if out.is_ready():
+                    hists["tick_ready"].update(1)
+                hists["tick_inflight_ms"].update((te - flight.tc) * 1e3)
+            if self._tick_fn is not None:
+                out = self._fetch(out)
+            t2 = pc()
+            self.ticks += 1
             self._hb_flush_s = 0.0
-            advanced = self._apply_commits(out)
-            self._apply_protocol(out, now)
+            if flight.gen != self._layout_gen or (yielded and self._stopped):
+                self.ticks_dropped += 1
+                self.mark_dirty()
+            else:
+                # publish the read-plane lane: the fused q_ack reduce is
+                # exactly what per-read lease checks need, and the row
+                # it replaces is a per-read [P] copy+sort on the hot GET
+                # path
+                np.copyto(self.tick_q_ack, np.asarray(out.q_ack))
+                self._seq_served = flight.seq
+                if sec is not None:
+                    sec = _TRACE.switch(sec, "tick.apply")
+                flight.advanced = self._apply_commits(out, flight.base)
+                self._apply_protocol(out, flight.now)
+                if flight.seq != self._input_seq \
+                        and (self.fence_start > _NEG_I32).any():
+                    # acks landed during the flight and a fence is
+                    # still waiting: what record_ack left undecided
+                    self.mark_dirty()
             t3 = pc()
+        except Exception as e:
+            if flight.fut is not None:
+                flight.fut.set_exception(e)
+            raise
         finally:
             if sec is not None:
                 _TRACE.leave(sec)
-        hists = self.tick_hists
-        hists["tick_build_ms"].update((t1 - t0) * 1e3)
-        hists["tick_device_ms"].update((t2 - t1) * 1e3)
+        if flight.fut is not None:
+            flight.fut.set_result(flight.advanced)
+        # tick_device_ms is its three parts, and the parts are the
+        # halves' own clock reads: what passed between the enqueue and
+        # the start of this half is in none of them
+        fetch_s = t2 - te
+        hists["tick_build_ms"].update((flight.t1 - flight.t0) * 1e3)
+        hists["tick_state_ms"].update((flight.ts - flight.t1) * 1e3)
+        hists["tick_call_ms"].update((flight.tc - flight.ts) * 1e3)
+        hists["tick_fetch_ms"].update(fetch_s * 1e3)
+        hists["tick_device_ms"].update(
+            (flight.tc - flight.t1 + fetch_s) * 1e3)
         hists["tick_apply_ms"].update((t3 - t2) * 1e3)
-        hists["tick_total_ms"].update((t3 - t0) * 1e3)
-        hists["tick_state_ms"].update((ts - t1) * 1e3)
-        hists["tick_call_ms"].update((tc - ts) * 1e3)
-        hists["tick_fetch_ms"].update((t2 - tc) * 1e3)
+        self._tick_own_s = flight.tc - flight.t0 + t3 - te
+        hists["tick_total_ms"].update(self._tick_own_s * 1e3)
         hists["tick_heartbeat_ms"].update(self._hb_flush_s * 1e3)
-        return advanced
+        return flight.advanced
 
     def _rel_views(self) -> tuple[np.ndarray, np.ndarray]:
         """(match_rel [G,P], commit_rel [G]): the int32 base-relative
@@ -1902,8 +2131,9 @@ class MultiRaftEngine:
         )
 
     def _device_tick(self, rel, commit_rel_now, now):
-        """State build, jitted call and download in one go: what
-        ``tick_once`` does in three timed steps."""
+        """State build, jitted call and download in one go, applying
+        nothing (a probe's call).  It leaves a tick in flight alone:
+        ``_call_tick`` then packs into a buffer of its own."""
         return self._fetch(self._call_tick(
             self._group_state(rel, commit_rel_now), now))
 
@@ -1923,10 +2153,13 @@ class MultiRaftEngine:
             self._params_dev = TickParams.make(self.eto_ms, self.hb_ms,
                                                self.lease_ms, self.snap_ms)
         if self._packed:
-            if self._tick_buf is None:
-                self._tick_buf = np.empty(
-                    packed_state_shape(self.G, self.P), np.int32)
-            args = (pack_state(state, now, self._tick_buf),)
+            buf = self._tick_buf
+            if buf is None or self._flight is not None:
+                # the program in flight may still read the reusable one
+                buf = np.empty(packed_state_shape(self.G, self.P), np.int32)
+                if self._flight is None:
+                    self._tick_buf = buf
+            args = (pack_state(state, now, buf),)
         else:
             args = (state, np.int32(now))
         self._tick_transfers = 0
@@ -2053,13 +2286,18 @@ class MultiRaftEngine:
             box._advance(q)
         return True
 
-    def _apply_commits(self, out) -> int:
+    def _apply_commits(self, out, base: np.ndarray) -> int:
+        """``base``: the bases ``out.commit_rel`` is relative to (the
+        snapshot's).  The output may be a loop turn old: a row that no
+        longer leads, or leads anew (``reset_pending_index`` moved its
+        base), takes nothing from it."""
         advanced = 0
         for s in np.nonzero(np.asarray(out.commit_advanced))[0]:
             box = self._boxes[s]
-            if box is None:
+            if box is None or self.role[s] != ROLE_LEADER \
+                    or self.base[s] != base[s]:
                 continue
-            new_commit = int(self.base[s] + out.commit_rel[s])
+            new_commit = int(base[s] + out.commit_rel[s])
             if new_commit > self.commit_abs[s]:
                 self.commit_abs[s] = new_commit
                 advanced += 1
@@ -2069,9 +2307,23 @@ class MultiRaftEngine:
 
     def _apply_protocol(self, out, now: int) -> None:
         """Schedule slow-path handlers from the tick's event masks
-        (controlled slots only); handlers re-verify under the node lock."""
+        (controlled slots only); handlers re-verify under the node lock.
+        ``now`` is the snapshot's.  A timer mask is held against its own
+        mirror row again (``still_due``): where the tick was overlapped
+        the masks are a loop turn old, and a leader contact, a beat or a
+        role change that landed in that turn has pushed the deadline or
+        left the role; a timer it pushed must not fire."""
         hc = self.has_ctrl
-        for s in np.nonzero(np.asarray(out.election_due) & hc)[0]:
+
+        def still_due(mask, deadline, leader: bool) -> np.ndarray:
+            slots = np.nonzero(np.asarray(mask) & hc)[0]
+            if slots.size:
+                keep = (deadline[slots] <= now) & ~self.quiescent[slots]
+                keep &= (self.role[slots] == ROLE_LEADER) == leader
+                slots = slots[keep]
+            return slots
+
+        for s in still_due(out.election_due, self.elect_deadline, False):
             ctrl = self._ctrls[s]
             if ctrl is None:
                 continue
@@ -2089,7 +2341,7 @@ class MultiRaftEngine:
             if ctrl is not None:
                 ctrl.schedule("quorum_dead",
                               ctrl.node._on_engine_quorum_dead)
-        sd_slots = np.nonzero(np.asarray(out.stepdown_due) & hc)[0]
+        sd_slots = still_due(out.stepdown_due, self.stepdown_deadline, True)
         if sd_slots.size:
             # re-arm the host mirror NOW (the handler runs async; a
             # same-deadline refire every tick would storm) on the
@@ -2118,7 +2370,7 @@ class MultiRaftEngine:
                                   ctrl.node._check_dead_nodes)
         for s in np.nonzero(np.asarray(out.fence_ok) & hc)[0]:
             self._resolve_fences(int(s))
-        hb_slots = np.nonzero(np.asarray(out.hb_due) & hc)[0]
+        hb_slots = still_due(out.hb_due, self.hb_deadline, True)
         if hb_slots.size:
             h0 = time.perf_counter()
             sec = _TRACE.enter("raft.heartbeat", h0) if _TRACE.enabled \
@@ -2133,7 +2385,7 @@ class MultiRaftEngine:
         snap_slots = np.nonzero(np.asarray(out.snap_due) & hc)[0]
         for s in snap_slots:
             ctrl = self._ctrls[s]
-            if ctrl is None:
+            if ctrl is None or self.snap_deadline[s] > now:
                 continue
             # advance the host mirror NOW (the handler runs async; a
             # same-deadline refire every tick would herd), keeping each

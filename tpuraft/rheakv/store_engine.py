@@ -536,6 +536,11 @@ class ReadConfirmBatcher:
             await asyncio.gather(
                 *(self._beat_dst(dst, rows) for dst, rows in by_dst.items()),
                 *(self._classic(st, r) for st, r in classic))
+            await self._tick_fences(order)
+        except BaseException:
+            # raised or cancelled: the synchronous close
+            self._tick_fences_now(order)
+            raise
         finally:
             sec = TRACER.enter("kv.read_round") if TRACER.enabled else None
             try:
@@ -591,24 +596,49 @@ class ReadConfirmBatcher:
             if not st.device:
                 st.note_ack(node.server_id)  # self-only quorum case
 
+    @staticmethod
+    def _fence_engines(order) -> list:
+        """The distinct engines that still hold a device fence of the
+        round (one: a store's groups share its engine)."""
+        return list({id(st.node._ctrl.engine): st.node._ctrl.engine
+                     for st in order if st.device and not st.done}.values())
+
+    async def _tick_fences(self, order: list) -> None:
+        """Device fences still open at the round's close (few: the
+        tick a destination's acks began has confirmed the rest): the
+        RPCs completed, so every ack this round can produce is already
+        in the engine's last_ack rows — one forced tick per distinct
+        engine reduces them and fires fence_ok, so resolution is
+        deterministic before the sweep.  The tick is ``engine.tick()``:
+        enqueued now, collected a loop turn later, and shared with
+        every round that closes while one is in flight; several
+        engines are all enqueued before any is collected."""
+        ticks = [eng.tick() for eng in self._fence_engines(order)]
+        if len(ticks) > 1:      # tasks, so each is begun before any wait
+            ticks = [asyncio.ensure_future(t) for t in ticks]
+        for t in ticks:
+            try:
+                await t
+            except Exception:  # noqa: BLE001 — fall to the sweep
+                LOG.exception("fence-resolve tick failed")
+
+    def _tick_fences_now(self, fences, soon: bool = False) -> None:
+        """``_tick_fences`` for a caller that does not await: the
+        engines' synchronous tick (a round that raised or was
+        cancelled), or with ``soon`` a tick begun now that the loop
+        collects (after a destination's acks)."""
+        for eng in self._fence_engines(fences):
+            try:
+                if soon:
+                    eng.tick_soon()
+                else:
+                    eng.tick_once()
+            except Exception:  # noqa: BLE001 — fall to the sweep
+                LOG.exception("fence-resolve tick failed")
+
     def _finish_round(self, order: list, beats: int) -> None:
         """The round's close, reached also when it raised or was
         cancelled: resolve, count and disarm every fence."""
-        # device fences: the RPCs completed, so every ack this round
-        # can produce is already in the engine's last_ack rows — one
-        # forced tick per distinct engine reduces them and fires
-        # fence_ok NOW (the adaptive loop's own tick may be a task
-        # behind), so resolution is deterministic before the sweep
-        dev_pending = [st for st in order
-                       if st.device and not st.done]
-        if dev_pending:
-            engines = {id(st.node._ctrl.engine): st.node._ctrl.engine
-                       for st in dev_pending}
-            for eng in engines.values():
-                try:
-                    eng.tick_once()
-                except Exception:  # noqa: BLE001 — fall to the sweep
-                    LOG.exception("fence-resolve tick failed")
         failed_groups = 0
         for st in order:
             if st.device:
@@ -672,6 +702,9 @@ class ReadConfirmBatcher:
                         st.note_ack(r.peer)
                 else:
                     fallback.append((st, r))
+            # device fences these acks may have completed: their tick
+            # begins now, not when the engine loop has woken up
+            self._tick_fences_now((st for st, _r, _b in rows), soon=True)
         finally:
             if sec is not None:
                 TRACER.leave(sec)
