@@ -662,7 +662,10 @@ async def test_tick_sections_nest_under_the_one_tracer(backend):
         for _ in range(4):
             e.tick_once()
         wall = time.perf_counter() - w0
-        table = TRACER.section_table()
+        # switched on from a running loop the tracer frames the
+        # collector too: a pass inside a tick is the tick's child
+        table = {name: row for name, row in TRACER.section_table().items()
+                 if not name.startswith("gc.")}
     finally:
         TRACER.configure(enabled=False)
         TRACER.reset()

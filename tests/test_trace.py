@@ -14,6 +14,8 @@ describe_metrics admin RPC, the HTTP listener).
 from __future__ import annotations
 
 import asyncio
+import functools
+import gc
 import json
 import threading
 
@@ -428,8 +430,18 @@ def test_a_second_with_no_section_exit_shares_the_delta(clock):
     t.leave(sec)
     rows = [s for s in t.spans() if s["name"] == "loop.fsm.apply"]
     assert len(rows) == 3
-    assert sum(s["dur_s"] for s in rows) == pytest.approx(3.5)
+    # a bucket holds the self seconds of its own wall second, no more:
+    # the half second past the third waits for the bucket it fell in
+    assert [s["dur_s"] for s in rows] == [pytest.approx(1.0)] * 3
     assert sum(s["args"]["n"] for s in rows) == pytest.approx(1)
+    sec = _section(t, "raft.ack")
+    clock.run(0.75)
+    t.leave(sec)
+    fourth = {s["name"]: s["dur_s"] for s in t.spans()
+              if s["ts_s"] == pytest.approx(3.0)}
+    assert fourth["loop.fsm.apply"] == pytest.approx(0.5)
+    assert fourth["loop.raft.ack"] == pytest.approx(0.5)
+    assert t.section_table()["fsm.apply"][2] == pytest.approx(3.5)
 
 
 def test_anchor_pair_is_in_chrome_events():
@@ -517,6 +529,411 @@ def test_section_table_rides_the_counters():
     assert c["trace_section_calls_kv.read_round"] == 1
     assert c["trace_section_busy_seconds_kv.read_round"] >= 0.0
     assert c["trace_section_self_seconds_kv.read_round"] >= 0.0
+
+
+# ---------------------------------------------------------------------------
+# the loop's dispatch, framed: handles by owner, the selector, the collector
+# ---------------------------------------------------------------------------
+
+# the interpreter's own, as this module found it: nothing traces at import
+_RUN = asyncio.events.Handle._run
+
+
+def _patched(loop, t: Tracer) -> tuple:
+    """(the dispatch, the selector, the collector) as the tracer left them:
+    True where it has a hook of its own in place."""
+    return (asyncio.events.Handle._run is not _RUN,
+            "select" in vars(loop._selector),
+            t._on_gc in gc.callbacks)
+
+
+@pytest.fixture
+def tracer():
+    """A tracer of the test's own; whatever it patched goes with it."""
+    t = Tracer()
+    yield t
+    t.configure(enabled=False)
+    assert asyncio.events.Handle._run is _RUN
+
+
+class _Callable:
+    def __call__(self, *_a):
+        pass
+
+    def method(self, *_a):
+        pass
+
+
+def _plain(*_a):
+    pass
+
+
+async def test_a_tasks_steps_file_under_its_coroutine(tracer, clock):
+    t = tracer.configure(enabled=True)
+
+    async def worker():
+        clock.run(0.25)
+        sec = _section(t, "kv.batch")       # opens inside the step's frame
+        clock.run(0.5)
+        t.leave(sec)
+        await asyncio.sleep(0)              # a second step
+        clock.run(0.125)
+
+    await asyncio.ensure_future(worker())
+    table = t.section_table()
+    step = "turn.step." + worker.__qualname__.replace(".", ":")
+    assert step.count(".") == 2             # three dotted parts, always
+    # the frame's self seconds are what ran outside any section
+    assert table[step] == (2, pytest.approx(0.875), pytest.approx(0.375))
+    # and the section's numbers are what they are without the frames
+    assert table["kv.batch"] == (1, pytest.approx(0.5), pytest.approx(0.5))
+    # the step that armed was running already: it has no frame, its later
+    # steps (the wake-up from the await above) do
+    (mine,) = [f[0] for f in t._sec_stack]
+    assert mine.startswith("turn.step.") and table[mine][0] == 0
+
+
+@pytest.mark.parametrize("how, name", [
+    ("call_soon", "turn.callback._plain"),
+    ("done_callback", "turn.callback._plain"),
+    ("partial", "turn.callback._plain"),
+    ("bound_method", "turn.callback._Callable:method"),
+    ("builtin", "turn.callback.Future:cancelled"),
+    ("call_later", "turn.timer._plain"),
+    ("unnameable", "turn.callback.?"),
+])
+async def test_a_handle_files_under_its_kind_and_owner(tracer, how, name):
+    t = tracer.configure(enabled=True)
+    loop = asyncio.get_running_loop()
+    fut = loop.create_future()
+    if how == "call_soon":
+        loop.call_soon(_plain)
+    elif how == "done_callback":
+        fut.add_done_callback(_plain)
+        fut.set_result(None)
+    elif how == "partial":
+        loop.call_soon(functools.partial(functools.partial(_plain, 1), 2))
+    elif how == "bound_method":
+        loop.call_soon_threadsafe(_Callable().method)
+    elif how == "builtin":
+        loop.call_soon(fut.cancelled)
+    elif how == "call_later":
+        loop.call_later(0.0, _plain)
+    else:
+        loop.call_soon(_Callable())         # no __qualname__: never raises
+    for _ in range(3):
+        await asyncio.sleep(0.001)
+    assert t.section_table()[name][0] == 1
+    assert t.turn_handles >= 1
+    # looked up once: the second handle of an owner builds no string
+    kind = name.split(".")[1]
+    assert name in [acc[3] for acc in t._turn_accs[kind].values()]
+
+
+async def test_idle_select_opens_once_an_iteration(tracer):
+    t = tracer.configure(enabled=True)
+    for _ in range(5):
+        await asyncio.sleep(0)
+    # this step runs in the turn after the fifth select since arming
+    selects = t.section_table()["idle.select"][0]
+    assert selects == t.turns + 1 == 5
+    assert t._sec_stack[-1][0].startswith("turn.step.")   # not in select
+    assert t.counters()["trace_turns"] == t.turns
+    assert t.counters()["trace_turn_handles"] == t.turn_handles >= 4
+
+
+async def test_a_collection_is_a_child_section_and_a_ring_record(tracer,
+                                                                  clock):
+    t = tracer.configure(enabled=True)
+
+    def pass_takes(phase, _info):           # after the tracer's own hook
+        if phase == "start":
+            clock.run(0.25)
+
+    gc.callbacks.append(pass_takes)
+    gc.disable()                            # no pass but the one asked for
+    try:
+        sec = _section(t, "fsm.apply")
+        clock.run(0.5)
+        gc.collect(1)
+        clock.run(0.125)
+        t.leave(sec)
+        table = t.section_table()
+        # a pass on another thread is not the loop's
+        th = threading.Thread(target=gc.collect, args=(1,))
+        th.start()
+        th.join()
+        assert t.section_table()["gc.gen1"][0] == 1
+    finally:
+        gc.enable()
+        gc.callbacks.remove(pass_takes)
+    assert table["gc.gen1"] == (1, pytest.approx(0.25), pytest.approx(0.25))
+    # the pass began inside the section and is not in its self seconds
+    assert table["fsm.apply"] == (1, pytest.approx(0.875),
+                                  pytest.approx(0.625))
+    (rec,) = [s for s in t.spans() if s["name"] == "gc.gen1"]
+    assert rec["proc"] == "loop" and rec["dur_s"] == pytest.approx(0.25)
+    assert set(rec["args"]) == {"collected", "uncollectable"}
+    assert table["gc.gen0"][0] == table["gc.gen2"][0] == 0
+
+
+async def test_a_pass_that_straddles_the_seconds_end_is_split(tracer, clock):
+    t = tracer.configure(enabled=True)
+    await asyncio.sleep(0)                  # from here on, in a frame
+
+    def pass_takes(phase, _info):
+        if phase == "start":
+            clock.run(0.25)
+
+    gc.callbacks.append(pass_takes)
+    gc.disable()
+    try:
+        clock.run(0.9)
+        sec = _section(t, "fsm.apply")
+        clock.run(0.05)
+        gc.collect(1)                       # 0.95 to 1.20 of the tracer's time
+        clock.run(0.02)
+        t.leave(sec)
+        clock.run(0.9)
+        t.leave(_section(t, "raft.ack"))    # an exit past the second second
+    finally:
+        gc.enable()
+        gc.callbacks.remove(pass_takes)
+    by_name: dict = {}
+    for s in t.spans():
+        if s["name"].startswith("loop."):
+            by_name.setdefault(s["name"], []).append(s["dur_s"])
+    # the pass's exit brought the roll-up and was split at the second's end;
+    # the section that held it has what it ran before and after, never less
+    # than nothing
+    assert by_name["loop.gc"] == [pytest.approx(0.05), pytest.approx(0.2)]
+    assert by_name["loop.fsm.apply"] == [pytest.approx(0.05),
+                                         pytest.approx(0.02)]
+    assert by_name["loop.turn"] == [pytest.approx(0.9), pytest.approx(0.78)]
+    for k in range(2):
+        assert sum(by_name[n][k] for n in (
+            "loop.fsm", "loop.raft", "loop.turn", "loop.idle.select",
+            "loop.gc", "loop.rest") if len(by_name[n]) > k) \
+            == pytest.approx(1.0)
+    assert all(d >= 0.0 for rows in by_name.values() for d in rows)
+
+
+async def test_a_turn_of_a_millisecond_leaves_a_record(tracer, clock):
+    t = tracer.configure(enabled=True)
+
+    async def worker(section_s, rest_s):
+        sec = _section(t, "fsm.apply")
+        clock.run(section_s)
+        t.leave(sec)
+        clock.run(rest_s)
+
+    await asyncio.ensure_future(worker(0.003, 0.001))       # 4 ms
+    await asyncio.ensure_future(worker(0.0005, 0.00025))    # under 1 ms
+    await asyncio.ensure_future(worker(0.004, 0.021))       # a tick period
+    for _ in range(2):
+        await asyncio.sleep(0)
+    turns = [s for s in t.spans() if s["name"] == "turn"]
+    assert [s["dur_s"] for s in turns] == [pytest.approx(0.004),
+                                           pytest.approx(0.025)]
+    assert all(s["proc"] == "loop" for s in turns)
+    first, second = (s["args"] for s in turns)
+    assert first["top"] == "fsm.apply" and first["handles"] >= 1
+    assert first["top_s"] == pytest.approx(0.003)
+    # the frame of the step itself, when no section took more
+    assert second["top"] == "turn.step." + worker.__qualname__.replace(
+        ".", ":")
+    assert second["top_s"] == pytest.approx(0.021)
+    # the short one only counts
+    assert t.turns >= 5 and t.turns_long == 1
+    assert t.counters()["trace_turns_long"] == 1
+
+
+async def test_every_second_of_the_thread_has_a_name(tracer, clock):
+    """Sections, handle frames, the selector, the collector and the rest
+    are the whole of each wall second."""
+    loop = asyncio.get_running_loop()
+    u = 1 / 64
+    # the clock moves inside the selector (the thread asleep) and in the
+    # loop's own bookkeeping between the selector and the handles
+    loop._selector.select = lambda timeout, real=loop._selector.select: (
+        clock.run(2 * u, cpu_share=0.0), real(timeout))[1]
+    loop._process_events = lambda events, real=loop._process_events: (
+        clock.run(u), real(events))[1]
+    t = tracer.configure(enabled=True, ring=1 << 14)
+
+    async def worker():
+        for _ in range(30):                 # 8 u an iteration: 3.75 s
+            clock.run(3 * u)
+            sec = _section(t, "kv.batch")
+            clock.run(u)
+            t.leave(sec)
+            loop.call_soon(clock.run, u)
+            await asyncio.sleep(0)
+
+    try:
+        await asyncio.ensure_future(worker())
+    finally:
+        del loop._process_events
+    by_name: dict = {}
+    for s in t.spans():
+        by_name.setdefault(s["name"], []).append(s)
+    mine = "loop.turn.step." + worker.__qualname__.replace(".", ":")
+    want = {"loop.turn.step": 24 * u, "loop.turn.callback": 8 * u,
+            "loop.turn.timer": 0.0, "loop.turn": 32 * u, mine: 24 * u,
+            "loop.turn.callback._Clock:run": 8 * u,
+            "loop.kv.batch": 8 * u, "loop.kv": 8 * u,
+            "loop.idle.select": 16 * u, "loop.idle": 16 * u,
+            "loop.gc": 0.0, "loop.rest": 8 * u}
+    for name, seconds in want.items():
+        rows = by_name[name]
+        assert len(rows) == 3, name         # every second, 0.0 or not
+        for row in rows[1:]:    # the first second begins in mid-iteration
+            assert row["dur_s"] == pytest.approx(seconds, abs=1e-9), name
+    for k in range(3):
+        whole = sum(by_name[n][k]["dur_s"] for n in (
+            "loop.kv", "loop.turn", "loop.idle.select", "loop.gc",
+            "loop.rest"))
+        assert whole == pytest.approx(1.0, rel=0.02)
+        cpu = by_name["loop.cpu"][k]
+        # busy_s stays the seconds under the sections authors wrote
+        assert cpu["args"]["busy_s"] == by_name["loop.kv"][k]["dur_s"]
+        assert cpu["dur_s"] == pytest.approx(48 * u, abs=8 * u)
+    # a two-part name rolls up once: no duplicate record
+    assert not [n for n in by_name if n.count(".") == 1
+                and len(by_name[n]) != 3]
+
+
+async def test_off_means_absent(tracer):
+    loop = asyncio.get_running_loop()
+    assert _patched(loop, tracer) == (False, False, False)
+    tracer.configure(enabled=False)         # never on: nothing installed
+    assert _patched(loop, tracer) == (False, False, False)
+    t = tracer.configure(enabled=True)
+    assert _patched(loop, t) == (True, True, True)
+    framed = asyncio.events.Handle._run
+    t.configure(enabled=True)               # twice: one wrapper
+    t.reset()
+    assert asyncio.events.Handle._run is framed and t._turn_run is _RUN
+    assert gc.callbacks.count(t._on_gc) == 1
+    for _ in range(2):                      # a turn is counted at its end
+        await asyncio.sleep(0)
+    assert t.turn_handles >= 1
+    t.enabled = False                       # how the benchmark ends it
+    assert _patched(loop, t) == (True, True, True)
+    await asyncio.sleep(0)                  # one more handle
+    assert _patched(loop, t) == (False, False, False)
+    assert loop._selector.select.__func__ is type(loop._selector).select
+    before = t.turn_handles
+    await asyncio.sleep(0)
+    assert t.turn_handles == before and t.enabled is False
+
+
+def test_enabled_with_no_running_loop_patches_nothing():
+    t = Tracer().configure(enabled=True)
+    assert asyncio.events.Handle._run is _RUN
+    assert t._on_gc not in gc.callbacks and t._turn_loop is None
+    t.leave(_section(t, "kv.batch"))
+    # no frames, so none of their rows: the roll-up is what it was
+    assert set(t.section_table()) == {"kv.batch"}
+
+
+async def test_a_second_tracer_takes_the_dispatch_over(tracer):
+    loop = asyncio.get_running_loop()
+    first = Tracer().configure(enabled=True)
+    second = tracer.configure(enabled=True)
+    assert first._turn_loop is None and second._turn_loop is loop
+    assert second._turn_run is _RUN and first._on_gc not in gc.callbacks
+    for _ in range(2):
+        await asyncio.sleep(0)
+    assert second.turn_handles >= 1 and first.turn_handles == 0
+
+
+async def test_a_callback_that_raises_leaves_the_stack_balanced(tracer):
+    t = tracer.configure(enabled=True)
+    loop = asyncio.get_running_loop()
+    seen = []
+    loop.set_exception_handler(lambda _loop, ctx: seen.append(ctx))
+
+    def raises():
+        _section(t, "raft.ack")             # its leave is skipped
+        raise RuntimeError("boom")
+
+    loop.call_soon(raises)
+    for _ in range(2):
+        await asyncio.sleep(0)
+    loop.set_exception_handler(None)
+    assert len(seen) == 1 and "boom" in str(seen[0]["exception"])
+    table = t.section_table()
+    name = "turn.callback." + raises.__qualname__.replace(".", ":")
+    assert table[name][0] == 1 and table["raft.ack"][0] == 1
+    # only the frame of the step this runs in is open
+    assert [f[0].split(".")[1] for f in t._sec_stack] == ["step"]
+    t.reset()                               # under a running handle's frame
+    assert t._sec_stack == []
+    await asyncio.sleep(0)                  # its exit finds nothing to pop
+    assert [f[0].split(".")[1] for f in t._sec_stack] == ["step"]
+
+
+@pytest.mark.parametrize("ending", ["returns", "raises", "cancelled"])
+async def test_drive_opens_the_section_around_each_stretch(tracer, clock,
+                                                           ending):
+    t = tracer.configure(enabled=True)
+    gate = asyncio.get_running_loop().create_future()
+
+    async def boot():
+        clock.run(0.25)
+        await asyncio.sleep(0)              # a bare yield
+        clock.run(0.5)
+        assert [f[0] for f in t._sec_stack][-1] == "store.boot"
+        got = await gate                    # a future
+        clock.run(0.125)
+        if ending == "raises":
+            raise ValueError(got)
+        return got
+
+    task = asyncio.ensure_future(t.drive("store.boot", boot()))
+    for _ in range(3):
+        await asyncio.sleep(0)
+    # suspended: the section is closed, other handles are not its children
+    assert "store.boot" not in [f[0] for f in t._sec_stack]
+    if ending == "cancelled":
+        task.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await task
+        assert gate.cancelled()
+        stretches, seconds = 3, 0.75        # the throw is a stretch too
+    else:
+        gate.set_result("up")
+        if ending == "raises":
+            with pytest.raises(ValueError, match="up"):
+                await task
+        else:
+            assert await task == "up"
+        stretches, seconds = 3, 0.875
+    assert t.section_table()["store.boot"] == (
+        stretches, pytest.approx(seconds), pytest.approx(seconds))
+
+
+async def test_a_replicas_boot_runs_under_store_boot():
+    from tests.kv_cluster import KVTestCluster
+
+    TRACER.configure(enabled=True, sample_rate=0.0)
+    c = KVTestCluster(3)
+    try:
+        await c.start_all()
+        await c.wait_region_leader(1)
+        table = TRACER.section_table()
+        TRACER.enabled = False
+        regions = sum(len(s._regions) for s in c.stores.values())
+        # a stretch before each suspension of a boot, and one to its end
+        assert regions >= 3 and table["store.boot"][0] >= regions
+        assert table["store.boot"][2] > 0.0
+        # what the boot's task steps ran outside the section is theirs
+        assert "turn.step.StoreEngine:_start_region" in table
+    finally:
+        TRACER.configure(enabled=False)
+        TRACER.reset()
+        await c.stop_all()
 
 
 # ---------------------------------------------------------------------------
@@ -895,6 +1312,15 @@ async def test_metrics_text_renders_the_section_table():
         calls = [ln for ln in text.splitlines()
                  if ln.startswith("tpuraft_trace_section_calls_kv_batch{")]
         assert len(calls) == 1 and float(calls[0].rsplit(" ", 1)[1]) >= 2
+        # switched on from the running loop, so the dispatch was framed:
+        # the turns' counters and the handles' sections, by owner
+        for name in ("trace_turns", "trace_turn_handles", "trace_turns_long",
+                     "trace_section_self_seconds_idle_select",
+                     "trace_section_calls_turn_step__StoreSender:_send_safe"):
+            assert f"# TYPE tpuraft_{name} counter" in text, name
+        turns = [ln for ln in text.splitlines()
+                 if ln.startswith("tpuraft_trace_turn_handles{")]
+        assert len(turns) == 1 and float(turns[0].rsplit(" ", 1)[1]) >= 10
     finally:
         TRACER.configure(enabled=False)
         TRACER.reset()

@@ -1731,7 +1731,12 @@ class StoreEngine:
 
     async def _start_region(self, region: Region) -> RegionEngine:
         engine = RegionEngine(region, self)
-        await engine.start()
+        if TRACER.enabled:
+            # a replica's boot is synchronous stretches between awaits
+            # that lie in other modules (the node's init, the log's)
+            await TRACER.drive("store.boot", engine.start())
+        else:
+            await engine.start()
         self._regions[region.id] = engine
         return engine
 

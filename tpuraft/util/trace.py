@@ -34,6 +34,21 @@ over the same second) beside them.  A ``tpuraft.trace_anchor``
 annotation carries one simultaneous (``perf_counter_ns``, ``time_ns``)
 pair, so the op spans can be laid over the profiler's trace offline.
 
+Most of what the loop thread runs opens no section: asyncio's task
+steps, ``call_soon`` callbacks, future resolutions, timers.  So while
+tracing is on, and was switched on from a running loop, the tracer
+frames the loop's own dispatch (``_arm_turns``): every handle the loop
+runs is a frame ``turn.<kind>.<owner>`` on the section stack
+(accumulators only), the selector's wait is the section
+``idle.select``, a collector pass on the loop thread is ``gc.gen<n>``,
+and one iteration's run phase is a *turn* (counted; a ``turn`` record
+from 1 ms up).  The sections authors wrote open inside a handle's
+frame as its children, so a frame's self seconds are what ran
+"outside any section", by owner, and with ``loop.rest`` every second
+of the thread has a name.  Nothing is patched in a process that never
+enables tracing, and the hooks restore themselves at the first handle
+that finds ``enabled`` false.
+
 A trace context (one i64: ``seq << 1 | sampled``) rides the KV batch
 item and the ``AppendEntriesRequest`` as TRAILING defaulted wire fields
 — old decoders stop before them — so follower-side append/flush spans
@@ -55,11 +70,16 @@ up to* the incident survives ring churn.
 
 from __future__ import annotations
 
+import asyncio.events as _events
+import asyncio.tasks as _tasks
 import contextlib
+import functools
+import gc
 import json
 import random
 import threading
 import time
+import types
 from collections import deque
 from typing import Optional, Union
 
@@ -78,6 +98,19 @@ ANCHOR_EVENT = "tpuraft.trace_anchor"
 # a roll-up that finds this many whole seconds gone by was idle, not
 # busy: one record takes the lot and the buckets realign
 _MAX_SPREAD_BUCKETS = 8
+# what frames the loop's own dispatch: none is a section an author wrote,
+# so loop.cpu's busy_s and the loop_pct.* layers leave them out
+_FRAME_LAYERS = ("turn", "idle", "gc")
+_SELECT = "idle.select"
+_GC_NAMES = ("gc.gen0", "gc.gen1", "gc.gen2")
+# written every second while the dispatch is framed, 0.0 where nothing ran
+_EVERY_SECOND = ("turn.step", "turn.callback", "turn.timer", "turn",
+                 _SELECT, "gc")
+_TURN_RECORD_S = 0.001      # a turn this long leaves a record
+_TURN_LONG_S = 0.020        # one tick period: counted as long
+# the tracer whose frames are on the interpreter's dispatch (Handle._run
+# is the process's, so this is too), or None: nothing is patched
+_framing: Optional["Tracer"] = None
 
 
 # graftcheck: loop-confined — created and consumed only by the Tracer
@@ -142,6 +175,24 @@ class Tracer:
         # None = not looked for yet, False = no JAX here, else the
         # TraceAnnotation class (imported when a section first enters)
         self._annotation: Union[None, bool, type] = None
+        # the loop's dispatch, framed while tracing is on (_arm_turns):
+        # the loop whose handles, selector and collector passes are
+        # frames on the stack above (None = nothing is patched)
+        self._turn_loop = None
+        self._turn_run = None            # Handle._run as it was found
+        self._turn_t0 = 0.0              # the open turn's start (0 = none)
+        self._turn_handles = 0           # handles run in the open turn
+        # the frame or section with most self seconds in the open turn;
+        # inf while no turn is framed, so that no exit ever claims it
+        self._turn_top = ""
+        self._turn_top_s = float("inf")
+        # kind -> {qualname: the accumulator of turn.<kind>.<qualname>}
+        self._turn_accs: dict = {"step": {}, "timer": {}, "callback": {}}
+        self._gc_frame: Optional[list] = None
+        self._rolling = False
+        self.turns = 0
+        self.turn_handles = 0
+        self.turns_long = 0
         self._arm_sections()
 
     # -- lifecycle -----------------------------------------------------------
@@ -163,22 +214,28 @@ class Tracer:
         # would shift every already-recorded span in the export
         if enabled:
             self._arm_sections()
+        else:
+            self._disarm_turns()
         return self
 
     def _arm_sections(self) -> None:
         """Accumulators and buckets start here.  The thread that arms is
         taken for the loop thread until a section says otherwise."""
         self._close_open_sections()
-        # per name [calls, inclusive s, self s]
+        # per name [calls, inclusive s, self s, the name]
         self._sec_acc: dict[str, list] = {}
         self._sec_tid = _get_ident()
         # the once-a-second roll-up: the open bucket's start on both
-        # clocks, and the accumulators as the last roll-up left them
+        # clocks, the accumulators as the last roll-up left them, and
+        # the self seconds by name that the last roll-up took ahead of
+        # (open frames) or left behind (past the bucket's end) them
         self._bucket_t0 = _pc()
         self._bucket_cpu0 = _thread_time()
         self._bucket_snap: dict[str, tuple] = {}
+        self._bucket_carry: dict[str, float] = {}
         self.anchor = (time.perf_counter_ns(), time.time_ns())
         self._anchor_noted = False
+        self._arm_turns()
 
     def _close_open_sections(self) -> None:
         while self._sec_stack:
@@ -186,6 +243,258 @@ class Tracer:
             if frame[1] is not None:
                 frame[1].__exit__(None, None, None)
                 frame[1] = None
+
+    # -- the loop's dispatch, framed (what runs outside any section) ----------
+
+    def _arm_turns(self) -> None:
+        """Part of "loop sections on": armed from a running loop, every
+        handle that loop runs, every wait in its selector and every
+        collector pass on its thread becomes a frame on the section
+        stack.  ONE wrapper at ``asyncio.events.Handle._run`` (every
+        ready callback goes through it, the C ``Task``'s steps and
+        wake-ups too; the loop is whoever created it, so a subclass is
+        no option), ``select`` shadowed on the loop's selector, one
+        ``gc.callbacks`` hook.  Arming again starts the counts over and
+        stacks nothing."""
+        global _framing
+        self._turn_t0 = 0.0
+        self._turn_handles = 0
+        for accs in self._turn_accs.values():
+            accs.clear()            # they were the old accumulators'
+        self._gc_frame = None
+        self.turns = self.turn_handles = self.turns_long = 0
+        loop = _events._get_running_loop() if self.enabled else None
+        if loop is not self._turn_loop:
+            self._disarm_turns()
+        if loop is None:
+            return
+        if self._turn_loop is None:
+            if _framing is not None:
+                _framing._disarm_turns()
+            _framing = self
+            self._turn_loop = loop
+            self._turn_run = _events.Handle._run
+            _events.Handle._run = self._framed_run(self._turn_run)
+            selector = getattr(loop, "_selector", None)
+            if selector is not None:
+                selector.select = self._framed_select(selector.select)
+            gc.callbacks.append(self._on_gc)
+            if self._annotation is None:
+                self._find_annotation()     # not inside the first frame
+        # a pass must never be the first of its name while a roll-up
+        # walks the accumulators: its rows are there from the start
+        for name in _GC_NAMES:
+            self._acc_of(name)
+        self._turn_top, self._turn_top_s = "", 0.0
+
+    def _disarm_turns(self) -> None:
+        """Give the interpreter its dispatch back; what was counted
+        stays."""
+        global _framing
+        if self._turn_loop is None:
+            return
+        _events.Handle._run = self._turn_run
+        selector = getattr(self._turn_loop, "_selector", None)
+        if selector is not None:
+            vars(selector).pop("select", None)
+        with contextlib.suppress(ValueError):
+            gc.callbacks.remove(self._on_gc)
+        self._turn_loop = self._turn_run = None
+        self._turn_t0 = 0.0
+        self._turn_top_s = float("inf")
+        if _framing is self:
+            _framing = None
+
+    def _framed_run(self, run):
+        """``Handle._run`` with a frame around it: the handle's self
+        seconds (its run less the sections opened in it) go to
+        ``turn.<kind>.<owner>``.  No annotation: tens of thousands a
+        second would swamp the xplane.  Every handle of the process
+        pays this while tracing is on (PERF.md section 6, PR 39, has
+        the price), so the two common kinds (a C task's step, a plain
+        ``Handle``) find their accumulator in line and the exit does
+        :meth:`_pop`'s sums in line."""
+        stack = self._sec_stack
+        loop = self._turn_loop
+        step_accs = self._turn_accs["step"]
+        plain_accs = self._turn_accs["callback"]
+        # handles never nest, so one frame serves them all; and a new
+        # list between the clock read and the push could start a
+        # collector pass that is in the handle's seconds AND its own
+        frame = ["", None, 0.0, 0.0]
+
+        def framed(handle):
+            if not self.enabled:
+                # the harness just clears the flag: the first handle
+                # after that puts everything back
+                self._disarm_turns()
+                return run(handle)
+            if handle._loop is not loop:
+                return run(handle)          # another thread's loop
+            t0 = _pc()      # the look-up below is the handle's cost
+            cb = handle._callback
+            task = getattr(cb, "__self__", None)
+            acc = None
+            if task.__class__ is _tasks.Task:
+                coro = task.get_coro()
+                if coro.__class__ is types.CoroutineType:
+                    acc = step_accs.get(coro.__qualname__)
+            elif handle.__class__ is _events.Handle:
+                acc = plain_accs.get(getattr(cb, "__qualname__", None))
+            if acc is None:
+                acc = self._turn_acc(handle)
+            frame[0] = acc[3]
+            frame[2] = 0.0
+            frame[3] = t0
+            stack.append(frame)
+            self._turn_handles += 1
+            try:
+                return run(handle)
+            finally:
+                t1 = _pc()
+                if stack and stack[-1] is frame:
+                    del stack[-1]
+                    dur = t1 - t0
+                    own = dur - frame[2]
+                    acc[0] += 1
+                    acc[1] += dur
+                    acc[2] += own
+                    if own > self._turn_top_s:
+                        self._turn_top, self._turn_top_s = acc[3], own
+                    if stack:
+                        stack[-1][2] += dur
+                    if t1 - self._bucket_t0 >= 1.0 and self.enabled:
+                        self._roll(t1, frame)
+                else:       # a leave was skipped, or reset() ran under it
+                    self.leave(frame, t1)
+
+        return framed
+
+    def _turn_acc(self, handle) -> list:
+        """The accumulator of ``turn.step.<coroutine>`` for a task's
+        step or wake-up, else of ``turn.timer.`` / ``turn.callback.
+        <callable>``: qualnames with ``:`` for ``.`` (the name keeps
+        three dotted parts), built once a qualname (a code object would
+        hash its whole content on every look-up; a string does not);
+        ``?`` for what has no name."""
+        cb = handle._callback
+        task = getattr(cb, "__self__", None)
+        if isinstance(task, _tasks.Task):
+            kind = "step"
+            owner = getattr(task.get_coro(), "__qualname__", None)
+        else:
+            kind = "timer" if type(handle) is _events.TimerHandle \
+                else "callback"
+            while isinstance(cb, functools.partial):
+                cb = cb.func
+            owner = getattr(getattr(cb, "__func__", cb), "__qualname__",
+                            None)
+        accs = self._turn_accs[kind]
+        acc = accs.get(owner)
+        if acc is None:
+            shown = owner if isinstance(owner, str) else "?"
+            acc = accs[owner] = self._acc_of(
+                f"turn.{kind}.{shown.replace('.', ':')}")
+        return acc
+
+    def _framed_select(self, select):
+        """The selector's ``select`` inside the section ``idle.select``
+        (an annotation: on the profiler's host line it parts "the host
+        slept" from "the host was busy" inside a device gap).  Its call
+        ends a turn and its return begins the next."""
+
+        def framed(timeout=None):
+            if not self.enabled:
+                self._disarm_turns()
+                return select(timeout)
+            self._end_turn(_pc())
+            frame = self.enter(_SELECT)     # its own clock read: a pass
+            # that the turn's record began is not the selector's too
+            try:
+                return select(timeout)
+            finally:
+                t1 = _pc()
+                if frame is not None:
+                    self.leave(frame, t1)
+                self._turn_top, self._turn_top_s = "", 0.0
+                self._turn_handles = 0
+                self._turn_t0 = t1
+
+        return framed
+
+    def _end_turn(self, now: float) -> None:
+        """Count the turn that ends here; from 1 ms up it leaves a
+        record with the frame or section that took most of it, so a
+        slow round can be looked up: what ran in the turn a fence, a
+        tick and an arrival all waited behind."""
+        t0 = self._turn_t0
+        if not t0:
+            return
+        self.turns += 1
+        self.turn_handles += self._turn_handles
+        dur = now - t0
+        if dur < _TURN_RECORD_S:
+            return
+        if dur >= _TURN_LONG_S:
+            self.turns_long += 1
+        self._emit(_LOOP_TID, "turn", _LOOP_PROC, t0, now,
+                   {"handles": self._turn_handles, "top": self._turn_top,
+                    "top_s": self._turn_top_s})
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks``: a pass on the loop thread is a child
+        section of whatever allocated, so that section's self seconds no
+        longer hold it.  gen0 is accumulators only; gen1 and gen2, rare
+        and long, are annotations and one ring record a pass."""
+        if _get_ident() != self._sec_tid:
+            return
+        stack = self._sec_stack
+        if phase == "start":
+            if not self.enabled:
+                return
+            gen, ann = info["generation"], None
+            if gen and self._annotation:    # never imported from a pass
+                ann = self._annotation(_GC_NAMES[gen])
+                ann.__enter__()
+            self._gc_frame = [_GC_NAMES[gen], ann, 0.0, _pc()]
+            stack.append(self._gc_frame)
+            return
+        frame, self._gc_frame = self._gc_frame, None
+        if frame is None:
+            return
+        t1 = _pc()
+        # an exit like any other: a pass that ends past the second's
+        # end brings the roll-up, which splits it there
+        self.leave(frame, t1)
+        if info["generation"]:
+            self._emit(_LOOP_TID, frame[0], _LOOP_PROC, frame[3], t1,
+                       {"collected": info["collected"],
+                        "uncollectable": info["uncollectable"]})
+
+    @types.coroutine
+    def drive(self, name: str, coro):
+        """``await TRACER.drive(name, coro)``: await ``coro`` with
+        section ``name`` open around each of its synchronous stretches
+        and closed across every suspension, for a path whose awaits lie
+        in other modules (a replica's boot).  The caller tests
+        ``enabled`` first, as for :meth:`enter`."""
+        value = exc = None
+        while True:
+            frame = self.enter(name) if self.enabled else None
+            try:
+                if exc is None:
+                    waits_for = coro.send(value)
+                else:
+                    waits_for = coro.throw(exc)
+            except StopIteration as done:
+                return done.value
+            finally:
+                if frame is not None:
+                    self.leave(frame)
+            try:
+                value, exc = (yield waits_for), None
+            except BaseException as e:  # noqa: BLE001 — handed to coro
+                value, exc = None, e
 
     def reset(self) -> None:
         """Drop all recorded/staged spans and counters (test isolation)."""
@@ -225,8 +534,12 @@ class Tracer:
                 self._note_anchor(cls)
             ann = cls(name)
             ann.__enter__()
-        frame = [name, ann, 0.0, now or _pc()]
+        # on the stack before its clock is read: a collector pass that
+        # the new list starts is the enclosing frame's child, not in
+        # this section's seconds and its own
+        frame = [name, ann, 0.0, 0.0]
         self._sec_stack.append(frame)
+        frame[3] = now or _pc()
         return frame
 
     def leave(self, frame: list, now: float = 0.0) -> None:
@@ -242,7 +555,7 @@ class Tracer:
                 self._pop(stack.pop(), t1)
         self._pop(stack.pop(), t1)
         if t1 - self._bucket_t0 >= 1.0 and self.enabled:
-            self._roll(t1)
+            self._roll(t1, frame)
 
     @contextlib.contextmanager
     def section(self, name: str):
@@ -268,14 +581,22 @@ class Tracer:
             ann.__exit__(None, None, None)
             frame[1] = None
         dur = t1 - t0
-        acc = self._sec_acc.get(name)
-        if acc is None:
-            acc = self._sec_acc[name] = [0, 0.0, 0.0]
+        own = dur - child
+        acc = self._sec_acc.get(name) or self._acc_of(name)
         acc[0] += 1
         acc[1] += dur
-        acc[2] += dur - child
+        acc[2] += own
+        if own > self._turn_top_s:
+            self._turn_top, self._turn_top_s = name, own
         if self._sec_stack:
             self._sec_stack[-1][2] += dur
+
+    def _acc_of(self, name: str) -> list:
+        """``name``'s accumulator, made on first use."""
+        acc = self._sec_acc.get(name)
+        if acc is None:
+            acc = self._sec_acc[name] = [0, 0.0, 0.0, name]
+        return acc
 
     def _find_annotation(self):
         """Import JAX's annotation when a section first enters.  A
@@ -297,34 +618,84 @@ class Tracer:
         with cls(ANCHOR_EVENT, perf_counter_ns=pair[0], time_ns=pair[1]):
             pass
 
-    def _roll(self, now: float) -> None:
+    def _roll(self, now: float, popped: Optional[list] = None) -> None:
+        """:meth:`_roll_buckets`, but never from inside itself: a
+        collector pass that ends inside a roll-up has added to its
+        accumulator and waits for the next exit."""
+        if self._rolling:
+            return
+        self._rolling = True
+        try:
+            self._roll_buckets(now, popped)
+        finally:
+            self._rolling = False
+
+    def _roll_buckets(self, now: float, popped: Optional[list]) -> None:
         """Close the bucket(s) that ended before ``now``: one record per
-        section and per layer prefix with the self seconds spent there,
+        section, per layer (the name's first part) and, for a name of
+        three parts, per first two, with the self seconds spent there,
         and ``loop.cpu`` with the thread's CPU seconds.  Whole seconds
-        with no section exit in them share the delta evenly."""
+        with no section exit in them share the delta evenly.  ``popped``
+        is the frame whose exit brought the roll-up: no other frame has
+        closed since the buckets' end, so what each open frame and that
+        one ran before it and after it is known, and a bucket holds the
+        self seconds of its own wall seconds, no more.  While the loop's
+        dispatch is framed the frames' rows are written every second,
+        and ``loop.rest`` takes what no frame covered: the loop's own
+        bookkeeping between handles."""
         cpu = _thread_time()
         whole = int(now - self._bucket_t0)
         n_buckets = whole if whole <= _MAX_SPREAD_BUCKETS else 1
-        rows: dict[str, list] = {}
+        end = self._bucket_t0 + whole
+        leaf: dict[str, list] = {}
         snap = self._bucket_snap
-        total_n, total_self = 0, 0.0
         for name, acc in self._sec_acc.items():
             n0, busy0, self0 = snap.get(name, (0, 0.0, 0.0))
-            n, busy, self_s = acc[0] - n0, acc[1] - busy0, acc[2] - self0
-            if not n:
-                continue
-            snap[name] = (acc[0], acc[1], acc[2])
-            rows[name] = [n, busy, self_s]
-            prefix, dot, _what = name.partition(".")
-            if dot:
-                layer = rows.setdefault(prefix, [0, 0.0, 0.0])
-                layer[0] += n
-                layer[1] += busy
-                layer[2] += self_s
-            total_n += n
-            total_self += self_s
-        # busy_s of loop.cpu: the wall seconds the sections account for
-        rows["cpu"] = [total_n, total_self, cpu - self._bucket_cpu0]
+            if acc[0] != n0:
+                snap[name] = (acc[0], acc[1], acc[2])
+                leaf[name] = [acc[0] - n0, acc[1] - busy0, acc[2] - self0]
+        # the accumulators know a frame once it has closed: take the
+        # open frames' seconds up to ``end`` ahead of them, leave the
+        # popped frame's seconds past ``end`` to the next bucket, and
+        # settle what the last roll-up took or left
+        for name, part in self._bucket_carry.items():
+            leaf.setdefault(name, [0, 0.0, 0.0])[2] -= part
+        carry: dict[str, float] = {}
+        above, in_parent = now, 0.0
+        for frame in ([popped] if popped is not None else []) \
+                + self._sec_stack[::-1]:
+            name, t0 = frame[0], frame[3]
+            part = max(end, t0) - max(end, above)   # on top past ``end``
+            if frame is popped:
+                in_parent = now - t0
+            else:
+                part += (above - t0) - (frame[2] - in_parent)
+                in_parent = 0.0
+            if part:
+                carry[name] = carry.get(name, 0.0) + part
+                leaf.setdefault(name, [0, 0.0, 0.0])[2] += part
+            above = t0
+        self._bucket_carry = carry
+        rows: dict[str, list] = {}
+        total_n, named_self, all_self = 0, 0.0, 0.0
+        for name, row in leaf.items():
+            rows[name] = row
+            parts = name.split(".")
+            for k in range(1, min(len(parts), 3)):
+                prefix = rows.setdefault(".".join(parts[:k]), [0, 0.0, 0.0])
+                for f in range(3):
+                    prefix[f] += row[f]
+            all_self += row[2]
+            if parts[0] not in _FRAME_LAYERS:
+                total_n += row[0]
+                named_self += row[2]
+        if self._turn_loop is not None:
+            for name in _EVERY_SECOND:
+                rows.setdefault(name, [0, 0.0, 0.0])
+            rows["rest"] = [0, 0.0, whole - all_self]
+        # busy_s of loop.cpu: the wall seconds the authors' sections
+        # account for
+        rows["cpu"] = [total_n, named_self, cpu - self._bucket_cpu0]
         for k in range(n_buckets):
             rel0 = self._bucket_t0 + k - self._pc0
             for name, (n, busy, self_s) in rows.items():
@@ -340,7 +711,7 @@ class Tracer:
     def section_table(self) -> dict:
         """name -> (calls, inclusive seconds, self seconds) since the
         tracer was armed."""
-        return {name: tuple(acc)
+        return {name: tuple(acc[:3])
                 for name, acc in list(self._sec_acc.items())}
 
     # -- op lifecycle (locally-originated traces) ----------------------------
@@ -511,6 +882,11 @@ class Tracer:
             "trace_ops_slow_retained": self.ops_slow_retained,
             "trace_ops_dropped": self.ops_dropped,
             "trace_spans_recorded": self.spans_recorded,
+            # loop iterations framed, the handles they ran, and the
+            # turns of one tick period (20 ms) or more
+            "trace_turns": self.turns,
+            "trace_turn_handles": self.turn_handles,
+            "trace_turns_long": self.turns_long,
         }
         for name, (n, busy, self_s) in self.section_table().items():
             out[f"trace_section_calls_{name}"] = n
