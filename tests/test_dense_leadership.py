@@ -17,6 +17,8 @@ import os
 import shutil
 import time
 
+import pytest
+
 from benchmark import check_manifest
 from benchmark.driver import run_cell
 
@@ -93,22 +95,21 @@ async def test_a_booted_stores_objects_are_out_of_the_collectors_reach(
     hosts (0.6 s every 3 s at 12,288 on the chip host), so a store freezes
     what its boot built, batch by batch, then what its elections built.
     The collector is the process's: the stores that serve are counted, and
-    the heap stays frozen, and full collections braked, until the last of
-    them has shut down."""
+    the heap stays frozen and young passes at the serving threshold until
+    the last of them has shut down."""
     from tests.kv_cluster import KVTestCluster
     from tpuraft.rheakv import store_engine as se
 
     monkeypatch.setattr(se, "_gc_stores", 0)    # whatever ran before
-    monkeypatch.setattr(se, "_brake_full_collections",
-                        lambda: gc.set_threshold(700, 10, 77))
-    monkeypatch.setattr(se, "_release_full_collections",
-                        lambda: gc.set_threshold(*before))
+    monkeypatch.setattr(se, "_gc_thresholds_before", None)
     before = gc.get_threshold()
+    serving = (max(before[0], se._SERVING_YOUNG_THRESHOLD), *before[1:])
     gc.unfreeze()
     c = KVTestCluster(n_stores=3)
     await c.start_all()
     try:
         assert se._gc_stores == 3
+        assert gc.get_threshold() == serving
         booted = gc.get_freeze_count()
         assert booted > 1000                    # nodes, logs, machines
         leader = await c.wait_region_leader(1)
@@ -125,11 +126,12 @@ async def test_a_booted_stores_objects_are_out_of_the_collectors_reach(
         # (what the second freeze caught in flight dies by reference
         # count meanwhile: a message, a finished task)
         assert booted < gc.get_freeze_count() <= held
-        # one store stops: the two that serve keep the freeze and the brake
+        # one store stops: the two that serve keep the freeze and the
+        # serving threshold
         await c.stop_store(c.endpoints[0])
         assert se._gc_stores == 2
         assert gc.get_freeze_count() > 1000
-        assert gc.get_threshold() == (700, 10, 77)
+        assert gc.get_threshold() == serving
     finally:
         await c.stop_all()
     assert se._gc_stores == 0
@@ -137,26 +139,111 @@ async def test_a_booted_stores_objects_are_out_of_the_collectors_reach(
     assert gc.get_threshold() == before
 
 
-def test_full_collections_wait_for_a_quarter_of_the_frozen_heap(monkeypatch):
-    """The freeze hides the frozen heap from CPython's own brake on full
-    collections, so the store puts the quarter back, counted over what is
-    frozen: silent at a few regions, 12 at 1,024 regions a store (358,552
-    frozen), 52 at 4,096 (1,466,588), and given back at shutdown."""
+def _thresholds_found(monkeypatch, found: tuple):
+    """A copy of the collector's state as a process would have it before
+    its first store: no store counted, ``found`` in force; gives the
+    process's own thresholds back at the test's end."""
     from tpuraft.rheakv import store_engine as se
 
-    before = gc.get_threshold()
-    young, middle, _ = before
+    monkeypatch.setattr(se, "_gc_stores", 0)
+    monkeypatch.setattr(se, "_gc_thresholds_before", None)
+    own = gc.get_threshold()
+    gc.set_threshold(*found)
+    return se, own
+
+
+@pytest.mark.parametrize("found", [(700, 10, 10), (2_000, 10, 10),
+                                   (700, 10, 100)])
+def test_the_first_store_up_sets_the_serving_young_threshold(monkeypatch,
+                                                              found):
+    """An operation's objects live one batch round trip: while any store
+    serves, a young pass waits for the serving threshold's allocations
+    (the middle and oldest thresholds are the application's), and the
+    last store down gives back exactly what the first one found."""
+    se, own = _thresholds_found(monkeypatch, found)
     try:
-        monkeypatch.setattr(se.gc, "get_freeze_count", lambda: 20_000)
-        se._brake_full_collections()
-        assert gc.get_threshold() == before
-        monkeypatch.setattr(se.gc, "get_freeze_count", lambda: 1_466_588)
-        se._brake_full_collections()
-        oldest = 1_466_588 // (4 * young * middle)
-        assert gc.get_threshold() == (young, middle, oldest)
-        monkeypatch.setattr(se.gc, "get_freeze_count", lambda: 358_552)
-        se._brake_full_collections()        # a smaller store never lowers it
-        assert gc.get_threshold() == (young, middle, oldest)
+        se._gc_store_up()
+        serving = (se._SERVING_YOUNG_THRESHOLD, *found[1:])
+        assert gc.get_threshold() == serving
+        se._gc_store_up()                   # a second store changes nothing
+        assert gc.get_threshold() == serving
+        se._gc_store_down()                 # ... nor does its leaving
+        assert gc.get_threshold() == serving
+        se._gc_store_down()
+        assert gc.get_threshold() == found
+        assert se._gc_thresholds_before is None
     finally:
-        se._release_full_collections()
-    assert gc.get_threshold() == before
+        gc.set_threshold(*own)
+
+
+@pytest.mark.parametrize("found", [(1_000_000, 10, 10), (0, 10, 10)])
+def test_a_threshold_the_application_set_is_never_lowered(monkeypatch, found):
+    """An embedding application that raised the young threshold above the
+    serving one keeps its own; one that switched the collector off
+    (threshold 0) keeps it off."""
+    se, own = _thresholds_found(monkeypatch, found)
+    try:
+        se._gc_store_up()
+        assert gc.get_threshold() == found
+        se._gc_store_down()
+        assert gc.get_threshold() == found
+    finally:
+        gc.set_threshold(*own)
+
+
+@pytest.mark.parametrize("leave", ["shutdown", "crash"])
+async def test_the_last_store_down_restores_the_thresholds_it_found(
+        monkeypatch, leave):
+    """``shutdown()`` and ``crash()`` both count a store out, and the last
+    one out restores the exact triple the first one found."""
+    from tests.kv_cluster import KVTestCluster
+
+    found = (1_234, 7, 9)
+    se, own = _thresholds_found(monkeypatch, found)
+    try:
+        c = KVTestCluster(n_stores=3)
+        await c.start_all()
+        try:
+            await c.wait_region_leader(1)
+            serving = (se._SERVING_YOUNG_THRESHOLD, 7, 9)
+            assert gc.get_threshold() == serving
+            for ep in list(c.endpoints):
+                if leave == "crash":
+                    c.net.stop_endpoint(ep)
+                    c.net.unbind(ep)
+                    c.stores.pop(ep).crash()
+                else:
+                    await c.stop_store(ep)
+                if c.stores:
+                    assert gc.get_threshold() == serving
+        finally:
+            await c.stop_all()
+        assert se._gc_stores == 0
+        assert gc.get_threshold() == found
+        assert gc.get_freeze_count() == 0
+    finally:
+        gc.set_threshold(*own)
+
+
+def test_a_requests_objects_die_before_a_young_pass_sees_them(monkeypatch):
+    """What the serving threshold is for: objects that live as long as a
+    batch of requests in flight (here 1,000 at a time) are freed by
+    reference count before a young pass ever examines them, where
+    CPython's 700 examines nearly every batch."""
+    def young_passes_over_batches() -> int:
+        gc.collect()                        # the young count from 0
+        first = gc.get_stats()[0]["collections"]
+        for _ in range(200):
+            batch = [[] for _ in range(1_000)]
+            del batch
+        return gc.get_stats()[0]["collections"] - first
+
+    se, own = _thresholds_found(monkeypatch, (700, 10, 10))
+    try:
+        assert young_passes_over_batches() >= 100
+        se._gc_store_up()
+        assert young_passes_over_batches() == 0
+        se._gc_store_down()
+        assert young_passes_over_batches() >= 100
+    finally:
+        gc.set_threshold(*own)

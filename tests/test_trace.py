@@ -1282,6 +1282,9 @@ async def test_metrics_text_and_describe_metrics_rpc():
         assert "# TYPE tpuraft_recorder_events counter" in text
         assert "# TYPE tpuraft_trace_ring_spans gauge" in text
         assert "# TYPE tpuraft_trace_slow_ema_ms gauge" in text
+        # the collector: passes by generation, the young threshold
+        assert "# TYPE tpuraft_gc_collections_gen2 counter" in text
+        assert "# TYPE tpuraft_gc_threshold_young gauge" in text
         # over the wire: the admin scrape returns the same rendering
         cli = CliService(c.client_transport("admin:0"))
         remote = await cli.describe_metrics(str(store.server_id))
@@ -1290,6 +1293,29 @@ async def test_metrics_text_and_describe_metrics_rpc():
     finally:
         await kv.shutdown()
         await c.stop_all()
+
+
+def test_the_collectors_passes_and_young_threshold_are_exported():
+    """A scrape and a ``describe()`` show how often each generation runs
+    and the young threshold in force (a serving store raises it), with
+    tracing off: the counts are CPython's own, monotonic across a forced
+    full collection."""
+    assert not TRACER.enabled
+    first = TRACER.counters()
+    names = [f"gc_collections_gen{g}" for g in range(3)]
+    assert all(isinstance(first[n], int) for n in names)
+    gc.collect()
+    then = TRACER.counters()
+    assert all(then[n] >= first[n] for n in names)
+    assert then["gc_collections_gen2"] >= first["gc_collections_gen2"] + 1
+    own = gc.get_threshold()
+    try:
+        gc.set_threshold(12_345, *own[1:])
+        assert TRACER.gauges()["gc_threshold_young"] == 12_345
+        assert "gc_young=12345" in TRACER.describe()
+    finally:
+        gc.set_threshold(*own)
+    assert TRACER.gauges()["gc_threshold_young"] == own[0]
 
 
 async def test_metrics_text_renders_the_section_table():

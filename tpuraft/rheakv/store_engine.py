@@ -72,64 +72,65 @@ def _dir_usage_bytes(root: str) -> int:
 # boot and under load alike, for garbage counted in dozens.  What is
 # frozen is still freed by reference counting; only a cycle that dies
 # later (a retired region's graph) waits for the unfreeze at the last
-# store's shutdown.  The freeze also hides those objects from the
-# collector's own brake on full passes (one runs only once a quarter as
-# many objects were promoted as the last one kept), so with 1.5 M
-# frozen a full pass ran on every tenth middle collection, twice as
-# often as unfrozen; _brake_full_collections puts the quarter back,
-# counted over what is frozen.  The collector is the process's, so this
-# state is too: the stores between start() and shutdown() are counted,
-# and the last one out gives back what they all took.
+# store's shutdown.
+#
+# While a store serves, a young pass waits for _SERVING_YOUNG_THRESHOLD
+# tracked allocations (net of deallocations) where CPython waits for
+# 700.  An operation's objects (the client batch, its command rows,
+# proposal futures, log entries, the riders' callbacks) live one batch
+# round trip, about 150 ms; at 700 a young pass came every 4 to 5 ms, so
+# every request in flight was examined young, again in a middle pass,
+# and what those promoted filled the oldest generation for the next full
+# pass to walk.  At 50,000 they die by reference count before a young
+# pass sees them.  Chosen by a sweep on the chip over 700, 2,000,
+# 10,000, 20,000 and 50,000 (PERF.md section 6, PR 40): in
+# kv3x1024.ycsb_a the collector took 127.9 ms of every second at 700
+# (32 full passes of 44 ms at the median in 25 s) and 15.2 at 50,000
+# (23 young passes of 12.7 ms, two middle ones of 41, no full one); the
+# loop's turn p95 was the lowest at 50,000 in both cells swept.  Middle
+# passes come once in 10 to 45 s, so full ones (every tenth middle one)
+# no longer need PR 29's brake on the oldest threshold.
+#
+# The collector is the process's, so this state is too: the stores
+# between start() and shutdown() (or crash()) are counted, the first one
+# in sets the serving threshold and the last one out gives back the
+# exact thresholds the first one found.
+_SERVING_YOUNG_THRESHOLD = 50_000
 _gc_lock = threading.Lock()
 _gc_stores = 0                      # guarded-by: _gc_lock
-# the oldest generation's threshold before the first store of this
-# process raised it (None = untouched)
-_gc_oldest_threshold_before: Optional[int] = None   # guarded-by: _gc_lock
-
-
-def _brake_full_collections() -> None:
-    """As many middle collections between two full ones as a quarter of
-    the frozen objects takes to allocate (CPython's own rule for the
-    oldest generation, which cannot see them).  Young and middle
-    collections keep their thresholds; never lowers the oldest one."""
-    global _gc_oldest_threshold_before
-    with _gc_lock:
-        young, middle, oldest = gc.get_threshold()
-        if young <= 0:
-            return  # the collector is off
-        want = gc.get_freeze_count() // (4 * young * max(middle, 1))
-        if want > oldest:
-            if _gc_oldest_threshold_before is None:
-                _gc_oldest_threshold_before = oldest
-            gc.set_threshold(young, middle, want)
-
-
-def _release_full_collections() -> None:
-    global _gc_oldest_threshold_before
-    with _gc_lock:
-        if _gc_oldest_threshold_before is not None:
-            young, middle, _ = gc.get_threshold()
-            gc.set_threshold(young, middle, _gc_oldest_threshold_before)
-            _gc_oldest_threshold_before = None
+# (young, middle, oldest) before the first store of this process set
+# them (None = no store serves)
+_gc_thresholds_before: Optional[tuple] = None   # guarded-by: _gc_lock
 
 
 def _gc_store_up() -> None:
-    global _gc_stores
+    """The first store of the process sets the serving threshold: the
+    young generation's to _SERVING_YOUNG_THRESHOLD, never below what
+    the application set, and not at all if the collector is off."""
+    global _gc_stores, _gc_thresholds_before
     with _gc_lock:
         _gc_stores += 1
+        if _gc_stores > 1:
+            return
+        _gc_thresholds_before = young, middle, oldest = gc.get_threshold()
+        if young > 0:
+            gc.set_threshold(max(young, _SERVING_YOUNG_THRESHOLD), middle,
+                             oldest)
 
 
 def _gc_store_down() -> None:
     """A store's regions are shut down.  Their graphs are garbage now,
-    but the freeze and the brake are also the still-serving stores':
-    only the last store of the process unfreezes and releases."""
-    global _gc_stores
+    but the freeze and the serving threshold are also the still-serving
+    stores': only the last store of the process unfreezes and restores
+    the thresholds the first one found."""
+    global _gc_stores, _gc_thresholds_before
     with _gc_lock:
         _gc_stores -= 1
-        last = _gc_stores == 0
-    if last:
+        if _gc_stores > 0:
+            return
         gc.unfreeze()
-        _release_full_collections()
+        gc.set_threshold(*_gc_thresholds_before)
+        _gc_thresholds_before = None
 
 
 @dataclass
@@ -968,7 +969,6 @@ class StoreEngine:
                 if isinstance(res, BaseException):
                     raise res
             gc.freeze()
-        _brake_full_collections()
         floor_ms = 0
         if self.multi_raft_engine is not None:
             # the boot burst is over: every row to the floor of the
@@ -1007,7 +1007,6 @@ class StoreEngine:
                    for n in nodes):
                 break
         gc.freeze()
-        _brake_full_collections()
 
     def _wire_multilog(self) -> None:
         """multilog scheme: the store's shared flush round times every

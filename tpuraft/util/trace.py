@@ -888,6 +888,10 @@ class Tracer:
             "trace_turn_handles": self.turn_handles,
             "trace_turns_long": self.turns_long,
         }
+        # the collector's passes by generation, counted by CPython
+        # whether tracing is on or not
+        for gen, st in enumerate(gc.get_stats()):
+            out[f"gc_collections_gen{gen}"] = st["collections"]
         for name, (n, busy, self_s) in self.section_table().items():
             out[f"trace_section_calls_{name}"] = n
             out[f"trace_section_busy_seconds_{name}"] = round(busy, 6)
@@ -900,6 +904,9 @@ class Tracer:
             "trace_enabled": int(self.enabled),
             "trace_ring_spans": len(self._ring),
             "trace_slow_ema_ms": round(self._p99_ema * 1000.0, 3),
+            # the young generation's threshold in force: a serving
+            # store raises it (rheakv/store_engine.py:_gc_store_up)
+            "gc_threshold_young": gc.get_threshold()[0],
         }
 
     def stats(self) -> dict:
@@ -912,7 +919,10 @@ class Tracer:
                 f"ops={c['trace_ops_seen']} sampled={c['trace_ops_sampled']} "
                 f"slow_retained={c['trace_ops_slow_retained']} "
                 f"ring={c['trace_ring_spans']} "
-                f"p99_ema={c['trace_slow_ema_ms']}ms>")
+                f"p99_ema={c['trace_slow_ema_ms']}ms "
+                f"gc_young={c['gc_threshold_young']} gc_passes="
+                f"{c['gc_collections_gen0']}/{c['gc_collections_gen1']}/"
+                f"{c['gc_collections_gen2']}>")
 
 
 # -- trace-context wire helpers ----------------------------------------------
