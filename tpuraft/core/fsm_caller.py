@@ -3,7 +3,9 @@
 Reference parity: ``core:core/FSMCallerImpl`` (SURVEY.md §3.1) — the
 Disruptor + ApplyTaskHandler becomes a single asyncio consumer task; all
 StateMachine callbacks (apply batches, snapshot save/load, role events)
-run on it in submission order, so user code never sees concurrency.
+run on it in submission order, so user code never sees concurrency.  A
+``StagedStateMachine``'s plain writes may instead apply in its store's
+apply pass, with no task (``FSMCaller``).
 """
 
 from __future__ import annotations
@@ -17,13 +19,42 @@ from typing import Awaitable, Callable, Optional
 from tpuraft.conf import Configuration
 from tpuraft.entity import EntryType, LogEntry, LogId, PeerId
 from tpuraft.errors import RaftError, RaftException, Status
-from tpuraft.core.state_machine import Iterator, StateMachine
+from tpuraft.core.state_machine import (
+    Iterator,
+    StagedStateMachine,
+    StateMachine,
+)
+from tpuraft.util.metrics import Histogram
 from tpuraft.util.trace import TRACER as _TRACE
 
 LOG = logging.getLogger(__name__)
 
 
 class FSMCaller:
+    """One group's apply pipeline, by two routes.
+
+    The drain task: every event (``("committed", index)``, a role
+    change, a snapshot save or load, an error, the shutdown) is queued,
+    and a demand-spawned task runs the queue in order through the state
+    machine's coroutines.  It is the one general route.
+
+    The apply pass: a commit that finds the caller idle (nothing queued,
+    no drain task alive, not poisoned, not shut) and its state machine a
+    ``StagedStateMachine`` queues nothing and joins the state machine's
+    ``apply_round``.  The round's pass, one callback a loop turn, stages
+    up to ``apply_batch`` of the caller's entries (``pass_stage``),
+    writes every caller's rows in one store call and then finishes each
+    caller (``pass_finish``): results, closures, the applied index, read
+    waiters.  What does not ride (an entry that is not a plain write, a
+    configuration, a no-op) goes to the drain task behind what the pass
+    applied; a caller capped by ``apply_batch`` joins the next pass.
+
+    Order is the guarantee on both routes: no closure, applied index,
+    read waiter or snapshot save moves ahead of the store call that
+    wrote the rows it stands on, and an event queued while the caller
+    waits in a pending pass takes it out of the pass, behind its
+    committed entries, so the state machine sees them first."""
+
     def __init__(self, fsm: StateMachine, log_manager, apply_batch: int = 32,
                  on_error: Optional[Callable[[Status], Awaitable[None]]] = None,
                  health=None, trace_proc: str = "fsm"):
@@ -61,6 +92,16 @@ class FSMCaller:
         # short-lived drain task runs only while events exist.
         self._queue: deque = deque()
         self._task: Optional[asyncio.Task] = None
+        # the apply pass: whether this caller waits in its store's
+        # pending pass, and between the pass's two halves what it staged
+        self._in_pass = False
+        self._passing: Optional[tuple] = None
+        # on_apply calls its drain task made: counted on the store's
+        # histogram when the state machine applies through one (with
+        # the pass's ``pass_regions``: the share of applies that took
+        # the pass), else on the caller's own
+        self.task_runs = fsm.apply_round.task_runs \
+            if isinstance(fsm, StagedStateMachine) else Histogram()
         self._shut = False
         self._error: Optional[Status] = None
         self._applied_waiters: list[tuple[int, asyncio.Future]] = []
@@ -72,7 +113,11 @@ class FSMCaller:
         """Witness adoption (Node._adopt_witness_mode): swap the user
         FSM for the null witness FSM.  Runs on the node loop between
         queue drains; events already queued simply land on the new FSM
-        — their payloads are stripped/irrelevant on a witness."""
+        — their payloads are stripped/irrelevant on a witness.  A caller
+        waiting in a pending pass leaves it: its entries go to the
+        drain task, on the new FSM."""
+        if self._in_pass:
+            self._enqueue(("committed", self._committed_index))
         self._fsm = fsm
 
     async def init(self, bootstrap_id: LogId) -> None:
@@ -90,6 +135,7 @@ class FSMCaller:
         """A crash: what is queued is never applied, and whoever waits
         for an apply is told the node went."""
         self._shut = True
+        self._in_pass = False   # the pending pass skips this caller
         self._queue.clear()
         if self._task is not None and not self._task.done():
             self._task.cancel()
@@ -104,6 +150,11 @@ class FSMCaller:
     def _enqueue(self, item) -> None:
         if self._shut:
             return
+        if self._in_pass:
+            # out of the pending pass: what it was to apply goes first
+            self._in_pass = False
+            if item[0] != "committed":
+                self._queue.append(("committed", self._committed_index))
         self._queue.append(item)
         if self._task is None or self._task.done():
             self._task = asyncio.ensure_future(self._drain())
@@ -147,7 +198,24 @@ class FSMCaller:
                     done(Status.OK())
                 except Exception:
                     LOG.exception("eager closure failed")
+        if self._in_pass:
+            return      # the pending pass reads up to the new index
+        if self._can_pass():
+            self._join_pass()
+            return
         self._enqueue(("committed", index))
+
+    def _can_pass(self) -> bool:
+        """Idle, and the state machine applies plain writes in a pass:
+        observed on each commit, so a swapped FSM counts at once."""
+        return (isinstance(self._fsm, StagedStateMachine)
+                and not self._queue
+                and (self._task is None or self._task.done())
+                and self._error is None and not self._shut)
+
+    def _join_pass(self) -> None:
+        self._in_pass = True
+        self._fsm.apply_round.join(self)
 
     def on_leader_start(self, term: int) -> None:
         self._enqueue(("leader_start", term))
@@ -193,6 +261,8 @@ class FSMCaller:
         return fut
 
     def _wake_applied_waiters(self) -> None:
+        if not self._applied_waiters:
+            return
         rest = []
         for idx, fut in self._applied_waiters:
             if fut.done():
@@ -202,6 +272,91 @@ class FSMCaller:
             else:
                 rest.append((idx, fut))
         self._applied_waiters = rest
+
+    # -- the apply pass (called by the store's round) -------------------------
+
+    def pass_stage(self):
+        """The pass's first half for this caller: stage up to
+        ``apply_batch`` committed DATA entries from the applied index on,
+        up to the first that does not ride.  Returns the run to write, or
+        None when the caller left the pass or its next entry does not
+        ride (the drain task then takes the entries)."""
+        if not self._in_pass:
+            return None
+        self._in_pass = False
+        first = self.last_applied_index + 1
+        last = min(self._committed_index, first + self._apply_batch - 1)
+        get = self._lm.get_entry
+        entries: list[LogEntry] = []
+        closures = self._closures
+        try:
+            for idx in range(first, last + 1):
+                e = get(idx)
+                if e is None or e.type != EntryType.DATA:
+                    break   # the drain task reports a missing entry
+                entries.append(e)
+            dones = [closures.get(e.id.index) for e in entries] \
+                if closures else [None] * len(entries)
+            run = self._fsm.stage_entries(entries, dones) \
+                if entries else None
+        except Exception:
+            self._crashed("stage")
+            return None
+        if run is None:
+            self._enqueue(("committed", self._committed_index))
+            return None
+        n = run.entries
+        if closures:
+            for e in entries[:n]:
+                closures.pop(e.id.index, None)
+        tids = ([e.trace_id for e in entries[:n] if e.trace_id]
+                if _TRACE.enabled else None)
+        self._passing = (entries[n - 1].id, dones[:n], n < last + 1 - first,
+                         tids, time.perf_counter() if tids else 0.0)
+        return run
+
+    def pass_finish(self, run, err: Optional[Exception]) -> None:
+        """The pass's second half, once the store call holding the run's
+        rows returned (``err`` None) or raised: results and closures,
+        the applied index, read waiters.  Then the caller joins the next
+        pass if ``apply_batch`` capped it, or hands what is left to the
+        drain task."""
+        last, dones, stopped, tids, t0 = self._passing
+        self._passing = None
+        try:
+            self._fsm.finish_staged(run, err)
+            for done in dones:  # auto-complete closures the FSM didn't run
+                if done is not None:
+                    done(Status.OK())
+        except Exception:
+            self._crashed("finish")
+            return
+        self.last_applied_index = last.index
+        self.last_applied_term = last.term
+        self.apply_batches += 1
+        self.applied_entries += len(dones)
+        self._lm.set_applied_index(last.index)
+        self._wake_applied_waiters()
+        if tids:
+            t1 = time.perf_counter()
+            for tid in tids:
+                _TRACE.span(tid, "fsm_apply", t0, t1,
+                            proc=self._trace_proc, entries=len(dones))
+        if self.last_applied_index < self._committed_index:
+            if not stopped and self._can_pass():
+                self._join_pass()
+            else:
+                self._enqueue(("committed", self._committed_index))
+
+    def _crashed(self, half: str) -> None:
+        """A crash in this caller's half of the pass stays with it: the
+        error reaches the state machine and the node through the drain
+        task, as a crash on the task does; the pass goes on."""
+        LOG.exception("FSMCaller apply pass %s crashed", half)
+        self._queue.appendleft(("crashed", Status.error(
+            RaftError.ESTATEMACHINE, f"apply pass {half} raised")))
+        if self._task is None or self._task.done():
+            self._task = asyncio.ensure_future(self._drain())
 
     # -- consumer ------------------------------------------------------------
 
@@ -215,7 +370,9 @@ class FSMCaller:
                     return
                 if self._error is not None and kind not in ("error",):
                     continue  # poisoned: only error propagation continues
-                if kind == "committed":
+                if kind == "crashed":   # in the apply pass
+                    await self._set_error(arg)
+                elif kind == "committed":
                     await self._do_committed(arg)
                 elif kind == "leader_start":
                     await self._fsm.on_leader_start(arg)
@@ -300,6 +457,7 @@ class FSMCaller:
                     tids = ([x.trace_id for x in run if x.trace_id]
                             if _TRACE.enabled else [])
                     a0 = time.perf_counter() if tids else 0.0
+                    self.task_runs.update(1)
                     try:
                         await self._fsm.on_apply(it)
                     except Exception:

@@ -113,6 +113,37 @@ class StateMachine:
 StateMachineAdapter = StateMachine
 
 
+class StagedStateMachine(StateMachine):
+    """A state machine whose plain writes can apply with no task: in its
+    store's apply pass, one callback a loop turn (``FSMCaller``).
+
+    ``apply_round`` is shared by the state machines of one store: an
+    idle ``FSMCaller`` joins it on a commit (``join(caller)``), and its
+    pass calls ``FSMCaller.pass_stage`` of every caller that joined,
+    writes what they staged in one store call and then calls each one's
+    ``FSMCaller.pass_finish``.  Its ``task_runs`` histogram counts the
+    ``on_apply`` calls its callers' drain tasks made.  The methods below
+    never await: an entry that does not ride the pass goes to
+    ``on_apply`` on the caller's drain task, as any state machine's
+    does, with every entry after it."""
+
+    apply_round = None
+
+    def stage_entries(self, entries: list[LogEntry],
+                      closures: list[Optional[Callable[[Status], None]]]):
+        """Stage the leading DATA ``entries`` that ride the pass, with
+        their closures; return the run (its ``entries`` is how many were
+        staged, ``rows`` what the store call writes, ``leader`` whether
+        this replica leads its group) or None when the first does not
+        ride."""
+        raise NotImplementedError
+
+    def finish_staged(self, run, err: Optional[Exception]) -> None:
+        """Report ``run``'s entries in log order once the store call
+        holding its rows returned (``err`` None) or raised ``err``."""
+        raise NotImplementedError
+
+
 # graftcheck: loop-confined — FSMCaller runs every callback serialized
 # on the node's event loop
 class WitnessStateMachine(StateMachine):

@@ -13,7 +13,7 @@ import logging
 import struct
 from typing import Optional
 
-from tpuraft.core.state_machine import Iterator, StateMachine
+from tpuraft.core.state_machine import Iterator, StagedStateMachine
 from tpuraft.errors import RaftError, Status
 from tpuraft.rheakv.kv_operation import KVOp, KVOperation
 from tpuraft.rheakv.metadata import Region
@@ -93,36 +93,71 @@ SYNC_SECTION = "fsm.sync.wal"
 _LEADS, _FOLLOWS = 1, 2      # a stager's role here, as a bit of a round's
 
 
+class _Run:
+    """What one region staged in one apply pass: its rows, per entry
+    ``(done, closure, a MULTI's sub-op count or None)``, how many log
+    entries they are and whether the region leads its group here."""
+
+    __slots__ = ("rows", "dones", "entries", "leader")
+
+    def __init__(self, rows: list, dones: list, leader: bool) -> None:
+        self.rows = rows
+        self.dones = dones
+        self.entries = len(dones)
+        self.leader = leader
+
+
 class ApplyRound:
-    """The store-wide apply round: the KV WAL's group commit.
+    """The store-wide apply round: the KV WAL's group commit, and the
+    store's apply pass.
 
-    Every region of a store that has committed runs to apply in the
-    same turn of the event loop stages its rows here, and the turn's
-    rows reach the raw store in ONE ``apply_write_batch`` call: on the
-    native engine one WAL record and one ``fsync``, where each region's
-    run made its own.  The first stager of a turn schedules the flush
-    with ``call_soon``; the drain tasks a commit burst made ready are
-    ahead of that callback in the ready queue, so the round closes on
-    whatever the turn offers and a lone apply is written in the turn it
-    arrived.  Nothing decides a round's end but the loop's own order.
+    Rows reach a round by two routes (``FSMCaller``).  A region whose
+    commit advanced while its FSMCaller was idle JOINS the round
+    (``join``) and applies inside the round's own callback, with no
+    task: the pass stages its entries (``FSMCaller.pass_stage``),
+    writes, and finishes them (``FSMCaller.pass_finish``).  A region on
+    its drain task STAGES a run from ``on_apply`` (``stage``) and awaits
+    a future.  Either way the turn's rows reach the raw store in ONE
+    ``apply_write_batch`` call: on the native engine one WAL record and
+    one ``fsync``, where each region's run made its own.  The first join
+    or stage of a turn schedules the callback (``flush``) with
+    ``call_soon``, so the round closes on whatever the turn offers and
+    a lone commit is written in the next turn.  Nothing decides a
+    round's end but the loop's own order.
 
-    Order is the guarantee: a stager's future resolves only after the
-    store call covering its rows returned, and ``on_apply`` returns only
-    after its future, so no closure, applied index, read waiter or
-    snapshot save moves ahead of the fsync of the rows it stands on."""
+    Order is the guarantee: a region is finished in the pass, and a
+    stager's future resolves, only after the store call covering its
+    rows returned, and ``on_apply`` returns only after its future, so no
+    closure, applied index, read waiter or snapshot save moves ahead of
+    the fsync of the rows it stands on."""
 
     def __init__(self, store: RawKVStore) -> None:
         self.store = store
+        self._callers: list = []  # FSMCallers of the pending pass, in order
         self._staged: list = []   # (rows, entries, future) of the open round
         self._roles = 0           # _LEADS | _FOLLOWS: its stagers' roles
         # events, one sample each, so a window's ``count`` is the
         # number: a round's store call, a log entry that rode one
-        # (sync_entries.count / syncs.count = entries per fsync), and a
+        # (sync_entries.count / syncs.count = entries per fsync), a
         # round in which a region this store leads and one it follows
-        # applied together (none on a store with one role)
+        # applied together (none on a store with one role), a region's
+        # run applied in a pass, and an ``on_apply`` call a region's
+        # drain task made (pass_regions.count over the two counts is
+        # the share of applies that took the pass)
         self.syncs = Histogram()
         self.sync_entries = Histogram()
         self.syncs_mixed = Histogram()
+        self.pass_regions = Histogram()
+        self.task_runs = Histogram()
+
+    def _open(self) -> None:
+        if not self._callers and not self._staged:
+            asyncio.get_running_loop().call_soon(self.flush)
+
+    def join(self, caller) -> None:
+        """Put an idle FSMCaller on the pending pass."""
+        self._open()
+        self._callers.append(caller)
 
     def stage(self, rows: list, entries: int,
               leader: bool = False) -> asyncio.Future:
@@ -130,52 +165,77 @@ class ApplyRound:
         round; the future resolves to None once they are written, or to
         the exception their write raised.  ``leader``: the region that
         applies them leads its group here."""
-        loop = asyncio.get_running_loop()
-        fut = loop.create_future()
-        if not self._staged:
-            loop.call_soon(self.flush)
+        fut = asyncio.get_running_loop().create_future()
+        self._open()
         self._staged.append((rows, entries, fut))
         self._roles |= _LEADS if leader else _FOLLOWS
         return fut
 
     def flush(self) -> None:
-        """Write the open round and resolve its stagers.  Also the store
-        shutdown's last call before the raw store closes."""
-        staged = self._staged
-        if not staged:
+        """The pass: stage the joined regions' entries, write them and
+        the open round's stagers in one store call, finish the joined
+        regions, resolve the stagers.  Also the store shutdown's last
+        call before the raw store closes."""
+        callers, staged = self._callers, self._staged
+        if not callers and not staged:
             return
-        roles, self._staged, self._roles = self._roles, [], 0
+        roles, self._callers, self._staged, self._roles = \
+            self._roles, [], [], 0
+        runs: list = []   # (caller, _Run)
+        if callers:
+            sec = TRACER.enter("fsm.apply") if TRACER.enabled else None
+            try:
+                for caller in callers:
+                    run = caller.pass_stage()
+                    if run is not None:
+                        runs.append((caller, run))
+                        roles |= _LEADS if run.leader else _FOLLOWS
+            finally:
+                if sec is not None:
+                    TRACER.leave(sec)
+        writes = [run.rows for _caller, run in runs]
+        writes += [rows for rows, _n, _fut in staged]
+        if not writes:
+            return
         sec = TRACER.enter(SYNC_SECTION) if TRACER.enabled else None
         try:
-            errors = self._write(staged)
+            errors = self._write(writes)
         finally:
             if sec is not None:
                 TRACER.leave(sec)
         self.syncs.update(1)
         if roles == _LEADS | _FOLLOWS:
             self.syncs_mixed.update(1)
-        self.sync_entries.update(1, sum(n for _rows, n, _fut in staged))
-        for (_rows, _n, fut), err in zip(staged, errors):
+        self.sync_entries.update(1, sum(run.entries for _c, run in runs)
+                                 + sum(n for _rows, n, _fut in staged))
+        if runs:
+            self.pass_regions.update(1, len(runs))
+            sec = TRACER.enter("fsm.apply") if TRACER.enabled else None
+            try:
+                for (caller, run), err in zip(runs, errors):
+                    caller.pass_finish(run, err)
+            finally:
+                if sec is not None:
+                    TRACER.leave(sec)
+        for (_rows, _n, fut), err in zip(staged, errors[len(runs):]):
             if not fut.done():
                 fut.set_result(err)
 
-    def _write(self, staged: list) -> list:
-        """One store call for the round.  If it raises, each stager's
-        rows are written on their own (blind puts and deletes: writing
+    def _write(self, runs: list) -> list:
+        """One store call for the round's runs of rows.  If it raises,
+        each run is written on its own (blind puts and deletes: writing
         a row twice is writing it once), so a failure stays with the
-        region whose rows caused it."""
-        if len(staged) == 1:
-            rows = staged[0][0]
-        else:
-            rows = [row for run, _n, _fut in staged for row in run]
+        region whose rows caused it.  Returns each run's error or None."""
+        rows = runs[0] if len(runs) == 1 else \
+            [row for run in runs for row in run]
         try:
             self.store.apply_write_batch(rows)
-            return [None] * len(staged)
-        except Exception as e:  # noqa: BLE001 — reported per stager below
-            if len(staged) == 1:
+            return [None] * len(runs)
+        except Exception as e:  # noqa: BLE001 — reported per run below
+            if len(runs) == 1:
                 return [e]
         errors: list = []
-        for run, _n, _fut in staged:
+        for run in runs:
             try:
                 self.store.apply_write_batch(run)
                 errors.append(None)
@@ -184,7 +244,7 @@ class ApplyRound:
         return errors
 
 
-class KVStoreStateMachine(StateMachine):
+class KVStoreStateMachine(StagedStateMachine):
     # write ops the apply coalescer folds into one mixed store write
     # (all return True and only touch the data namespace)
     _RUN_OPS = frozenset(
@@ -230,6 +290,49 @@ class KVStoreStateMachine(StateMachine):
         if code == KVOp.PUT_LIST:
             return list(KVOperation.unpack_kv_list(op.value))
         return [(k, None) for k in KVOperation.unpack_key_list(op.value)]
+
+    def _run_of(self, op: KVOperation) -> tuple:
+        """``(subs, rows)``: a MULTI's sub-ops (None for any other op)
+        and, when the entry rides the store's apply round, its rows (else
+        None).  A run entry is a PUT/DELETE(-list), or a MULTI of nothing
+        else, of a region that is not sealed."""
+        if self.sealed_into >= 0:
+            return None, None
+        if op.op != KVOp.MULTI:
+            return None, (self._run_rows(op) if op.op in self._RUN_OPS
+                          else None)
+        subs = KVOperation.unpack_multi(op.value)
+        if not all(w.op in self._RUN_OPS for w in subs):
+            return subs, None
+        rows: list = []
+        for w in subs:
+            rows.extend(self._run_rows(w))
+        return subs, rows
+
+    # -- the apply pass (StagedStateMachine) ---------------------------------
+
+    def stage_entries(self, entries: list, closures: list) -> Optional[_Run]:
+        rows: list = []
+        dones: list = []
+        for e, done in zip(entries, closures):
+            try:
+                subs, op_rows = self._run_of(KVOperation.decode(e.data))
+            except Exception:  # noqa: BLE001 — on_apply meets it again
+                break
+            if op_rows is None:
+                break
+            rows.extend(op_rows)
+            dones.append((done, done if isinstance(done, KVClosure) else None,
+                          None if subs is None else len(subs)))
+        if not dones:
+            return None
+        return _Run(rows, dones, self.leader_term >= 0)
+
+    def finish_staged(self, run: _Run, err: Optional[Exception]) -> None:
+        self._finish_run(err, len(run.rows), run.dones)
+        heat = getattr(self.store_engine, "heat", None)
+        if heat is not None:
+            heat.note_applied(self.region.id, run.entries)
 
     def _finish_run(self, err: Optional[Exception], n_rows: int,
                     dones: list) -> None:
@@ -282,18 +385,13 @@ class KVStoreStateMachine(StateMachine):
                     op = KVOperation.decode(it.data())
                     done = it.done()
                     closure = done if isinstance(done, KVClosure) else None
-                    subs = None
-                    if self.sealed_into < 0:
-                        if op.op == KVOp.MULTI:
-                            subs = KVOperation.unpack_multi(op.value)
-                        writes = [op] if subs is None else subs
-                        if all(w.op in self._RUN_OPS for w in writes):
-                            for w in writes:
-                                rows.extend(self._run_rows(w))
-                            dones.append((done, closure,
-                                          None if subs is None else len(subs)))
-                            it.next()
-                            continue
+                    subs, op_rows = self._run_of(op)
+                    if op_rows is not None:
+                        rows.extend(op_rows)
+                        dones.append((done, closure,
+                                      None if subs is None else len(subs)))
+                        it.next()
+                        continue
                 if dones:
                     fut = self.apply_round.stage(rows, len(dones),
                                                  self.leader_term >= 0)
