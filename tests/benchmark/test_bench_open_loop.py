@@ -4,6 +4,7 @@ overload reads a growing backlog, not an error; and the tiny cell through it
 is correct, and not correct with each fault planted, as in the closed loop."""
 
 import asyncio
+import os
 import time
 
 import numpy as np
@@ -185,10 +186,10 @@ def test_a_rate_it_keeps_reads_the_rate_and_a_flat_backlog():
     assert len({w[1] for w in writes}) == len(writes)
 
 
-def test_both_loops_have_the_one_contract():
+def test_both_loops_have_the_one_contract(manifest_root):
     import inspect
 
-    bm = check_manifest.check(REPO)
+    bm = check_manifest.check(manifest_root)
     for mod in (closed, open_loop):
         sig = inspect.signature(mod.run_window)
         assert list(sig.parameters) == [
@@ -197,10 +198,13 @@ def test_both_loops_have_the_one_contract():
         assert mod.IMPLEMENTS == {"faults": [[]]}
     # looked up by the kind the mix names, from the tree that was checked
     _, _, mix = check_manifest.cell(bm, "kv3x1024.ycsb_a_open")
-    assert plugins.loop_of(bm, mix).__file__ == open_loop.__file__
+    loops = os.path.join(manifest_root, "benchmark", "loops")
+    assert plugins.loop_of(bm, mix).__file__ == os.path.join(loops,
+                                                             "open.py")
     assert mix["loop"] == {"kind": "open", "rate": mix["loop"]["rate"]}
     _, _, a = check_manifest.cell(bm, "kv3x1024.ycsb_a")
-    assert plugins.loop_of(bm, a).__file__ == closed.__file__
+    assert plugins.loop_of(bm, a).__file__ == os.path.join(loops,
+                                                           "closed.py")
     # the same mix but for how it arrives
     assert {k: v for k, v in mix.items() if k not in ("name", "why", "loop")} \
         == {k: v for k, v in a.items() if k not in ("name", "why", "loop")}

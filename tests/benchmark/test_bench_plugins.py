@@ -10,7 +10,7 @@ import os
 import time
 
 import pytest
-from bench_helpers import REPO, add_files, extended_copy
+from bench_helpers import add_files, extended_copy
 
 from benchmark import check_manifest, cluster, plugins
 from benchmark.cluster import NotImplementedConfig
@@ -166,9 +166,9 @@ def test_a_cluster_a_loop_and_a_metric_are_added_as_files_and_the_cell_runs(
     ("stores", 5), ("replicas", 5), ("read_mode", "lease"),
     ("transport", "tcp"), ("log_scheme", "file"), ("kv_store", "memory"),
     ("engine.backend", "numpy"), ("engine.mesh_devices", 4)])
-def test_a_value_the_named_module_does_not_run_is_refused_by_name(field,
-                                                                  value):
-    bm = check_manifest.check(REPO)
+def test_a_value_the_named_module_does_not_run_is_refused_by_name(
+        manifest_root, field, value):
+    bm = check_manifest.check(manifest_root)
     _, cfg, _ = check_manifest.cell(bm, "kv3x1024.ycsb_a")
     assert plugins.cluster_of(bm, cfg) is cluster.Cluster
     cfg = json.loads(json.dumps(cfg))

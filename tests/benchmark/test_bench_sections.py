@@ -7,7 +7,7 @@ import asyncio
 import time
 
 import pytest
-from bench_helpers import REPO, TINY_CELL, extended_copy
+from bench_helpers import TINY_CELL, extended_copy
 
 from benchmark import check_manifest
 from benchmark.driver import run_cell
@@ -45,8 +45,8 @@ NEW_METRICS = {
 MAY_BE_SILENT = {"log_wake_ms"}
 
 
-def test_the_manifest_passes_with_the_seventeen_entries():
-    bm = check_manifest.check(REPO)
+def test_the_manifest_passes_with_the_seventeen_entries(manifest_root):
+    bm = check_manifest.check(manifest_root)
     by_name = {m["name"]: m for m in bm["per_layer"]}
     assert len(NEW_METRICS) == 17 and set(NEW_METRICS) <= set(by_name)
     # appended after the twelve that were there, none of them moved; what
@@ -56,9 +56,11 @@ def test_the_manifest_passes_with_the_seventeen_entries():
 
 
 @pytest.mark.parametrize("name", list(NEW_METRICS))
-def test_each_entry_is_a_data_file_for_a_reader_that_was_there(name):
+def test_each_entry_is_a_data_file_for_a_reader_that_was_there(
+        manifest_root, name):
     kind, reads, layer, moves = NEW_METRICS[name]
-    m = {m["name"]: m for m in check_manifest.check(REPO)["per_layer"]}[name]
+    m = {m["name"]: m for m in check_manifest.check(
+        manifest_root)["per_layer"]}[name]
     assert "workloads" not in m                 # every cell, later ones too
     assert (m["layer"], m["moves"], m["better"]) == (layer, moves, "lower")
     reader = m["_reader"]

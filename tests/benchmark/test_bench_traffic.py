@@ -36,7 +36,7 @@ def test_same_seed_same_operations_with_the_stated_shares_and_skew(name,
     assert a.records.min() >= 0 and a.records.max() < 16384
 
 
-def test_what_no_cell_uses_yet_is_refused_by_name():
+def test_what_no_cell_uses_yet_is_refused_by_name(manifest_root):
     """Kinds of operation by the generator; a fault schedule by the lookup,
     unless the mix's loop module says that it runs it; a loop of a kind that
     is no module by the manifest's check."""
@@ -44,7 +44,7 @@ def test_what_no_cell_uses_yet_is_refused_by_name():
                          ({"request_distribution": "latest"}, "latest")):
         with pytest.raises(NotImplementedTraffic, match=word):
             OpStream(dict(_mix("ycsb_a"), **change), 64, 1, n=16)
-    bm = check_manifest.check(REPO)
+    bm = check_manifest.check(manifest_root)
     for name in ("ycsb_a", "ycsb_a_open"):
         assert plugins.loop_of(bm, _mix(name)).IMPLEMENTS == {"faults": [[]]}
         with pytest.raises(NotImplementedTraffic, match="faults="):
@@ -53,7 +53,7 @@ def test_what_no_cell_uses_yet_is_refused_by_name():
     errs: list = []
     check_manifest.check_traffic_file(
         dict(_mix("ycsb_a"), loop={"kind": "bursty", "rate": 100}),
-        "a_mix.json", errs, REPO)
+        "a_mix.json", errs, manifest_root)
     assert any("loop.kind 'bursty'" in e and "no such file" in e
                for e in errs)
     uniform = OpStream(dict(_mix("ycsb_a"), request_distribution="uniform"),
